@@ -217,7 +217,7 @@ def main(argv=None):
         at = dict(cap=320, batch=64, candidates=(16, 32, 64), iters=2)
 
     cfg = GenASMConfig()
-    spec = DeviceSpec.for_platform()
+    spec = DeviceSpec.for_device()
     align.clear_autotune_cache()  # heuristic block sizes, reproducible rows
     rows = kernel_table(caps, batch=batch, ref_batch=ref_batch, iters=iters,
                         spec=spec, cfg=cfg)

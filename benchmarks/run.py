@@ -53,6 +53,9 @@ def main(argv=None) -> None:
                     help="write collected module summaries here")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (align_dispatch, bitalign, edit_distance, graph_serve,
                    kernel_dc, prealign_filter, read_alignment, roofline,
                    segram_e2e, serve_engine, shard_scaling)
@@ -94,7 +97,7 @@ def main(argv=None) -> None:
                 out = mod.main()
             if isinstance(out, dict):
                 summaries[name] = out
-        except Exception as e:  # keep the harness running
+        except Exception as e:  # run the rest, fail the run at the end
             print(f"{name},0.0,ERROR:{type(e).__name__}:{e}", flush=True)
             summaries[name] = {"error": f"{type(e).__name__}: {e}"}
     if args.json:
@@ -103,9 +106,9 @@ def main(argv=None) -> None:
         print(f"wrote {args.json}")
     errors = [n for n, s in summaries.items()
               if isinstance(s, dict) and "error" in s]
-    if args.smoke and errors:
-        # the CI smoke step must fail the build, not ship an error artifact
-        sys.exit(f"smoke benchmark(s) failed: {', '.join(errors)}")
+    if errors:
+        # a failed module fails the run, not ship an error artifact
+        sys.exit(f"benchmark(s) failed: {', '.join(errors)}")
 
 
 if __name__ == "__main__":

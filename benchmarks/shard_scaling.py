@@ -25,22 +25,18 @@ Each sharded row reports four modes so the win decomposes:
   through ``start``/``finish`` double-buffering, batch i's align
   overlapping batch i+1's scatter.
 
-Needs ``jax.device_count() >= 4``; when launched with fewer devices it
-re-execs itself in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=4`` (XLA fixes the
-device count at first backend use, so an in-process flag flip cannot
-work from the combined harness).
+Runs in one process.  The sharded rows use a shard mesh when
+``jax.device_count() >= 4``; on the CPU backend give the process four
+host devices from outside (XLA fixes the device count at first backend
+use), otherwise the shards run as a stacked vmap on one device:
 
-    PYTHONPATH=src python benchmarks/shard_scaling.py            # full
-    PYTHONPATH=src python benchmarks/shard_scaling.py --smoke    # CI-sized
+    XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+        PYTHONPATH=src python benchmarks/shard_scaling.py [--smoke]
 """
 from __future__ import annotations
 
 import argparse
 import json
-import os
-import subprocess
-import sys
 import time
 
 try:
@@ -177,36 +173,12 @@ def _measure(*, ref_len, n_reads, read_len, p_cap, candidates, reps, seed):
     }
 
 
-def _needs_respawn() -> bool:
-    import jax
-
-    return jax.device_count() < max(SHARD_COUNTS)
-
-
-def _respawn(argv, json_path) -> dict:
-    """Re-exec with forced host devices; the child writes the JSON."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + f" --xla_force_host_platform_device_count="
-                          f"{max(SHARD_COUNTS)}").strip()
-    cmd = [sys.executable, os.path.abspath(__file__),
-           *argv, "--json", json_path, "--_no-respawn"]
-    proc = subprocess.run(cmd, env=env, capture_output=True, text=True)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"shard_scaling worker failed:\n{proc.stderr[-2000:]}")
-    with open(json_path) as f:
-        return json.load(f)
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized run (smaller reference, fewer reps)")
     ap.add_argument("--json", default=None, help="write summary JSON here")
     ap.add_argument("--seed", type=int, default=17)
-    ap.add_argument("--_no-respawn", dest="no_respawn", action="store_true",
-                    help=argparse.SUPPRESS)  # internal: already re-execed
     args = ap.parse_args(argv)
 
     if args.smoke:
@@ -220,29 +192,14 @@ def main(argv=None):
         align_params = dict(ref_len=400_000, n_reads=16, read_len=450,
                             p_cap=512, candidates=8, reps=4)
 
-    if not args.no_respawn and _needs_respawn():
-        import tempfile
-
-        json_path = args.json
-        if json_path is None:
-            fd, json_path = tempfile.mkstemp(suffix="_shard_scaling.json")
-            os.close(fd)
-        child_argv = (["--smoke"] if args.smoke else []) \
-            + ["--seed", str(args.seed)]
-        try:
-            out = _respawn(child_argv, json_path)
-        finally:
-            if args.json is None:
-                os.unlink(json_path)
-    else:
-        out = _measure(seed=args.seed, **params)
-        # align-dominated point: long reads/caps, small candidate
-        # budget — where the sharded/pipelined align stage must win
-        out["align_point"] = _measure(seed=args.seed + 1, **align_params)
-        if args.json:
-            with open(args.json, "w") as f:
-                json.dump(out, f, indent=2)
-            print(f"wrote {args.json}")
+    out = _measure(seed=args.seed, **params)
+    # align-dominated point: long reads/caps, small candidate
+    # budget — where the sharded/pipelined align stage must win
+    out["align_point"] = _measure(seed=args.seed + 1, **align_params)
+    if args.json:
+        with open(args.json, "w") as f:
+            json.dump(out, f, indent=2)
+        print(f"wrote {args.json}")
 
     base = out["shards"]["1"]["reads_per_s"]
     for s in SHARD_COUNTS:

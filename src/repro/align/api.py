@@ -108,7 +108,9 @@ _BLOCK_CACHE: dict[tuple[str, int, int], int] = {}
 
 
 def _heuristic_block(batch: int) -> int:
-    return min(DEFAULT_BT, max(8, batch))
+    from repro.kernels.genasm_dc import batch_tile
+
+    return batch_tile(DEFAULT_BT, batch, interpret=needs_interpret())
 
 
 def block_size_for(backend: str, bucket_cap: int, k: int, batch: int) -> int:

@@ -28,38 +28,8 @@ from repro.core.bitvector import pattern_bitmasks
 from repro.core.genasm import (AlignResult, GenASMConfig, pad_pattern,
                                pad_text, window_commit)
 from repro.core.genasm_tb import OP_PAD, window_tb, window_tb_r
-
-
-def _pad_to_block(arr: jnp.ndarray, block: int, fill) -> jnp.ndarray:
-    """Pad the leading (batch) axis up to a multiple of ``block``."""
-    b = arr.shape[0]
-    pad = (-b) % block
-    if not pad:
-        return arr
-    return jnp.concatenate(
-        [arr, jnp.full((pad,) + arr.shape[1:], fill, arr.dtype)])
-
-
-def _dc_v1(sub_t, sub_p, *, w, k, block_bt, interpret):
-    from repro.kernels.genasm_dc import window_dc_batch
-
-    b = sub_t.shape[0]
-    bt = min(block_bt, max(8, b))
-    d, tb = window_dc_batch(
-        _pad_to_block(sub_t, bt, 4), _pad_to_block(sub_p, bt, 4),
-        w=w, k=k, block_bt=bt, interpret=interpret)
-    return d[:b], tb[:b]
-
-
-def _dc_v2(sub_t, sub_p, *, w, k, block_bt, interpret):
-    from repro.kernels.genasm_dc_v2 import window_dc_batch_v2
-
-    b = sub_t.shape[0]
-    bt = min(block_bt, max(8, b))
-    d, r = window_dc_batch_v2(
-        _pad_to_block(sub_t, bt, 4), _pad_to_block(sub_p, bt, 4),
-        w=w, k=k, block_bt=bt, interpret=interpret)
-    return d[:b], r[:b]
+from repro.kernels.genasm_dc import window_dc_batch
+from repro.kernels.genasm_dc_v2 import window_dc_batch_v2
 
 
 @partial(jax.jit, static_argnames=("cfg", "p_cap", "emit_cigar", "store_r",
@@ -98,7 +68,7 @@ def batched_kernel_align(
         lambda t, tl: pad_text(t, tl, p_cap + n_win * cfg.commit, cfg))(
         texts, t_lens)
 
-    dc = _dc_v2 if store_r else _dc_v1
+    dc = window_dc_batch_v2 if store_r else window_dc_batch
     slice_w = jax.vmap(lambda buf, i: lax.dynamic_slice(buf, (i,), (w,)))
     if store_r:
         tb_fn = jax.vmap(

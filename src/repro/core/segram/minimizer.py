@@ -43,6 +43,26 @@ def hash32(x: jnp.ndarray) -> jnp.ndarray:
     return x
 
 
+_INVALID = np.uint32(0xFFFFFFFF)
+# bases per device pass of the index build: the per-position k-mer and
+# window gathers cost ~100 B/base, so a whole chromosome is built in
+# overlapping chunks instead of one program
+BUILD_CHUNK = 1 << 22
+
+
+def _kmer_hashes(seq: jnp.ndarray, k: int) -> jnp.ndarray:
+    """Minimizer-order hash per k-mer (``0xFFFFFFFF`` for invalid ones)."""
+    codes = kmer_codes(seq, k)
+    return jnp.where(codes == _INVALID, _INVALID, hash32(codes))
+
+
+def _window_argmin(h: jnp.ndarray, w: int) -> jnp.ndarray:
+    """Position of the first minimal hash in every ``w``-window of ``h``."""
+    n_win = h.shape[0] - w + 1
+    widx = jnp.arange(n_win)[:, None] + jnp.arange(w)[None, :]
+    return jnp.argmin(h[widx], axis=-1) + jnp.arange(n_win)
+
+
 @partial(jax.jit, static_argnames=("w", "k"))
 def minimizers(seq: jnp.ndarray, *, w: int, k: int):
     """Minimizer sampling (paper Figure 6-4).
@@ -50,16 +70,44 @@ def minimizers(seq: jnp.ndarray, *, w: int, k: int):
     Returns ``(is_min [n-k+1] bool, hashes [n-k+1] uint32)``: positions that
     are the minimum-hash k-mer of at least one w-window.
     """
-    codes = kmer_codes(seq, k)
-    h = jnp.where(codes == jnp.uint32(0xFFFFFFFF), jnp.uint32(0xFFFFFFFF), hash32(codes))
-    n_k = h.shape[0]
-    n_win = n_k - w + 1
-    widx = jnp.arange(n_win)[:, None] + jnp.arange(w)[None, :]
-    wh = h[widx]  # [n_win, w]
-    arg = jnp.argmin(wh, axis=-1) + jnp.arange(n_win)
-    is_min = jnp.zeros((n_k,), bool).at[arg].set(True)
-    is_min = is_min & (h != jnp.uint32(0xFFFFFFFF))
+    h = _kmer_hashes(seq, k)
+    arg = _window_argmin(h, w)
+    is_min = jnp.zeros((h.shape[0],), bool).at[arg].set(True)
+    is_min = is_min & (h != _INVALID)
     return is_min, h
+
+
+@partial(jax.jit, static_argnames=("w", "k"))
+def _chunk_minimizers(seq: jnp.ndarray, *, w: int, k: int):
+    h = _kmer_hashes(seq, k)
+    return _window_argmin(h, w), h
+
+
+def host_minimizers(ref: np.ndarray, *, w: int, k: int,
+                    chunk: int = BUILD_CHUNK):
+    """`minimizers` of a whole reference as host arrays, built on the
+    device ``chunk`` windows at a time (each chunk reads ``k-1 + w-1``
+    bases past its end), so device memory stays bounded for any
+    reference length.  Bitwise equal to one `minimizers` pass."""
+    n = len(ref)
+    if n <= chunk:
+        is_min, h = minimizers(jnp.asarray(ref), w=w, k=k)
+        return np.asarray(is_min), np.asarray(h)
+    n_k = n - k + 1
+    n_win = n_k - w + 1
+    span = chunk + (k - 1) + (w - 1)
+    is_min = np.zeros(n_k, bool)
+    h = np.empty(n_k, np.uint32)
+    for s in range(0, n_win, chunk):
+        seg = np.full(span, 4, np.int8)  # sentinel pad: invalid k-mers
+        part = ref[s:s + span]
+        seg[:len(part)] = part
+        arg, hh = _chunk_minimizers(jnp.asarray(seg), w=w, k=k)
+        m = min(chunk, n_win - s)
+        is_min[s + np.asarray(arg[:m])] = True
+        e = min(s + chunk + w - 1, n_k)
+        h[s:e] = np.asarray(hh[:e - s])
+    return is_min & (h != _INVALID), h
 
 
 class MinimizerIndex(NamedTuple):
@@ -73,9 +121,7 @@ class MinimizerIndex(NamedTuple):
 def build_index(ref: np.ndarray, *, w: int = 10, k: int = 15,
                 freq_frac: float = 0.0002) -> MinimizerIndex:
     """Offline index construction (paper §6.5) with frequency filtering."""
-    is_min, h = jax.jit(partial(minimizers, w=w, k=k))(jnp.asarray(ref))
-    is_min = np.asarray(is_min)
-    h = np.asarray(h)
+    is_min, h = host_minimizers(np.asarray(ref, np.int8), w=w, k=k)
     pos = np.nonzero(is_min)[0].astype(np.int32)
     hh = h[pos]
     order = np.argsort(hh, kind="stable")

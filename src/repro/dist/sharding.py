@@ -231,13 +231,22 @@ def stacked_specs(tree, mesh, *, axis: str = "shard"):
 def shard_mesh(num_shards: int, *, axis: str = "shard"):
     """1-D device mesh over the first ``num_shards`` devices, or None.
 
-    Returns None when fewer than ``num_shards`` devices exist (callers
-    fall back to a vmapped single-device execution of the same
-    program) or when ``num_shards == 1`` (nothing to place).
+    Returns None when ``num_shards == 1`` (nothing to place), and on the
+    CPU backend when fewer than ``num_shards`` devices exist (callers
+    then run a vmapped single-device execution of the same program —
+    the test path).  On an accelerator too few devices is an error: a
+    sharded deployment never silently runs its shards on one chip.
     """
     import numpy as np
 
-    if num_shards <= 1 or jax.device_count() < num_shards:
+    if num_shards <= 1:
+        return None
+    if jax.device_count() < num_shards:
+        if jax.default_backend() != "cpu":
+            raise ValueError(
+                f"num_shards={num_shards} needs {num_shards} devices; "
+                f"found {jax.device_count()} {jax.default_backend()} "
+                f"device(s)")
         return None
     from jax.sharding import Mesh
 

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.align.api import register_backend
-from repro.align.batched import _pad_to_block
 from repro.core.bitvector import pattern_bitmasks
 from repro.core.genasm import AlignResult, GenASMConfig, pad_pattern, \
     window_commit
@@ -80,8 +79,6 @@ def batched_graph_align(
     b = texts.shape[0]
     p_lens = p_lens.astype(jnp.int32)
     t_lens = t_lens.astype(jnp.int32)
-    bt = min(block_bt, max(8, b))
-    pad_b = b + (-b) % bt
 
     gtexts = as_graph_text(texts)
     pats = jax.vmap(lambda p, pl: pad_pattern(p, pl, p_cap, cfg))(
@@ -93,19 +90,17 @@ def batched_graph_align(
     slice_w = jax.vmap(lambda buf, i: lax.dynamic_slice(buf, (i,), (w,)))
     tb_fn = jax.vmap(
         partial(window_tb_graph, w=w, o=o, k=k, affine=cfg.affine))
-    full_w = jnp.full((pad_b,), w, jnp.int32)  # no tail mask: full windows
+    full_w = jnp.full((b,), w, jnp.int32)  # no tail mask: full windows
 
     def window_step(carry, _):
         cur_p, cur_t = carry[0], carry[1]
         sub_p = slice_w(pats, cur_p)  # [B, w]
         sub_g = slice_w(gbufs, cur_t)
         bases, succ = unpack_graph_text(sub_g)
-        d_all, r_all = bitalign_dc_batch(
-            _pad_to_block(bases, bt, 4), _pad_to_block(succ, bt, 0),
-            _pad_to_block(sub_p, bt, 4), full_w,
-            m_bits=w, k=k, block_bt=bt, interpret=interpret)
-        d_min = d_all[:b, 0]  # anchored at window node 0
-        store = r_all[:b]  # [B, w, k+1, nw]
+        d_all, store = bitalign_dc_batch(
+            bases, succ, sub_p, full_w, m_bits=w, k=k, block_bt=block_bt,
+            interpret=interpret)  # store: [B, w, k+1, nw]
+        d_min = d_all[:, 0]  # anchored at window node 0
         cap_p = jnp.minimum(jnp.int32(cfg.commit), p_lens - cur_p)
         pm = jax.vmap(lambda p: pattern_bitmasks(p, w))(sub_p)
         pc, tc, err, ops, n_ops, nodes, stuck = tb_fn(

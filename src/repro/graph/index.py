@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import threading
 from dataclasses import dataclass
+from functools import partial
 from pathlib import Path
 from typing import NamedTuple, Sequence
 
@@ -90,11 +91,17 @@ class GraphIndex:
         return int(len(self.ref))
 
 
-def _build_tiles(bases: jnp.ndarray, succ: jnp.ndarray, *, tile_len: int,
-                 tile_stride: int):
+# tiles per device pass of the tile build: one tile row costs ~50 B per
+# node of q-gram/gather intermediates, so large graphs build in chunks
+TILE_CHUNK = 8192
+
+
+@partial(jax.jit, static_argnames=("n_rows", "tile_len", "tile_stride"))
+def _tile_rows(bases: jnp.ndarray, succ: jnp.ndarray, row0, *, n_rows: int,
+               tile_len: int, tile_stride: int):
+    """Tiles ``row0 .. row0 + n_rows`` (rows past the graph are empty)."""
     n = bases.shape[0]
-    c = max(1, -(-int(n) // tile_stride))
-    starts = jnp.arange(c) * tile_stride
+    starts = (row0 + jnp.arange(n_rows)) * tile_stride
     idx = starts[:, None] + jnp.arange(tile_len)[None, :]
     inb = idx < n
     idxc = jnp.clip(idx, 0, n - 1)
@@ -113,6 +120,19 @@ def _build_tiles(bases: jnp.ndarray, succ: jnp.ndarray, *, tile_len: int,
     slack = ((QGRAM_Q - 1) *
              jnp.sum(hop_edges, axis=-1)).astype(jnp.int32)
     return pack_graph_text(tb, ts_m), valid, bloom, slack
+
+
+def _build_tiles(bases: jnp.ndarray, succ: jnp.ndarray, *, tile_len: int,
+                 tile_stride: int):
+    n = bases.shape[0]
+    c = max(1, -(-int(n) // tile_stride))
+    rows = min(c, TILE_CHUNK)
+    parts = [_tile_rows(bases, succ, r0, n_rows=rows, tile_len=tile_len,
+                        tile_stride=tile_stride)
+             for r0 in range(0, c, rows)]
+    if len(parts) == 1:
+        return parts[0]
+    return tuple(jnp.concatenate(p)[:c] for p in zip(*parts))
 
 
 def build_graph_index(

@@ -251,24 +251,24 @@ def tile_prefilter(
         n_live=jnp.sum(live, axis=-1, dtype=jnp.int32))
 
 
-def _filter_dists(wins_flat, fpat_flat, flens_flat, *, m_bits: int, k: int,
-                  use_kernel: bool, block_bt: int | None, interpret: bool):
-    """[BC, tile_len] per-node distances, kernel or pure-lax path."""
+def _filter_best(wins_flat, fpat_flat, flens_flat, *, span: int,
+                 m_bits: int, k: int, use_kernel: bool,
+                 block_bt: int | None, interpret: bool):
+    """Per-row ``(d, off)``: the minimum filter distance over the first
+    ``span`` window nodes and the first node attaining it (``k + 1`` and
+    0 when none matches) — kernel or pure-lax path, bitwise equal."""
     bases, succ = unpack_graph_text(wins_flat)
     if use_kernel:
-        from repro.align.batched import _pad_to_block
-        from repro.kernels.bitalign import bitalign_dc_batch
+        from repro.kernels.bitalign import bitalign_filter_batch
 
-        bc = wins_flat.shape[0]
-        bt = min(block_bt or 128, max(8, bc))
-        dists, _ = bitalign_dc_batch(
-            _pad_to_block(bases, bt, 4), _pad_to_block(succ, bt, 0),
-            _pad_to_block(fpat_flat, bt, WILDCARD),
-            _pad_to_block(flens_flat, bt, m_bits),
-            m_bits=m_bits, k=k, block_bt=bt, interpret=interpret)
-        return dists[:bc]
+        return bitalign_filter_batch(
+            bases, succ, fpat_flat, flens_flat, m_bits=m_bits, k=k,
+            span=span, block_bt=block_bt or 128, interpret=interpret)
     f = partial(bitalign_search, m_bits=m_bits, k=k)
-    return jax.vmap(f)(bases, succ, fpat_flat, flens_flat)
+    dists = jax.vmap(f)(bases, succ, fpat_flat, flens_flat)
+    dists = jnp.where(jnp.arange(dists.shape[-1]) < span, dists, k + 1)
+    return (jnp.min(dists, axis=-1).astype(jnp.int32),
+            jnp.argmin(dists, axis=-1).astype(jnp.int32))
 
 
 def graph_candidate_stage(
@@ -327,22 +327,18 @@ def graph_candidate_stage(
         view, starts, tile_stride=tile_stride, n_tiles=n_tiles,
         backbone_len=backbone_len)
     fpat, flens = _filter_pattern(reads, read_lens, filter_bits)
-    dc = partial(_filter_dists, m_bits=filter_bits, k=filter_k,
-                 use_kernel=use_kernel, block_bt=block_bt,
+    # anchors past the search span could not fit an alignment window
+    dc = partial(_filter_best, span=search_span, m_bits=filter_bits,
+                 k=filter_k, use_kernel=use_kernel, block_bt=block_bt,
                  interpret=interpret)
-    span_ok = jnp.arange(tile_len) < search_span
 
     if n_cap is None:
         # --- dense: one gather + one DC launch over every slot
         wins = view.tile_gtext[tile_local]  # [B, C, tile_len]
-        dists = dc(wins.reshape(b * c, tile_len),
-                   jnp.repeat(fpat, c, axis=0),
-                   jnp.repeat(flens, c)).reshape(b, c, tile_len)
-        # anchors past the search span could not fit an alignment window
-        dists = jnp.where(span_ok[None, None, :], dists, filter_k + 1)
-        d_c = jnp.min(dists, axis=-1).astype(jnp.int32)
-        off_c = jnp.argmin(dists, axis=-1).astype(jnp.int32)
-        d_c = jnp.where(keep, d_c, filter_k + 1)
+        d_c, off_c = dc(wins.reshape(b * c, tile_len),
+                        jnp.repeat(fpat, c, axis=0), jnp.repeat(flens, c))
+        d_c = jnp.where(keep, d_c.reshape(b, c), filter_k + 1)
+        off_c = off_c.reshape(b, c)
     else:
         # --- ragged: compact survivors into [n_cap] rows, DC those only,
         # scatter back to the dense grid.  Non-survivor slots take the
@@ -359,10 +355,7 @@ def graph_candidate_stage(
         rowmask = jnp.arange(n_cap) < n_tot
         ridx = slots // c  # read of each compacted row
         wins_r = view.tile_gtext[tile_local.reshape(bc)[slots]]
-        dists = dc(wins_r, fpat[ridx], flens[ridx])  # [n_cap, tile_len]
-        dists = jnp.where(span_ok[None, :], dists, filter_k + 1)
-        d_r = jnp.min(dists, axis=-1).astype(jnp.int32)
-        off_r = jnp.argmin(dists, axis=-1).astype(jnp.int32)
+        d_r, off_r = dc(wins_r, fpat[ridx], flens[ridx])  # [n_cap] each
         d_c = jnp.full((bc,), filter_k + 1, jnp.int32).at[slots].set(
             jnp.where(rowmask, d_r, filter_k + 1)).reshape(b, c)
         off_c = jnp.zeros((bc,), jnp.int32).at[slots].set(

@@ -6,15 +6,19 @@ the batch of independent window alignments is the vector axis — every VPU
 lane advances one alignment, sequentially in ``i`` (text chars) and with a
 *statically unrolled* ``d`` loop (the k+1 distance rows, k=24 default).
 
-Data layout inside the kernel is word-major ``[.., nw, BT]`` so the batch
-tile ``BT`` occupies the 128-wide lane dimension; bitvector words (nw=2
-for W=64) and distance rows live in sublanes/registers.  The per-window
-traceback store (the ASIC's TB-SRAM) is the kernel output, written once
-per text step — the same "24 B/cycle/PE" streaming locality the paper
-engineers, here expressed as one VMEM->HBM block stream per window tile.
+Data layout inside the kernel is **lane-major**: every array carries the
+batch tile ``BT`` (a multiple of 128 when compiled) on its last axis, so
+one bitvector word of one distance row is a ``[1, BT]`` vector and a
+text column is one ``[1, BT]`` row.  Stores are ``[.., rows, BT]`` with
+``rows = (k+1)·nw`` (v2) or ``(k+1)·3·nw`` (v1, M/I/D per row): row
+``(d·3 + t)·nw + j`` holds word ``j`` of vector ``t`` at distance ``d``.
+The PM table is built outside the kernel (XLA, `core.bitvector`) and
+enters as ``[NUM_CHARS·nw, BT]``; the wrappers transpose the stores back
+to the batch-major layout the traceback reads.
 
-VMEM budget per block (BT=128, W=64, k=24): tb out 4.9 MB + text/pattern
-tiles 16 KB + PM scratch 5 KB + R carry 26 KB — well under 16 MB.
+VMEM per block (BT=128, W=64, k=24): v1 TB store 4.9 MB, v2 R store
+1.7 MB, text + PM 40 KB — double-buffered, inside v5e's 16 MiB default
+scoped limit.
 """
 from __future__ import annotations
 
@@ -25,67 +29,122 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-from repro.core.bitvector import NUM_CHARS, WORD_BITS
+from repro.core.bitvector import NUM_CHARS, WORD_BITS, pattern_bitmasks
 
 DEFAULT_BT = 128
+LANES = 128  # TPU vector lane width: compiled batch tiles are multiples
+_ONES = 0xFFFFFFFF
 
 
-def _shl1_wm(x: jnp.ndarray) -> jnp.ndarray:
-    """shift-left-1 for word-major [.., nw, BT] bitvectors."""
-    carry = x >> 31
-    shifted = x << 1
-    zeros = jnp.zeros(x.shape[:-2] + (1,) + x.shape[-1:], jnp.uint32)
-    incoming = jnp.concatenate([zeros, carry[..., :-1, :]], axis=-2)
-    return shifted | incoming
+def batch_tile(block_bt: int | None, batch: int, *, interpret: bool) -> int:
+    """Batch tile of one kernel launch.
+
+    ``min(block_bt, max(8, batch))`` — small tiles keep interpret-mode
+    CPU runs cheap — rounded up to whole 128-lane vectors when the
+    kernel is compiled, the only block widths Mosaic accepts.
+    """
+    bt = min(block_bt or DEFAULT_BT, max(8, batch))
+    if interpret:
+        return bt
+    return -(-bt // LANES) * LANES
 
 
-def _pm_table(pattern_tile: jnp.ndarray, w: int, nw: int) -> jnp.ndarray:
-    """[NUM_CHARS, nw, BT] uint32 PM table from a [BT, w] int8 pattern tile."""
-    rev = pattern_tile[:, ::-1].astype(jnp.int32)  # [BT, w]; rev[:, g] = char at bit g
-    weights = jnp.uint32(1) << jnp.arange(WORD_BITS, dtype=jnp.uint32)
+def to_lanes(x: jnp.ndarray, bt: int, fill) -> jnp.ndarray:
+    """``[B, ...]`` → ``[..., B']``: batch moved to the last (lane) axis and
+    padded with ``fill`` up to a multiple of ``bt``."""
+    b = x.shape[0]
+    x = jnp.moveaxis(x, 0, -1)
+    pad = (-b) % bt
+    if pad:
+        x = jnp.concatenate(
+            [x, jnp.full(x.shape[:-1] + (pad,), fill, x.dtype)], axis=-1)
+    return x
+
+
+def pm_lanes(patterns: jnp.ndarray, m_bits: int, bt: int) -> jnp.ndarray:
+    """``[B, m_bits]`` patterns → lane-major PM table ``[NUM_CHARS·nw, B']``
+    (row ``c·nw + j`` = word ``j`` of char ``c``'s mask; padded lanes are
+    all-wildcard patterns)."""
+    pm = pattern_bitmasks(patterns, m_bits)  # [B, NUM_CHARS, nw]
+    return to_lanes(pm.reshape(pm.shape[0], -1), bt, 0)
+
+
+def shl1_words(x: list) -> list:
+    """Shift-left-1 of a little-endian word list (each ``[1, BT]``)."""
+    return [x[0] << 1] + [(x[j] << 1) | (x[j - 1] >> 31)
+                          for j in range(1, len(x))]
+
+
+def load_pm(pm_ref, nw: int) -> list:
+    """PM table rows as ``pm[c][j]`` ``[1, BT]`` vectors."""
+    return [[pm_ref[pl.ds(c * nw + j, 1), :] for j in range(nw)]
+            for c in range(NUM_CHARS)]
+
+
+def select_pm(pm: list, c: jnp.ndarray) -> list:
+    """Per-lane PM words of text char ``c`` (``[1, BT]`` int32; chars
+    outside the alphabet select all-zero words)."""
     out = []
-    for c in range(NUM_CHARS):
-        mismatch = ~((rev == c) | (rev == 4))
-        mm = mismatch.astype(jnp.uint32).reshape(rev.shape[0], nw, WORD_BITS)
-        pm = jnp.sum(mm * weights[None, None, :], axis=-1, dtype=jnp.uint32)  # [BT, nw]
-        out.append(pm.T)  # [nw, BT]
-    return jnp.stack(out)  # [5, nw, BT]
-
-
-def _dc_kernel(text_ref, pattern_ref, dmin_ref, tb_ref, *, w: int, k: int, nw: int):
-    bt = text_ref.shape[0]
-    pm = _pm_table(pattern_ref[...], w, nw)  # [5, nw, BT]
-    ones = jnp.full((k + 1, nw, bt), 0xFFFFFFFF, jnp.uint32)
-
-    def step(s, R_old):
-        i = w - 1 - s  # text position, scanned w-1 .. 0
-        c = text_ref[:, i].astype(jnp.int32)  # [BT]
-        cur_pm = jnp.zeros((nw, bt), jnp.uint32)
+    for j in range(len(pm[0])):
+        cur = jnp.zeros(c.shape, jnp.uint32)
         for ch in range(NUM_CHARS):
-            cur_pm = jnp.where((c == ch)[None, :], pm[ch], cur_pm)
+            cur = jnp.where(c == ch, pm[ch][j], cur)
+        out.append(cur)
+    return out
 
-        R0 = _shl1_wm(R_old[0]) | cur_pm
-        new_rows = [R0]
-        stores = [jnp.stack([R0, ones[0], ones[0]])]  # d=0: (M=R0, I=1s, D=1s)
-        for d in range(1, k + 1):
-            D = R_old[d - 1]
-            S = _shl1_wm(R_old[d - 1])
-            I = _shl1_wm(new_rows[d - 1])
-            M = _shl1_wm(R_old[d]) | cur_pm
-            new_rows.append(D & S & I & M)
-            stores.append(jnp.stack([M, I, D]))
-        R_new = jnp.stack(new_rows)  # [k+1, nw, BT]
-        st = jnp.stack(stores)  # [k+1, 3, nw, BT]
-        tb_ref[:, i] = st.transpose(3, 0, 1, 2)  # [BT, k+1, 3, nw]
-        return R_new
 
-    R_fin = lax.fori_loop(0, w, step, ones)
-    msbs = (R_fin[:, nw - 1, :] >> 31) & 1  # [k+1, BT]
-    found = msbs == 0
-    dmin = jnp.where(
-        jnp.any(found, axis=0), jnp.argmax(found, axis=0), k + 1
-    ).astype(jnp.int32)
-    dmin_ref[...] = dmin
+def dc_rows(comb: list, cur_pm: list, k: int, mask=None):
+    """One text step of the DC recurrence on word lists.
+
+    ``comb[d]`` is the previous text char's status row ``d`` (its word
+    list), ``mask`` an optional per-word AND mask (BitAlign's pattern
+    tail).  Returns ``(R_new, mid)`` with ``mid[d] = (M, I, D)`` word
+    lists for ``d ≥ 1`` (the v1 TB store).
+    """
+    def m(words):
+        return words if mask is None else [a & t for a, t in zip(words, mask)]
+
+    rows = [m([a | p for a, p in zip(shl1_words(comb[0]), cur_pm)])]
+    mid = [None]
+    for d in range(1, k + 1):
+        D = comb[d - 1]
+        S = shl1_words(comb[d - 1])
+        I = shl1_words(rows[d - 1])
+        M = [a | p for a, p in zip(shl1_words(comb[d]), cur_pm)]
+        rows.append(m([dd & s & ii & mm for dd, s, ii, mm in zip(D, S, I, M)]))
+        mid.append((M, I, D))
+    return rows, mid
+
+
+def min_distance(rows: list, k: int) -> jnp.ndarray:
+    """Smallest ``d`` whose status row has MSB 0 (``k+1`` if none)."""
+    out = jnp.full(rows[0][0].shape, k + 1, jnp.int32)
+    for d in range(k, -1, -1):
+        out = jnp.where((rows[d][-1] >> 31) == 0, jnp.int32(d), out)
+    return out
+
+
+def _dc_kernel(text_ref, pm_ref, dmin_ref, tb_ref, *, w: int, k: int,
+               nw: int):
+    bt = text_ref.shape[-1]
+    pm = load_pm(pm_ref, nw)
+    ones = jnp.full((1, bt), _ONES, jnp.uint32)
+
+    def step(s, flat):
+        i = w - 1 - s  # text position, scanned w-1 .. 0
+        R_old = [list(flat[d * nw:(d + 1) * nw]) for d in range(k + 1)]
+        cur_pm = select_pm(pm, text_ref[pl.ds(i, 1), :])
+        R_new, mid = dc_rows(R_old, cur_pm, k)
+        mid[0] = (R_new[0], [ones] * nw, [ones] * nw)  # d=0: (R0, 1s, 1s)
+        for d in range(k + 1):
+            for t in range(3):
+                for j in range(nw):
+                    tb_ref[i, pl.ds((d * 3 + t) * nw + j, 1), :] = mid[d][t][j]
+        return tuple(x for row in R_new for x in row)
+
+    fin = lax.fori_loop(0, w, step, (ones,) * ((k + 1) * nw))
+    rows = [fin[d * nw:(d + 1) * nw] for d in range(k + 1)]
+    dmin_ref[...] = min_distance(rows, k)
 
 
 @functools.partial(
@@ -102,8 +161,8 @@ def window_dc_batch(
 ):
     """Batched GenASM-DC windows via Pallas.
 
-    ``sub_texts``/``sub_patterns``: [B, w] int8 (B a multiple of
-    ``block_bt``; pad with sentinel windows).  Returns
+    ``sub_texts``/``sub_patterns``: [B, w] int8 (any B: the batch is
+    padded with sentinel windows up to the `batch_tile`).  Returns
     ``(d_min [B] int32, tb [B, w, k+1, 3, nw] uint32)`` identical to
     vmapped :func:`repro.core.genasm_dc.window_dc`.
     """
@@ -111,25 +170,29 @@ def window_dc_batch(
         raise ValueError("w must be a multiple of 32")
     nw = w // WORD_BITS
     b = sub_texts.shape[0]
-    if b % block_bt != 0:
-        raise ValueError(f"batch {b} not a multiple of block_bt {block_bt}")
+    bt = batch_tile(block_bt, b, interpret=interpret)
+    text = to_lanes(sub_texts.astype(jnp.int32), bt, 4)  # [w, B']
+    pm = pm_lanes(sub_patterns, w, bt)  # [5·nw, B']
+    bp = text.shape[-1]
+    rows = (k + 1) * 3 * nw
 
     kernel = functools.partial(_dc_kernel, w=w, k=k, nw=nw)
-    grid = (b // block_bt,)
-    return pl.pallas_call(
+    d, tb = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(bp // bt,),
         in_specs=[
-            pl.BlockSpec((block_bt, w), lambda i: (i, 0)),
-            pl.BlockSpec((block_bt, w), lambda i: (i, 0)),
+            pl.BlockSpec((w, bt), lambda i: (0, i)),
+            pl.BlockSpec((NUM_CHARS * nw, bt), lambda i: (0, i)),
         ],
         out_specs=[
-            pl.BlockSpec((block_bt,), lambda i: (i,)),
-            pl.BlockSpec((block_bt, w, k + 1, 3, nw), lambda i: (i, 0, 0, 0, 0)),
+            pl.BlockSpec((1, bt), lambda i: (0, i)),
+            pl.BlockSpec((w, rows, bt), lambda i: (0, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b,), jnp.int32),
-            jax.ShapeDtypeStruct((b, w, k + 1, 3, nw), jnp.uint32),
+            jax.ShapeDtypeStruct((1, bp), jnp.int32),
+            jax.ShapeDtypeStruct((w, rows, bp), jnp.uint32),
         ],
         interpret=interpret,
-    )(sub_texts, sub_patterns)
+    )(text, pm)
+    tb = tb.reshape(w, k + 1, 3, nw, bp).transpose(4, 0, 1, 2, 3)
+    return d[0, :b], tb[:b]

@@ -14,6 +14,9 @@ a PM re-derivation per TB step — TB executes ≤ W−O steps/window vs the
 DC's W·(k+1) writes, so trading DC-side bytes for TB-side gathers is a
 clear win (DC is the streaming bottleneck the paper engineered TB-SRAMs
 for).  Confirmed by measurement in EXPERIMENTS.md §Perf.
+
+Same lane-major layout as `genasm_dc` (batch on the last axis; store row
+``d·nw + j`` = word ``j`` of status row ``d``).
 """
 from __future__ import annotations
 
@@ -26,65 +29,60 @@ from jax.experimental import pallas as pl
 
 from repro.core.bitvector import NUM_CHARS, WORD_BITS
 
-from .genasm_dc import _pm_table, _shl1_wm
+from .genasm_dc import (DEFAULT_BT, _ONES, batch_tile, dc_rows,
+                        load_pm, min_distance, pm_lanes,
+                        select_pm, to_lanes)
 
 
-def _dc_kernel_v2(text_ref, pattern_ref, dmin_ref, r_ref, *, w: int, k: int,
+def _dc_kernel_v2(text_ref, pm_ref, dmin_ref, r_ref, *, w: int, k: int,
                   nw: int):
-    bt = text_ref.shape[0]
-    pm = _pm_table(pattern_ref[...], w, nw)  # [5, nw, BT]
-    ones = jnp.full((k + 1, nw, bt), 0xFFFFFFFF, jnp.uint32)
-    r_ref[:, w] = ones.transpose(2, 0, 1)  # boundary row (i = w)
+    bt = text_ref.shape[-1]
+    pm = load_pm(pm_ref, nw)
+    ones = jnp.full((1, bt), _ONES, jnp.uint32)
+    r_ref[w] = jnp.full(r_ref.shape[1:], _ONES, jnp.uint32)  # row i = w
 
-    def step(s, R_old):
+    def step(s, flat):
         i = w - 1 - s
-        c = text_ref[:, i].astype(jnp.int32)
-        cur_pm = jnp.zeros((nw, bt), jnp.uint32)
-        for ch in range(NUM_CHARS):
-            cur_pm = jnp.where((c == ch)[None, :], pm[ch], cur_pm)
-        R0 = _shl1_wm(R_old[0]) | cur_pm
-        rows = [R0]
-        for d in range(1, k + 1):
-            D = R_old[d - 1]
-            S = _shl1_wm(R_old[d - 1])
-            I = _shl1_wm(rows[d - 1])
-            M = _shl1_wm(R_old[d]) | cur_pm
-            rows.append(D & S & I & M)
-        R_new = jnp.stack(rows)  # [k+1, nw, BT]
-        r_ref[:, i] = R_new.transpose(2, 0, 1)
-        return R_new
+        R_old = [list(flat[d * nw:(d + 1) * nw]) for d in range(k + 1)]
+        R_new, _ = dc_rows(R_old, select_pm(pm, text_ref[pl.ds(i, 1), :]), k)
+        for d in range(k + 1):
+            for j in range(nw):
+                r_ref[i, pl.ds(d * nw + j, 1), :] = R_new[d][j]
+        return tuple(x for row in R_new for x in row)
 
-    R_fin = lax.fori_loop(0, w, step, ones)
-    msbs = (R_fin[:, nw - 1, :] >> 31) & 1
-    found = msbs == 0
-    dmin_ref[...] = jnp.where(
-        jnp.any(found, axis=0), jnp.argmax(found, axis=0), k + 1
-    ).astype(jnp.int32)
+    fin = lax.fori_loop(0, w, step, (ones,) * ((k + 1) * nw))
+    dmin_ref[...] = min_distance(
+        [fin[d * nw:(d + 1) * nw] for d in range(k + 1)], k)
 
 
 @functools.partial(jax.jit, static_argnames=("w", "k", "block_bt", "interpret"))
 def window_dc_batch_v2(sub_texts, sub_patterns, *, w: int = 64, k: int = 24,
-                       block_bt: int = 128, interpret: bool = False):
+                       block_bt: int = DEFAULT_BT, interpret: bool = False):
     """Returns ``(d_min [B], R [B, w+1, k+1, nw])`` — status rows only."""
     nw = w // WORD_BITS
     b = sub_texts.shape[0]
-    if b % block_bt != 0:
-        raise ValueError(f"batch {b} not a multiple of block_bt {block_bt}")
+    bt = batch_tile(block_bt, b, interpret=interpret)
+    text = to_lanes(sub_texts.astype(jnp.int32), bt, 4)  # [w, B']
+    pm = pm_lanes(sub_patterns, w, bt)
+    bp = text.shape[-1]
+    rows = (k + 1) * nw
     kernel = functools.partial(_dc_kernel_v2, w=w, k=k, nw=nw)
-    return pl.pallas_call(
+    d, r = pl.pallas_call(
         kernel,
-        grid=(b // block_bt,),
+        grid=(bp // bt,),
         in_specs=[
-            pl.BlockSpec((block_bt, w), lambda i: (i, 0)),
-            pl.BlockSpec((block_bt, w), lambda i: (i, 0)),
+            pl.BlockSpec((w, bt), lambda i: (0, i)),
+            pl.BlockSpec((NUM_CHARS * nw, bt), lambda i: (0, i)),
         ],
         out_specs=[
-            pl.BlockSpec((block_bt,), lambda i: (i,)),
-            pl.BlockSpec((block_bt, w + 1, k + 1, nw), lambda i: (i, 0, 0, 0)),
+            pl.BlockSpec((1, bt), lambda i: (0, i)),
+            pl.BlockSpec((w + 1, rows, bt), lambda i: (0, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b,), jnp.int32),
-            jax.ShapeDtypeStruct((b, w + 1, k + 1, nw), jnp.uint32),
+            jax.ShapeDtypeStruct((1, bp), jnp.int32),
+            jax.ShapeDtypeStruct((w + 1, rows, bp), jnp.uint32),
         ],
         interpret=interpret,
-    )(sub_texts, sub_patterns)
+    )(text, pm)
+    r = r.reshape(w + 1, k + 1, nw, bp).transpose(3, 0, 1, 2)
+    return d[0, :b], r[:b]

@@ -1,17 +1,18 @@
-"""Public jit'd wrappers for the Pallas kernels.
+"""Public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode —
-the kernel body runs as traced JAX ops, bit-for-bit matching the TPU
-lowering semantics.  On TPU backends the compiled kernels run natively.
+On CPU the kernels execute in ``interpret=True`` mode — the kernel body
+runs as traced JAX ops, bit-for-bit matching the TPU lowering semantics.
+On TPU backends the compiled kernels run natively.  The DC kernels pad
+the batch to their tile themselves (`genasm_dc.batch_tile`).
 """
 from __future__ import annotations
-
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 
+from . import bitalign as _ba
 from . import genasm_dc as _gdc
+from . import genasm_dc_v2 as _v2
 from . import myers as _my
 
 
@@ -19,31 +20,33 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def window_dc(sub_texts, sub_patterns, *, w: int = 64, k: int = 24, squeeze=False,
+def window_dc(sub_texts, sub_patterns, *, w: int = 64, k: int = 24,
               block_bt: int | None = None):
-    """GenASM-DC over a batch of windows (Pallas kernel, padded to tile).
+    """GenASM-DC over a batch of windows (Pallas kernel).
 
     ``sub_texts``/``sub_patterns``: [B, w] int8.  Returns
-    ``(d_min [B], tb [B, w, k+1, 3, nw])``; with ``squeeze=True`` drops a
-    leading singleton batch (used by the windowed aligner's scan body).
+    ``(d_min [B], tb [B, w, k+1, 3, nw])``.
     """
-    b = sub_texts.shape[0]
-    bt = block_bt or min(_gdc.DEFAULT_BT, max(8, b))
-    pad = (-b) % bt
-    if pad:
-        sub_texts = jnp.concatenate(
-            [sub_texts, jnp.full((pad, sub_texts.shape[1]), 4, sub_texts.dtype)]
-        )
-        sub_patterns = jnp.concatenate(
-            [sub_patterns, jnp.full((pad, sub_patterns.shape[1]), 4, sub_patterns.dtype)]
-        )
-    d, tb = _gdc.window_dc_batch(
-        sub_texts, sub_patterns, w=w, k=k, block_bt=bt, interpret=_interpret()
-    )
-    d, tb = d[:b], tb[:b]
-    if squeeze:
-        return d[0], tb[0]
-    return d, tb
+    return _gdc.window_dc_batch(sub_texts, sub_patterns, w=w, k=k,
+                                block_bt=block_bt or _gdc.DEFAULT_BT,
+                                interpret=_interpret())
+
+
+def window_dc_v2(sub_texts, sub_patterns, *, w: int = 64, k: int = 24,
+                 block_bt: int | None = None):
+    """v2 kernel: R-only TB store (3× smaller; see genasm_dc_v2)."""
+    return _v2.window_dc_batch_v2(sub_texts, sub_patterns, w=w, k=k,
+                                  block_bt=block_bt or _gdc.DEFAULT_BT,
+                                  interpret=_interpret())
+
+
+def bitalign_dc(bases, succ_bits, patterns, p_lens, *, m_bits: int, k: int,
+                block_bt: int | None = None):
+    """Batched BitAlign DC kernel."""
+    return _ba.bitalign_dc_batch(bases, succ_bits, patterns, p_lens,
+                                 m_bits=m_bits, k=k,
+                                 block_bt=block_bt or _gdc.DEFAULT_BT,
+                                 interpret=_interpret())
 
 
 def myers_distance(texts, patterns, m_lens, *, m_bits: int, mode: str = "global",
@@ -63,47 +66,3 @@ def myers_distance(texts, patterns, m_lens, *, m_bits: int, mode: str = "global"
         interpret=_interpret(),
     )
     return out[:b]
-
-
-def window_dc_v2(sub_texts, sub_patterns, *, w: int = 64, k: int = 24,
-                 squeeze=False, block_bt: int | None = None):
-    """v2 kernel: R-only TB store (3× smaller; see genasm_dc_v2)."""
-    from . import genasm_dc_v2 as _v2
-
-    b = sub_texts.shape[0]
-    bt = block_bt or min(_gdc.DEFAULT_BT, max(8, b))
-    pad = (-b) % bt
-    if pad:
-        sub_texts = jnp.concatenate(
-            [sub_texts, jnp.full((pad, sub_texts.shape[1]), 4, sub_texts.dtype)])
-        sub_patterns = jnp.concatenate(
-            [sub_patterns, jnp.full((pad, sub_patterns.shape[1]), 4,
-                                    sub_patterns.dtype)])
-    d, r = _v2.window_dc_batch_v2(sub_texts, sub_patterns, w=w, k=k,
-                                  block_bt=bt, interpret=_interpret())
-    d, r = d[:b], r[:b]
-    if squeeze:
-        return d[0], r[0]
-    return d, r
-
-
-def bitalign_dc(bases, succ_bits, patterns, p_lens, *, m_bits: int, k: int,
-                block_bt: int | None = None):
-    """Batched BitAlign DC kernel (padded to tile)."""
-    from . import bitalign as _ba
-
-    b = bases.shape[0]
-    bt = block_bt or min(32, max(8, b))
-    pad = (-b) % bt
-    if pad:
-        bases = jnp.concatenate([bases, jnp.full((pad, bases.shape[1]), 4,
-                                                 bases.dtype)])
-        succ_bits = jnp.concatenate(
-            [succ_bits, jnp.zeros((pad, succ_bits.shape[1]), succ_bits.dtype)])
-        patterns = jnp.concatenate(
-            [patterns, jnp.full((pad, patterns.shape[1]), 4, patterns.dtype)])
-        p_lens = jnp.concatenate([p_lens, jnp.ones((pad,), p_lens.dtype)])
-    d, r = _ba.bitalign_dc_batch(bases, succ_bits, patterns, p_lens,
-                                 m_bits=m_bits, k=k, block_bt=bt,
-                                 interpret=_interpret())
-    return d[:b], r[:b]

@@ -41,6 +41,7 @@ from repro.core.genasm import GenASMConfig
 from repro.dist.fault import WorkQueue
 from repro.serve import EngineConfig, ServeEngine, Session, poisson_load
 from repro.genomics import io, simulate
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def paf_row(gid: int, res, ref_len: int) -> dict:
@@ -176,6 +177,7 @@ def main(argv=None):
                     help="serve /metrics /healthz /trace /attrib /roofline "
                          "on this port while running (0 = ephemeral)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     prof = simulate.PROFILES[args.profile]
     ref = simulate.random_reference(args.ref_len, seed=1)

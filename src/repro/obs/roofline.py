@@ -44,6 +44,8 @@ from dataclasses import dataclass, field
 from pathlib import Path
 
 SPEC_DIR = Path(__file__).with_name("device_specs")
+# JAX ``device_kind`` -> bundled spec (`DeviceSpec.for_device`)
+SPEC_OF_KIND = {"TPU v5 lite": "tpu_v5e", "cpu": "cpu_host"}
 
 # mirrors repro.core.bitvector.WORD_BITS without importing jax-adjacent code
 WORD_BITS = 32
@@ -99,18 +101,22 @@ class DeviceSpec:
         return cls.from_json(bundled)
 
     @classmethod
-    def for_platform(cls, platform: str | None = None) -> "DeviceSpec":
-        """Spec for the current (or named) JAX platform; cpu_host if JAX
-        is unavailable — `repro.obs` must work in kernel-free installs."""
-        if platform is None:
-            try:
-                import jax
+    def for_device(cls, kind: str | None = None) -> "DeviceSpec":
+        """Spec for a JAX ``device_kind`` (default: the first device's).
 
-                platform = jax.default_backend()
-            except Exception:
-                platform = "cpu"
-        return cls.load({"tpu": "tpu_v5e", "gpu": "gpu_generic"}.get(
-            platform, "cpu_host"))
+        An unknown kind is an error, never a default: a roofline share
+        against the wrong chip's peaks is a wrong number.
+        """
+        if kind is None:
+            import jax
+
+            kind = jax.devices()[0].device_kind
+        try:
+            return cls.load(SPEC_OF_KIND[kind])
+        except KeyError:
+            raise ValueError(
+                f"no device spec for device_kind {kind!r}; known kinds: "
+                f"{sorted(SPEC_OF_KIND)}") from None
 
     def roof_ops_per_s(self, intensity: float) -> float:
         """Attainable word-ops/s at ``intensity`` (ops/HBM byte)."""
@@ -161,9 +167,12 @@ def dc_window_counters(w: int, k: int, *, store: str = "mid") -> dict:
 
 
 def effective_block(block_bt: int | None, batch: int) -> int:
-    """The batch tile the kernel driver actually uses (`align.batched`
-    clamps ``block_bt`` to ``min(block_bt, max(8, batch))``)."""
-    return min(block_bt if block_bt else 128, max(8, batch))
+    """The batch tile the kernels actually launch with
+    (`repro.kernels.genasm_dc.batch_tile` on the current platform)."""
+    from repro.align.api import needs_interpret
+    from repro.kernels.genasm_dc import batch_tile
+
+    return batch_tile(block_bt, batch, interpret=needs_interpret())
 
 
 _STORE_OF = {"lax": "mid", "pallas_dc": "mid", "pallas_dc_v2": "r"}
@@ -227,7 +236,7 @@ def predict_block_bt(backend: str, bucket_cap: int, k: int, batch: int, *,
     larger tile on ties (fewer launches never hurts the model).  No
     device work: this is what ``REPRO_ALIGN_AUTOTUNE=model`` calls.
     """
-    spec = spec or DeviceSpec.for_platform()
+    spec = spec or DeviceSpec.for_device()
     best_bt, best_t = None, float("inf")
     for bt in sorted(set(effective_block(c, batch) for c in candidates)):
         t = predict_time_s(
@@ -320,7 +329,7 @@ class RooflineManager:
     def __init__(self, spec: DeviceSpec | None = None, *, metrics=None,
                  tracer=None, enabled: bool = True,
                  measure: bool = True) -> None:
-        self.spec = spec or DeviceSpec.for_platform()
+        self.spec = spec or DeviceSpec.for_device()
         self.metrics = metrics
         self.tracer = tracer
         self.enabled = enabled

@@ -532,6 +532,12 @@ class ServeEngine:
         """Number of compiled bucket executors currently cached."""
         return len(self._executors)
 
+    @property
+    def executors(self) -> tuple:
+        """The cached bucket executors (e.g. to check a sharded one's
+        ``spmd``)."""
+        return tuple(self._executors.values())
+
     # ------------------------------------------------------------- worker ----
     def _flush_candidate(self, now: float) -> tuple[int, list[_Request]] | None:
         """Pick a bucket to flush: the most-overdue one, else any full one.

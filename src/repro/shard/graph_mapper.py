@@ -111,6 +111,8 @@ class ShardedGraphMapExecutor:
                  align_sharded: bool = False,
                  prefilter: bool | None = None,
                  trace_hook=None):
+        from repro import align as align_dispatch
+
         validate_graph_geometry(sharded, p_cap=p_cap, filter_k=filter_k,
                                 cfg=cfg)
         shard_merge.check_graph_domain(n_tiles=sharded.n_tiles,
@@ -150,16 +152,18 @@ class ShardedGraphMapExecutor:
             filter_k=filter_k, max_candidates=shard_candidates,
             minimizer_w=sharded.minimizer_w,
             minimizer_k=sharded.minimizer_k,
-            use_kernel=False, block_bt=block_bt, interpret=True)
+            use_kernel=align_dispatch.get_backend(self.backend).uses_pallas,
+            block_bt=block_bt, interpret=align_dispatch.needs_interpret())
         pf_fn = partial(_pf_one_shard, static=static_pf)
 
         mesh = None if force_vmap else dist_sharding.shard_mesh(
             self.num_shards)
         self.spmd = mesh is not None
         if self.spmd:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
+            # check_vma=False: the per-shard bodies scan from replicated
+            # initial carries; every output is per-shard by construction
             arr_specs = tuple(dist_sharding.stacked_specs(
                 sharded.arrays, mesh))
 
@@ -169,9 +173,9 @@ class ShardedGraphMapExecutor:
                 out = pf_fn(*[a[0] for a in arrs], reads, lens)
                 return jax.tree.map(lambda x: x[None], out)
 
-            self._pf = jax.jit(shard_map(
+            self._pf = jax.jit(jax.shard_map(
                 block_pf, mesh=mesh, in_specs=arr_specs + (P(), P()),
-                out_specs=P("shard")))
+                out_specs=P("shard"), check_vma=False))
 
             def make_stage(n_cap):
                 stage = partial(_stage_one_shard, n_cap=n_cap,
@@ -184,10 +188,10 @@ class ShardedGraphMapExecutor:
                     out = stage(*[a[0] for a in arrs], reads, lens, pf0)
                     return jax.tree.map(lambda x: x[None], out)
 
-                return jax.jit(shard_map(
+                return jax.jit(jax.shard_map(
                     block_stage, mesh=mesh,
                     in_specs=arr_specs + (P(), P(), P("shard")),
-                    out_specs=P("shard")))
+                    out_specs=P("shard"), check_vma=False))
         else:
             def stacked_pf(*args):
                 self._hook(("prefilter",))
@@ -239,7 +243,6 @@ class ShardedGraphMapExecutor:
             margs = jax.tree.map(blocked, merged)
             rargs = (blocked(reads), blocked(lens))
             if mesh is not None:
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 def block(m, r, ln):
@@ -247,10 +250,10 @@ class ShardedGraphMapExecutor:
                                      r[0], ln[0])
                     return jax.tree.map(lambda y: y[None], out)
 
-                out = shard_map(
+                out = jax.shard_map(
                     block, mesh=mesh,
                     in_specs=(P("shard"), P("shard"), P("shard")),
-                    out_specs=P("shard"))(margs, *rargs)
+                    out_specs=P("shard"), check_vma=False)(margs, *rargs)
             else:
                 out = jax.vmap(align_core)(margs, *rargs)
             return jax.tree.map(

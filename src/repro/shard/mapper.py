@@ -204,9 +204,10 @@ class ShardedMapExecutor:
             self.num_shards)
         self.spmd = mesh is not None
         if self.spmd:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
+            # check_vma=False: the per-shard bodies scan from replicated
+            # initial carries; every output is per-shard by construction
             arr_specs = tuple(dist_sharding.stacked_specs(
                 sharded.arrays, mesh))
 
@@ -215,10 +216,10 @@ class ShardedMapExecutor:
                 out = stage(refs[0], offs[0], hashes[0], poss[0], reads, lens)
                 return jax.tree.map(lambda x: x[None], out)
 
-            self._stage = jax.jit(shard_map(
+            self._stage = jax.jit(jax.shard_map(
                 block_stage, mesh=mesh,
                 in_specs=arr_specs + (P(), P()),
-                out_specs=P("shard")))
+                out_specs=P("shard"), check_vma=False))
         else:
             def stacked_stage(refs, offs, hashes, poss, reads, lens):
                 hook(("scatter",))
@@ -266,16 +267,15 @@ class ShardedMapExecutor:
             args = tuple(blocked(x)
                          for x in (text, reads, lens, t_len, pos, fd))
             if mesh is not None:
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import PartitionSpec as P
 
                 def block(*rows):
                     out = align_core(*[r[0] for r in rows])
                     return jax.tree.map(lambda y: y[None], out)
 
-                out = shard_map(block, mesh=mesh,
-                                in_specs=(P("shard"),) * 6,
-                                out_specs=P("shard"))(*args)
+                out = jax.shard_map(
+                    block, mesh=mesh, in_specs=(P("shard"),) * 6,
+                    out_specs=P("shard"), check_vma=False)(*args)
             else:
                 out = jax.vmap(align_core)(*args)
             return jax.tree.map(

@@ -22,7 +22,7 @@ replaces that host step with a device reduction:
   differentially by ``tests/test_shard_merge.py``.
 
 JAX runs with ``x64`` disabled globally, so the 64-bit key only exists
-inside a `jax.experimental.enable_x64` scope: wrap calls to the jitted
+inside a `jax.enable_x64` scope: wrap calls to the jitted
 merge in `x64_scope` (the executors do).  Inputs and outputs are plain
 ``int32`` arrays, so nothing 64-bit leaks to callers.  The pack/unpack
 helpers are dtype-driven (``.astype``/shift/mask only), so they run
@@ -40,8 +40,8 @@ the 21-bit field max and `unpack_graph_key` restores them.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64 as x64_scope  # re-exported
 
 from repro.core.mapper import POS_SENTINEL
 
@@ -52,6 +52,11 @@ GRAPH_TILE_BITS = 21
 GRAPH_D_MAX = (1 << GRAPH_D_BITS) - 1
 GRAPH_ORIGIN_MAX = (1 << GRAPH_ORIGIN_BITS) - 1  # == POS_SENTINEL
 GRAPH_TILE_MAX = (1 << GRAPH_TILE_BITS) - 1  # sentinel encoding for tiles
+
+
+def x64_scope():
+    """Context manager enabling 64-bit types for the packed merge keys."""
+    return jax.enable_x64(True)
 
 
 def pack_linear_key(distance, position):

@@ -72,10 +72,8 @@ def make_pod_compressed_allreduce(mesh, param_specs_tree):
                              is_leaf=lambda x: isinstance(x, tuple))
         return reduced, resid
 
-    from jax.experimental.shard_map import shard_map
-
     specs = param_specs_tree
-    return shard_map(
+    return jax.shard_map(
         reduce_tree, mesh=mesh, in_specs=(specs, specs), out_specs=(specs, specs),
-        check_rep=False,
+        check_vma=False,
     )

@@ -89,6 +89,20 @@ def test_tiles_match_extract_subgraph():
             min(idx.tile_len, g.n_nodes - c * idx.tile_stride)
 
 
+def test_chunked_tile_build_matches_one_pass(monkeypatch):
+    """Tiles built a chunk of rows at a time equal the one-pass build."""
+    ref = simulate.random_reference(12_000, seed=4)
+    vs = simulate.simulate_variants(ref, n_snp=30, n_ins=15, n_del=15,
+                                    seed=5)
+    g = cgraph.build_graph(ref, vs)
+    args = (jnp.asarray(g.bases), jnp.asarray(g.succ_bits))
+    one = gindex._build_tiles(*args, tile_len=512, tile_stride=64)
+    monkeypatch.setattr(gindex, "TILE_CHUNK", 37)
+    chunked = gindex._build_tiles(*args, tile_len=512, tile_stride=64)
+    for a, b in zip(one, chunked):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_npz_roundtrip(tmp_path):
     ref = simulate.random_reference(800, seed=5)
     variants = simulate.simulate_variants(ref, n_snp=5, n_ins=2, n_del=2,

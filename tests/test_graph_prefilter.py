@@ -88,13 +88,11 @@ def _dense_slot_dists(gidx, arr, lens, pf, filter_k):
                                           jnp.asarray(lens, jnp.int32),
                                           P_CAP)
     wins = view.tile_gtext[tile_local]
-    dists = gmapper._filter_dists(
+    d, _ = gmapper._filter_best(
         wins.reshape(b * c, tile_len), jnp.repeat(fpat, c, axis=0),
-        jnp.repeat(flens, c), m_bits=P_CAP, k=filter_k, use_kernel=False,
-        block_bt=None, interpret=True).reshape(b, c, tile_len)
-    span_ok = jnp.arange(tile_len) < tile_len - T_CAP
-    dists = jnp.where(span_ok[None, None, :], dists, filter_k + 1)
-    return np.asarray(jnp.min(dists, axis=-1)), np.asarray(tile_g)
+        jnp.repeat(flens, c), span=tile_len - T_CAP, m_bits=P_CAP,
+        k=filter_k, use_kernel=False, block_bt=None, interpret=True)
+    return np.asarray(d).reshape(b, c), np.asarray(tile_g)
 
 
 # ------------------------------------------------------------- soundness --

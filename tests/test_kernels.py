@@ -100,3 +100,49 @@ def test_bitalign_kernel_matches_ref(rng, m_bits, k):
                                    m_bits=m_bits, k=k)
     np.testing.assert_array_equal(np.asarray(dk), np.asarray(dr))
     np.testing.assert_array_equal(np.asarray(rk_), np.asarray(rr))
+
+
+@pytest.mark.parametrize("batch,block_bt", [(1, None), (16, None), (32, 128),
+                                            (200, 128), (300, 256)])
+def test_batch_tile_is_lane_legal_when_compiled(batch, block_bt):
+    """Compiled launches only use whole 128-lane tiles; interpret mode
+    keeps the small CPU-friendly tile."""
+    from repro.kernels.genasm_dc import LANES, batch_tile
+
+    bt = batch_tile(block_bt, batch, interpret=False)
+    assert bt % LANES == 0 and bt >= min(block_bt or 128, batch)
+    assert batch_tile(block_bt, batch, interpret=True) == \
+        min(block_bt or 128, max(8, batch))
+
+
+@pytest.mark.parametrize("span", [20, 80])
+def test_bitalign_filter_kernel_matches_dc_min(rng, span):
+    """The filter kernel's (min, argmin) over the span equals the DC
+    kernel's per-node distances reduced the same way."""
+    from repro.core.segram import graph
+    from repro.genomics import simulate
+    from repro.kernels import bitalign
+
+    B, N, m_bits, k = 6, 80, 64, 8
+    bases = np.zeros((B, N), np.int8)
+    succ = np.zeros((B, N), np.uint32)
+    pats = np.full((B, m_bits), 4, np.int8)
+    plens = np.zeros((B,), np.int32)
+    for i in range(B):
+        refseq = rng.integers(0, 4, size=N - 10).astype(np.int8)
+        variants = simulate.simulate_variants(refseq, n_snp=2, n_ins=1,
+                                              n_del=1, seed=i)
+        g = graph.build_graph(refseq, variants)
+        bases[i], succ[i] = graph.extract_subgraph(g, 0, N)
+        m = int(rng.integers(10, 40))
+        s0 = int(rng.integers(0, 20))
+        pats[i, :m] = refseq[s0:s0 + m]
+        plens[i] = m
+    args = [jnp.asarray(x) for x in (bases, succ, pats, plens)]
+    dists, _ = ops.bitalign_dc(*args, m_bits=m_bits, k=k, block_bt=4)
+    d, off = bitalign.bitalign_filter_batch(*args, m_bits=m_bits, k=k,
+                                            span=span, block_bt=4,
+                                            interpret=True)
+    masked = np.where(np.arange(N) < span, np.asarray(dists), k + 1)
+    np.testing.assert_array_equal(np.asarray(d), masked.min(-1))
+    np.testing.assert_array_equal(np.asarray(off), masked.argmin(-1))

@@ -339,6 +339,15 @@ def test_device_spec_load_and_roof():
         assert DeviceSpec.load(name).peak_word_ops > 0
 
 
+def test_device_spec_keyed_by_device_kind():
+    """Specs follow JAX's device_kind; an unknown kind is an error."""
+    assert DeviceSpec.for_device("TPU v5 lite").name == "tpu_v5e"
+    assert DeviceSpec.for_device("cpu").name == "cpu_host"
+    assert DeviceSpec.for_device().name == "cpu_host"  # this test host
+    with pytest.raises(ValueError, match="device_kind"):
+        DeviceSpec.for_device("TPU v9 imaginary")
+
+
 def test_predict_block_bt_prefers_fewer_launches_under_overhead():
     # launch overhead dominates at tiny work sizes -> pick the largest
     # tile that fits the batch (one launch per window)

@@ -118,3 +118,14 @@ def test_segram_end_to_end_maps_reads(rng):
                            m_bits=128, k=16, win_len=192, minimizer_w=8,
                            minimizer_k=12)
     assert int(np.sum(~np.asarray(out["failed"]))) >= 5
+
+
+@pytest.mark.parametrize("n,chunk", [(5000, 700), (20011, 4096), (300, 64)])
+def test_chunked_minimizers_match_one_pass(rng, n, chunk):
+    """The index build's chunked device passes give the one-pass bytes."""
+    ref = rng.integers(0, 4, n).astype(np.int8)
+    ref[rng.integers(0, n, 20)] = 4  # N bases: invalid k-mers
+    is_min, h = minimizer.minimizers(jnp.asarray(ref), w=10, k=15)
+    c_min, c_h = minimizer.host_minimizers(ref, w=10, k=15, chunk=chunk)
+    np.testing.assert_array_equal(np.asarray(is_min), c_min)
+    np.testing.assert_array_equal(np.asarray(h), c_h)

@@ -30,6 +30,21 @@ def reads(ref):
                                    profile=simulate.ILLUMINA, seed=6)
 
 
+def test_shard_mesh_never_folds_shards_onto_one_accelerator(monkeypatch):
+    """Too few devices: the CPU test path gets no mesh (stacked vmap);
+    an accelerator raises instead of running every shard on one chip."""
+    import jax
+
+    from repro.dist import sharding
+
+    n = jax.device_count() + 1
+    assert sharding.shard_mesh(1) is None
+    assert sharding.shard_mesh(n) is None  # CPU: stacked-vmap fallback
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match=f"num_shards={n} needs {n}"):
+        sharding.shard_mesh(n)
+
+
 def test_plan_layout_bounds_and_slices():
     lay = shard.plan_layout(1000, 4, halo=100)
     assert lay.bounds == (0, 250, 500, 750, 1000)

@@ -96,3 +96,23 @@ def test_genasm_score_parity_vs_dp():
             close += 1
     assert total >= 12
     assert close / total >= 0.8, f"{close}/{total}"
+
+
+def test_compile_cache_dir_follows_env(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise a fixed
+    directory inside the checkout."""
+    from repro.launch import compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert compile_cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir is None  # JAX's own
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        got = compile_cache.enable_compile_cache()
+        assert got == str(compile_cache.CHECKOUT / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+        assert (compile_cache.CHECKOUT / "chip_smoke.py").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

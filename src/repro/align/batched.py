@@ -80,16 +80,18 @@ def batched_kernel_align(
         cur_p, cur_t = carry[0], carry[1]  # each [B]
         sub_p = slice_w(pats, cur_p)  # [B, w]
         sub_t = slice_w(txts, cur_t)
-        d_min, tb = dc(sub_t, sub_p, w=w, k=k, block_bt=block_bt,
-                       interpret=interpret)
+        with jax.named_scope("dc_window"):
+            d_min, tb = dc(sub_t, sub_p, w=w, k=k, block_bt=block_bt,
+                           interpret=interpret)
         cap_p = jnp.minimum(jnp.int32(cfg.commit), p_lens - cur_p)
-        if store_r:
-            pm = jax.vmap(lambda p: pattern_bitmasks(p, w))(sub_p)
-            pc, tc, err, ops, n_ops, stuck = tb_fn(
-                tb, sub_t, pm, jnp.minimum(d_min, k), cap_p)
-        else:
-            pc, tc, err, ops, n_ops, stuck = tb_fn(
-                tb, jnp.minimum(d_min, k), cap_p)
+        with jax.named_scope("traceback"):
+            if store_r:
+                pm = jax.vmap(lambda p: pattern_bitmasks(p, w))(sub_p)
+                pc, tc, err, ops, n_ops, stuck = tb_fn(
+                    tb, sub_t, pm, jnp.minimum(d_min, k), cap_p)
+            else:
+                pc, tc, err, ops, n_ops, stuck = tb_fn(
+                    tb, jnp.minimum(d_min, k), cap_p)
         new_carry, n_emit = window_commit(
             carry, d_min=d_min, pc=pc, tc=tc, err=err, n_ops=n_ops,
             stuck=stuck, p_len=p_lens, k=k)

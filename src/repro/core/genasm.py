@@ -142,20 +142,23 @@ def align(
         cur_p, cur_t = carry[0], carry[1]
         sub_p = lax.dynamic_slice(pat, (cur_p,), (w,))
         sub_t = lax.dynamic_slice(txt, (cur_t,), (w,))
-        d_min, tb = dc_fn(sub_t, sub_p)
+        # the same stage scopes as the Pallas path (align/batched.py)
+        with jax.named_scope("dc_window"):
+            d_min, tb = dc_fn(sub_t, sub_p)
         cap_p = jnp.minimum(jnp.int32(cfg.commit), p_len - cur_p)
-        if cfg.store_r:
-            from .bitvector import pattern_bitmasks
-            from .genasm_tb import window_tb_r
+        with jax.named_scope("traceback"):
+            if cfg.store_r:
+                from .bitvector import pattern_bitmasks
+                from .genasm_tb import window_tb_r
 
-            pm = pattern_bitmasks(sub_p, w)
-            pc, tc, err, ops, n_ops, stuck = window_tb_r(
-                tb, sub_t, pm, jnp.minimum(d_min, k), cap_p, w=w, o=o, k=k,
-                affine=cfg.affine)
-        else:
-            pc, tc, err, ops, n_ops, stuck = window_tb(
-                tb, jnp.minimum(d_min, k), cap_p, w=w, o=o, k=k,
-                affine=cfg.affine)
+                pm = pattern_bitmasks(sub_p, w)
+                pc, tc, err, ops, n_ops, stuck = window_tb_r(
+                    tb, sub_t, pm, jnp.minimum(d_min, k), cap_p, w=w, o=o,
+                    k=k, affine=cfg.affine)
+            else:
+                pc, tc, err, ops, n_ops, stuck = window_tb(
+                    tb, jnp.minimum(d_min, k), cap_p, w=w, o=o, k=k,
+                    affine=cfg.affine)
         new_carry, n_emit = window_commit(
             carry, d_min=d_min, pc=pc, tc=tc, err=err, n_ops=n_ops,
             stuck=stuck, p_len=p_len, k=k)

@@ -11,13 +11,14 @@ minimizer index replicated or sharded over ``"model"`` (DESIGN.md §5).
 """
 from __future__ import annotations
 
-import time
 from functools import partial
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.obs.trace import StageTimer
 
 from .bitvector import SENTINEL, WILDCARD
 from .genasm import GenASMConfig
@@ -101,23 +102,19 @@ def seed_filter_read(
     seed hits), with the ``[t_cap]`` alignment text sliced from
     ``ref_buf``.
     """
-    starts, votes = seed_candidates(
-        read, hashes, positions,
-        w=minimizer_w, k=minimizer_k, max_candidates=max_candidates,
-    )
+    # named scopes label the device ops of each stage in the profiler's
+    # op metadata (they change no generated code)
+    with jax.named_scope("seeding"):
+        starts, votes = seed_candidates(
+            read, hashes, positions,
+            w=minimizer_w, k=minimizer_k, max_candidates=max_candidates,
+        )
     # candidate starts are diagonal-bucketed to 32 (minimizer voting), so the
     # filter window must absorb bucket quantization + k edits of drift
     margin = filter_k + 32
 
     # --- pre-alignment filter (use case 2): exact distance of the read's
     # first filter_bits bases against each candidate region prefix.
-    fpat = jnp.where(
-        jnp.arange(filter_bits) < jnp.minimum(read_len, filter_bits),
-        read[:filter_bits], WILDCARD,
-    ).astype(jnp.int8)
-    region_pad = jnp.concatenate(
-        [ref_buf, jnp.full((filter_bits + 2 * margin,), SENTINEL, jnp.int8)])
-
     def filt(s):
         s0 = jnp.clip(s - margin, 0, jnp.maximum(ref_len - 1, 0))
         region = jax.lax.dynamic_slice(
@@ -125,10 +122,18 @@ def seed_filter_read(
         dists = bitap_search(region, fpat, m_bits=filter_bits, k=filter_k)
         return jnp.min(dists), s0 + jnp.argmin(dists).astype(jnp.int32)
 
-    fd, fpos = jax.vmap(filt)(starts)
-    fd = jnp.where(votes > 0, fd, filter_k + 1)
-    fpos = jnp.where(votes > 0, fpos, POS_SENTINEL)
-    best = lex_best(fd, fpos)
+    with jax.named_scope("dc_filter"):
+        fpat = jnp.where(
+            jnp.arange(filter_bits) < jnp.minimum(read_len, filter_bits),
+            read[:filter_bits], WILDCARD,
+        ).astype(jnp.int8)
+        region_pad = jnp.concatenate(
+            [ref_buf,
+             jnp.full((filter_bits + 2 * margin,), SENTINEL, jnp.int8)])
+        fd, fpos = jax.vmap(filt)(starts)
+        fd = jnp.where(votes > 0, fd, filter_k + 1)
+        fpos = jnp.where(votes > 0, fpos, POS_SENTINEL)
+        best = lex_best(fd, fpos)
     pos = fpos[best]
     prefilter_ok = fd[best] <= filter_k
 
@@ -302,17 +307,14 @@ class LinearMapExecutor:
 
     def __call__(self, index: ReferenceIndex, reads, read_lens) -> MapResult:
         lens = jnp.asarray(read_lens)
-        before = set(self._compiled)
-        t0 = time.monotonic()
-        sf = self._sf(index, jnp.asarray(reads), lens)
-        jax.block_until_ready(sf)
-        t1 = time.monotonic()
-        res = self._align(sf, lens)
-        jax.block_until_ready(res)
-        t2 = time.monotonic()
-        new = self._compiled - before
-        self.last_times = [
-            ("seed_filter", t0, t1, {"compile": ("seed_filter",) in new}),
-            ("align", t1, t2, {"compile": ("align",) in new}),
-        ]
+        timer = StageTimer()
+        # a stage not traced before traces (compiles) in this call
+        with timer.stage("seed_filter",
+                         compile=("seed_filter",) not in self._compiled):
+            sf = self._sf(index, jnp.asarray(reads), lens)
+            jax.block_until_ready(sf)
+        with timer.stage("align", compile=("align",) not in self._compiled):
+            res = self._align(sf, lens)
+            jax.block_until_ready(res)
+        self.last_times = timer.times
         return res

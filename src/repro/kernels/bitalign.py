@@ -181,6 +181,7 @@ def bitalign_dc_batch(bases, succ_bits, patterns, p_lens, *, m_bits: int,
         ],
         scratch_shapes=scratch,
         interpret=interpret,
+        name="bitalign_dc",
     )(node, pm, tail)
     r = r.reshape(n, k + 1, nw, bp).transpose(3, 0, 1, 2)
     return dists.T[:b], r[:b]
@@ -215,5 +216,6 @@ def bitalign_filter_batch(bases, succ_bits, patterns, p_lens, *, m_bits: int,
         out_shape=[jax.ShapeDtypeStruct((1, bp), jnp.int32)] * 2,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="bitalign_filter",
     )(node, pm, tail)
     return best[0, :b], off[0, :b]

@@ -193,6 +193,7 @@ def window_dc_batch(
             jax.ShapeDtypeStruct((w, rows, bp), jnp.uint32),
         ],
         interpret=interpret,
+        name="genasm_dc",
     )(text, pm)
     tb = tb.reshape(w, k + 1, 3, nw, bp).transpose(4, 0, 1, 2, 3)
     return d[0, :b], tb[:b]

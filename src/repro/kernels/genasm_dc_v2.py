@@ -83,6 +83,7 @@ def window_dc_batch_v2(sub_texts, sub_patterns, *, w: int = 64, k: int = 24,
             jax.ShapeDtypeStruct((w + 1, rows, bp), jnp.uint32),
         ],
         interpret=interpret,
+        name="genasm_dc_v2",
     )(text, pm)
     r = r.reshape(w + 1, k + 1, nw, bp).transpose(3, 0, 1, 2)
     return d[0, :b], r[:b]

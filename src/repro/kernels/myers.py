@@ -125,4 +125,5 @@ def myers_distance_batch(
         out_specs=pl.BlockSpec((block_bt,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((b,), jnp.int32),
         interpret=interpret,
+        name="myers",
     )(texts, patterns, m_lens)

@@ -7,10 +7,12 @@ automatically on every serving and benchmark run (DESIGN.md §12):
 
 * `trace` — thread-safe monotonic-clock `Span`/`Tracer` with parent
   links and per-span attributes, a ring-buffer `TraceLog`, Chrome/
-  Perfetto ``trace_event`` JSON export, and a structured JSONL sink.
+  Perfetto ``trace_event`` JSON export, a structured JSONL sink, and
+  ``jax.profiler`` annotations of the same names (JAX imported lazily).
 * `attrib` — folds finished spans into a per-stage wall-time ledger
-  (enqueue-wait → seed/filter → graph prefilter → DC filter → shard
-  scatter → host merge → align → emit) and renders the Amdahl report:
+  (enqueue-wait → dispatch → encode → seed/filter → graph prefilter →
+  DC filter → shard scatter → host merge → align → fetch → emit →
+  trace replay) and renders the Amdahl report:
   serial fraction, per-stage p50/p99, projected speedup from sharding
   each stage.
 * `http` — stdlib exposition endpoint serving ``/metrics`` (the
@@ -25,7 +27,8 @@ automatically on every serving and benchmark run (DESIGN.md §12):
 
 Stdlib-only at import by design: it must import (and stay cheap) in
 every environment the serving path runs in, kernels or not — the
-roofline module's measured side lazy-imports `jax` only when asked.
+roofline module's measured side and the tracer's profiler annotations
+lazy-import `jax` only when used.
 """
 from .attrib import (AttributionReport, StageLedger, build_ledger,
                      render_report)

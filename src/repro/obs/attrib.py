@@ -5,6 +5,7 @@
 per canonical pipeline stage —
 
     enqueue_wait   admission-queue wait (submit → flush start)
+    dispatch       index snapshot + executor lookup (pipelined: + launch)
     encode         host read batching/padding
     seed_filter    linear seed + GenASM-DC pre-alignment filter
     prefilter      graph seed + q-gram tile screen (no DC)
@@ -14,8 +15,13 @@ per canonical pipeline stage —
     merge_device   packed-key argmin-reduce of shard winners on device
     align          windowed GenASM/BitAlign alignment of the winners
     align_shard    the same align stage sharded over the shard mesh
-    emit           result materialization, cache put, future resolution
+    fetch          device→host copies of the flush's results
+    emit           metrics, cache put, future resolution
+    trace_replay   the tracer's own per-flush bookkeeping (tracing cost)
     other          flush time not covered by any child stage span
+
+The worker's ``worker_wait`` spans (flush end → next pick) lie outside
+flushes and stay out of the ledger.
 
 Stage spans parented by a ``flush`` span additionally feed the coverage
 accounting: ``coverage`` is attributed-stage time over total flush time,
@@ -40,9 +46,10 @@ from typing import Iterable, NamedTuple
 from .trace import Span, TraceLog
 
 # canonical stage order (pipeline position, not size)
-STAGE_ORDER = ("enqueue_wait", "encode", "seed_filter", "prefilter",
-               "dc_filter", "scatter", "merge", "merge_device", "align",
-               "align_shard", "emit", "other")
+STAGE_ORDER = ("enqueue_wait", "dispatch", "encode", "seed_filter",
+               "prefilter", "dc_filter", "scatter", "merge", "merge_device",
+               "align", "align_shard", "fetch", "emit", "trace_replay",
+               "other")
 _STAGE_SET = frozenset(STAGE_ORDER)
 
 # stages whose current implementation already scales with shards; the

@@ -23,9 +23,15 @@ The log exports two ways:
   t_start/t_end, duration, parent, tid, attrs), the machine-readable
   sink for offline analysis.
 
-Everything is stdlib; a disabled tracer (`NULL_TRACER`) costs one
-attribute check per call site, which is what keeps tracing overhead on
-the serving hot path under the 3% budget (EXPERIMENTS.md perf #18).
+An enabled tracer also enters a ``jax.profiler.TraceAnnotation`` of the
+same name around each scoped span, so a ``jax.profiler`` capture shows the
+engine's stages on its host plane, on the device trace's clock;
+`StageTimer` does the same for executor stages, with or without a
+tracer.  JAX is imported on first use, so this module itself is
+stdlib-only, and without JAX the annotations are skipped.  A disabled
+tracer (`NULL_TRACER`) costs one attribute check per call site, which
+is what keeps tracing overhead on the serving hot path under the 3%
+budget (EXPERIMENTS.md perf #18).
 """
 from __future__ import annotations
 
@@ -34,8 +40,23 @@ import json
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
+
+_annotation = None  # jax.profiler.TraceAnnotation, bound on first use
+
+
+def profiler_annotation(name: str):
+    """Context manager marking ``name`` in an active ``jax.profiler``
+    capture (about a microsecond when none is active); a no-op where
+    JAX is not installed."""
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation as _annotation
+        except ImportError:
+            _annotation = lambda name: nullcontext()  # noqa: E731
+    return _annotation(name)
 
 
 @dataclass
@@ -210,18 +231,23 @@ class Tracer:
 
     # ------------------------------------------------------------ surface --
     @contextmanager
-    def span(self, name: str, **attrs):
-        """Scoped span: ``with tracer.span("flush", bucket_cap=320) as s:``."""
+    def span(self, name: str, *, t_start: float | None = None, **attrs):
+        """Scoped span: ``with tracer.span("flush", bucket_cap=320) as s:``.
+
+        ``t_start`` (monotonic, already past) starts the span where a span
+        recorded before it ended, so that no moment falls between them."""
         if not self.enabled:
             yield _NULL_SPAN
             return
-        s = Span(name=name, t_start=time.monotonic(),
+        s = Span(name=name,
+                 t_start=time.monotonic() if t_start is None else t_start,
                  span_id=next(self._ids), parent_id=self.current_parent(),
                  tid=self._tid(), attrs=attrs)
         st = self._stack()
         st.append(s.span_id)
         try:
-            yield s
+            with profiler_annotation(name):
+                yield s
         finally:
             st.pop()
             s.t_end = time.monotonic()
@@ -230,7 +256,8 @@ class Tracer:
     def add(self, name: str, t_start: float, t_end: float, *,
             tid: str | None = None, parent: int | None = None,
             async_: bool = False, **attrs) -> None:
-        """Retroactive span from timestamps already on the monotonic clock."""
+        """Retroactive span from timestamps already on the monotonic clock
+        (the tracer's log only: the profiler cannot take past events)."""
         if not self.enabled:
             return
         self.log.append(Span(
@@ -272,10 +299,11 @@ class StageTimer:
     """Per-call stage clock executors use to fill their ``last_times``.
 
     Records ``(stage, t_start, t_end, attrs)`` tuples — the engine (or a
-    benchmark) replays them into a `Tracer` via ``add()``.  Callers must
-    block on the stage's device work inside the ``stage()`` scope
-    (``jax.block_until_ready`` / ``np.asarray``) or the interval only
-    measures async dispatch.
+    benchmark) replays them into a `Tracer` via ``add()`` — and marks each
+    stage in an active ``jax.profiler`` capture under its own name.
+    Callers must block on the stage's device work inside the ``stage()``
+    scope (``jax.block_until_ready`` / ``np.asarray``) or the interval
+    only measures async dispatch.
     """
 
     def __init__(self) -> None:
@@ -284,8 +312,9 @@ class StageTimer:
     @contextmanager
     def stage(self, name: str, **attrs):
         """Scope one stage: appends ``(name, t0, t1, attrs)`` on exit."""
-        t0 = time.monotonic()
-        try:
-            yield
-        finally:
-            self.times.append((name, t0, time.monotonic(), attrs))
+        with profiler_annotation(name):
+            t0 = time.monotonic()
+            try:
+                yield
+            finally:
+                self.times.append((name, t0, time.monotonic(), attrs))

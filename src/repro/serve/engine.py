@@ -42,6 +42,7 @@ from dataclasses import dataclass, field
 from functools import partial
 from typing import NamedTuple, Sequence
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import mapper
@@ -174,6 +175,16 @@ class _Request:
     future: Future
     digest: bytes | None = None  # computed once in submit(), reused by put()
     t_submit: float = field(default_factory=time.monotonic)
+
+
+class _HostResult(NamedTuple):
+    """A flush's results on the host: what `ServeEngine._deliver` reads."""
+
+    position: np.ndarray
+    distance: np.ndarray
+    ops: np.ndarray
+    n_ops: np.ndarray
+    path: np.ndarray | None
 
 
 class _PendingFlush(NamedTuple):
@@ -383,9 +394,6 @@ class ServeEngine:
             self.metrics.gauge("queue_depth").set(
                 sum(len(q) for q in self._queues.values()))
             self._cv.notify_all()  # the worker may not be the FIFO waiter
-        if self.tracer.enabled:
-            self.tracer.event("submit", bucket=req.bucket,
-                              length=req.length)
         return fut
 
     def map_all(self, reads: Sequence[np.ndarray]) -> list[ServeResult]:
@@ -575,6 +583,8 @@ class ServeEngine:
 
     def _run(self) -> None:
         picked: tuple[int, list[_Request]] | None = None
+        tr = self.tracer
+        t_idle = None  # end of the worker's last span: a worker_wait starts
         try:
             while True:
                 action = "stop"
@@ -600,18 +610,25 @@ class ServeEngine:
                                       else min(wait, 0.05))
                     self.metrics.gauge("queue_depth").set(
                         sum(len(q) for q in self._queues.values()))
+                t_pick = time.monotonic()
+                if tr.enabled and t_idle is not None:
+                    # the stretch from the last flush's end to this pick:
+                    # the inflight release, the lock, the queue scan and
+                    # any wait for a bucket to become ready
+                    tr.add("worker_wait", t_idle, t_pick)
                 if action == "stop":
-                    self._finish_pending()
+                    self._release(self._finish_pending()[0])
                     return
                 if action == "finish":
-                    self._finish_pending()
-                    continue
-                cap, reqs = picked  # compute outside the lock
-                if self.config.pipelined:
-                    self._execute_pipelined(cap, reqs)
+                    done, t_idle = self._finish_pending()
                 else:
-                    self._execute(cap, reqs)
-                picked = None
+                    cap, reqs = picked  # compute outside the lock
+                    if self.config.pipelined:
+                        done, t_idle = self._execute_pipelined(cap, reqs)
+                    else:
+                        done, t_idle = self._execute(cap, reqs, t_pick)
+                    picked = None
+                self._release(done)
         except BaseException as e:  # noqa: BLE001 — worker must not die silently
             with self._cv:
                 self._error = e
@@ -629,54 +646,67 @@ class ServeEngine:
                 self._inflight = 0
                 self._cv.notify_all()
 
-    def _execute_pipelined(self, cap: int, reqs: list[_Request]) -> None:
+    def _release(self, n: int) -> None:
+        """Count ``n`` delivered reads out of flight; wakes drain()."""
+        if n:
+            with self._cv:
+                self._inflight -= n
+                self._cv.notify_all()
+
+    def _execute_pipelined(self, cap: int, reqs: list[_Request]
+                           ) -> tuple[int, float]:
         """Dispatch a flush without materializing it; finish the previous.
 
         Double buffering, one batch deep: batch *i+1*'s encode + scatter
         + device merge dispatch overlaps batch *i*'s still-running align
         (the executors' ``start`` surface never syncs between stages).
+        Returns ``(reads delivered, end of the worker's last span)``: the
+        previous flush's reads.
         """
         prev, self._pending = self._pending, None
-        c = self.config
+        tr = self.tracer
         try:
             t_flush = self._clock()
-            index, epoch = self.index.current()
-            fn = self._executor(cap, index.layout_key, sharded_index=index)
-            arr, lens = encode.batch_reads(
-                [r.read for r in reqs]
-                + [np.zeros(0, np.int8)] * (c.max_batch - len(reqs)), cap)
-            pending = fn.start(index.arrays, arr, lens, timed=False)
+            # no flush span yet (it opens when the batch is finished), so
+            # encode and dispatch are top-level spans of the worker
+            arr = self._encode(cap, reqs)
+            with tr.span("dispatch", bucket_cap=cap, pipelined=True):
+                index, epoch = self.index.current()
+                fn = self._executor(cap, index.layout_key,
+                                    sharded_index=index)
+                lens = self._lengths(cap, reqs)
+                pending = fn.start(index.arrays, arr, lens, timed=False)
             self._pending = _PendingFlush(cap, reqs, fn, pending, epoch,
                                           lens, t_flush)
         except BaseException:
             self._pending = prev  # the worker handler fails prev too
             raise
-        if prev is not None:
-            self._finish_flush(prev)
+        if prev is None:
+            return 0, time.monotonic()
+        return self._finish_flush(prev)
 
-    def _finish_pending(self) -> None:
+    def _finish_pending(self) -> tuple[int, float]:
         prev, self._pending = self._pending, None
-        if prev is not None:
-            self._finish_flush(prev)
+        if prev is None:
+            return 0, time.monotonic()
+        return self._finish_flush(prev)
 
-    def _finish_flush(self, state: _PendingFlush) -> None:
-        """Materialize a dispatched flush and deliver its results."""
+    def _finish_flush(self, state: _PendingFlush) -> tuple[int, float]:
+        """Materialize a dispatched flush and deliver its results; returns
+        ``(reads delivered, end of the flush)``."""
         c, tr = self.config, self.tracer
         cap, reqs = state.cap, state.reqs
         try:
             with tr.span("flush", bucket_cap=cap, batch=len(reqs),
                          workload=c.workload, shards=c.num_shards,
-                         pipelined=True):
-                if tr.enabled:
-                    for r in reqs:
-                        tr.add("enqueue_wait", r.t_submit, state.t_flush,
-                               bucket_cap=cap, async_=True)
-                res, times = state.fn.finish(state.pending)
+                         pipelined=True) as flush:
+                with tr.span("fetch", bucket_cap=cap):
+                    res, times = state.fn.finish(state.pending)
                 state.fn.last_times = list(times)
-                for name, t0, t1, attrs in times:
-                    tr.add(name, t0, t1, bucket_cap=cap, **attrs)
                 self._deliver(cap, reqs, state.epoch, state.lens, res,
                               getattr(state.pending, "stats", None))
+                t_replay = self._replay(flush, cap, reqs, state.t_flush,
+                                        times, None)
         except BaseException as e:
             # this flush's futures die here; the worker handler that
             # re-raises cannot see them anymore (self._pending is clear)
@@ -684,33 +714,58 @@ class ServeEngine:
                 if not r.future.done():
                     r.future.set_exception(e)
             raise
-        with self._cv:
-            self._inflight -= len(reqs)
-            self._cv.notify_all()
+        return len(reqs), self._end_flush(flush, cap, t_replay)
+
+    def _lengths(self, cap: int, reqs: list[_Request]) -> np.ndarray:
+        """``[max_batch]`` read lengths as `encode.batch_reads` gives them:
+        trimmed to the cap, 0 on padding rows."""
+        lens = np.zeros(self.config.max_batch, np.int32)
+        lens[:len(reqs)] = [min(r.length, cap) for r in reqs]
+        return lens
+
+    def _encode(self, cap: int, reqs: list[_Request]) -> np.ndarray:
+        """The flush's reads as one ``[max_batch, cap]`` int8 batch."""
+        with self.tracer.span("encode", bucket_cap=cap):
+            return encode.batch_reads(
+                [r.read for r in reqs]
+                + [np.zeros(0, np.int8)] * (self.config.max_batch - len(reqs)),
+                cap)[0]
+
+    def _fetch(self, cap: int, res) -> _HostResult:
+        """Device→host copies of the result fields `_deliver` reads."""
+        with self.tracer.span("fetch", bucket_cap=cap):
+            host = _HostResult(
+                np.asarray(res.position), np.asarray(res.distance),
+                np.asarray(res.ops), np.asarray(res.n_ops),
+                np.asarray(res.path) if self.config.workload == "graph"
+                else None)
+            # the last reference to the device results (callers pass them
+            # unbound): their buffers are freed here, not unnamed later
+            del res
+        return host
 
     def _deliver(self, cap: int, reqs: list[_Request], epoch, lens, res,
                  stats) -> None:
-        """Flush tail shared by both modes: metrics, cache, futures."""
-        c, tr, m = self.config, self.tracer, self.metrics
-        pos = np.asarray(res.position)
-        dist = np.asarray(res.distance)
-        ops = np.asarray(res.ops)
-        n_ops = np.asarray(res.n_ops)
-        paths = np.asarray(res.path) if c.workload == "graph" else None
+        """Flush tail shared by both modes, on host results: metrics,
+        cache, futures."""
+        c, m = self.config, self.metrics
+        with self.tracer.span("emit", bucket_cap=cap):
+            pos, dist, ops, n_ops = (res.position, res.distance, res.ops,
+                                     res.n_ops)
+            paths = res.path if c.workload == "graph" else None
+            m.counter("batches_flushed").inc()
+            m.counter(f"batches_flushed_cap{cap}").inc()
+            m.histogram("batch_occupancy", lo=1e-3, hi=1.0).observe(
+                len(reqs) / c.max_batch)
+            real = int(sum(min(r.length, cap) for r in reqs))
+            m.counter("bases_useful").inc(real)
+            m.counter("bases_padded_read").inc(len(reqs) * cap - real)
+            m.counter("bases_padded_slot").inc(
+                (c.max_batch - len(reqs)) * cap)
+            if stats:  # graph executors: tile-screen / DC-occupancy
+                for name, v in stats.items():
+                    m.counter(f"graph_{name}").inc(int(v))
 
-        m.counter("batches_flushed").inc()
-        m.counter(f"batches_flushed_cap{cap}").inc()
-        m.histogram("batch_occupancy", lo=1e-3, hi=1.0).observe(
-            len(reqs) / c.max_batch)
-        real = int(sum(min(r.length, cap) for r in reqs))
-        m.counter("bases_useful").inc(real)
-        m.counter("bases_padded_read").inc(len(reqs) * cap - real)
-        m.counter("bases_padded_slot").inc((c.max_batch - len(reqs)) * cap)
-        if stats:  # graph executors: tile-screen / DC-occupancy
-            for name, v in stats.items():
-                m.counter(f"graph_{name}").inc(int(v))
-
-        with tr.span("emit", bucket_cap=cap):
             done = self._clock()
             results = []
             for i, r in enumerate(reqs):
@@ -728,36 +783,79 @@ class ServeEngine:
             for r, out in zip(reqs, results):
                 r.future.set_result(out)
 
-    def _execute(self, cap: int, reqs: list[_Request]) -> None:
+    def _replay(self, flush, cap: int, reqs: list[_Request], t_flush: float,
+                times, kc) -> float | None:
+        """The tracer's per-flush bookkeeping, the flush's last work: each
+        request's queue wait (async: waits overlap the previous flush's
+        compute) and the executor's per-stage monotonic windows
+        (seed_filter/prefilter/dc_filter/scatter/merge_device/align/
+        align_shard, with compile/dc_rows/shard attrs; the align span
+        carries the analytic counters when modeled), all as children of
+        ``flush``.  Returns when it started (None with tracing off): the
+        caller records ``trace_replay`` from there to the flush's end, so
+        that one span holds what tracing costs the worker, closing the
+        flush span included."""
+        tr = self.tracer
+        if not tr.enabled:
+            return None
+        t0 = time.monotonic()
+        parent = flush.span_id
+        for r in reqs:
+            tr.add("enqueue_wait", r.t_submit, t_flush, parent=parent,
+                   bucket_cap=cap, async_=True)
+        for name, t_a, t_b, attrs in times:
+            if name in ("align", "align_shard") and kc is not None:
+                attrs = {**attrs, "word_ops": kc.word_ops,
+                         "hbm_bytes": kc.hbm_bytes}
+            tr.add(name, t_a, t_b, parent=parent, bucket_cap=cap, **attrs)
+        return t0
+
+    def _end_flush(self, flush, cap: int, t_replay: float | None) -> float:
+        """After the flush span closes: its ``trace_replay`` child, and the
+        flush's end (where the next ``worker_wait`` starts)."""
+        if t_replay is None:
+            return time.monotonic()
+        self.tracer.add("trace_replay", t_replay, flush.t_end,
+                        parent=flush.span_id, bucket_cap=cap)
+        return flush.t_end
+
+    def _execute(self, cap: int, reqs: list[_Request], t_pick: float
+                 ) -> tuple[int, float]:
+        """One flush; returns ``(reads delivered, end of the flush)``.
+
+        The flush and its ``dispatch`` start at ``t_pick``, where the
+        worker's ``worker_wait`` ended, and the next ``worker_wait``
+        starts at the flush's end: no moment of the worker falls between
+        them, not even this call and its return (freeing the locals)."""
         c = self.config
         tr = self.tracer
         t_flush = self._clock()
-        with tr.span("flush", bucket_cap=cap, batch=len(reqs),
-                     workload=c.workload, shards=c.num_shards):
-            if tr.enabled:
-                # queue waits overlap the previous flush's compute, so
-                # they export as async spans (outside the slice nesting)
-                for r in reqs:
-                    tr.add("enqueue_wait", r.t_submit, t_flush,
-                           bucket_cap=cap, async_=True)
-            index, epoch = self.index.current()
-            if c.num_shards > 1:
-                payload = index.arrays
-                fn = self._executor(cap, index.layout_key,
-                                    sharded_index=index)
-            elif c.workload == "graph":
-                payload = index.arrays
-                fn = self._executor(cap, index.tile_stride)
-            else:
-                payload = index
-                fn = self._executor(cap)
-            with tr.span("encode", bucket_cap=cap):
-                arr, lens = encode.batch_reads(
-                    [r.read for r in reqs]
-                    + [np.zeros(0, np.int8)] * (c.max_batch - len(reqs)),
-                    cap)
-            res = fn(payload, arr, lens)
-            last_times = getattr(fn, "last_times", ())
+        with tr.span("flush", t_start=t_pick, bucket_cap=cap,
+                     batch=len(reqs), workload=c.workload,
+                     shards=c.num_shards) as flush:
+            with tr.span("dispatch", t_start=t_pick, bucket_cap=cap):
+                index, epoch = self.index.current()
+                if c.num_shards > 1:
+                    payload = index.arrays
+                    fn = self._executor(cap, index.layout_key,
+                                        sharded_index=index)
+                elif c.workload == "graph":
+                    payload = index.arrays
+                    fn = self._executor(cap, index.tile_stride)
+                else:
+                    payload = index
+                    fn = self._executor(cap)
+                lens = self._lengths(cap, reqs)
+                # copied here, so the executor's own jnp.asarray of it is
+                # a no-op and no host work falls between encode and the
+                # first device stage
+                dev_lens = jnp.asarray(lens)
+            arr = self._encode(cap, reqs)
+            # unbound, so that fetch frees the device results
+            host = self._fetch(cap, fn(payload, arr, dev_lens))
+            self._deliver(cap, reqs, epoch, lens, host,
+                          getattr(fn, "last_stats", None))
+            times = getattr(fn, "last_times", ())
             # per-kernel analytic counters: the linear workload's align
             # stage has an exact op/byte model, sharded or not — the
             # mesh split changes the launch layout, not the per-read
@@ -767,25 +865,12 @@ class ServeEngine:
             if rf is not None and rf.enabled and c.workload == "linear":
                 from repro import align as align_dispatch
 
-                align_s = next((t1 - t0 for name, t0, t1, _ in last_times
+                align_s = next((t1 - t0 for name, t0, t1, _ in times
                                 if name in ("align", "align_shard")), None)
                 kc = rf.record_flush(
                     self.align_backend, cap, c.genasm.k, c.max_batch,
                     align_s=align_s,
                     block_bt=align_dispatch.block_size_for(
                         self.align_backend, cap, c.genasm.k, c.max_batch))
-            # replay the executor's per-stage monotonic windows as child
-            # spans of this flush (seed_filter/prefilter/dc_filter/
-            # scatter/merge_device/align/align_shard, with compile/
-            # dc_rows/shard attrs; the align span carries the analytic
-            # counters when modeled)
-            for name, t0, t1, attrs in last_times:
-                if name in ("align", "align_shard") and kc is not None:
-                    attrs = {**attrs, "word_ops": kc.word_ops,
-                             "hbm_bytes": kc.hbm_bytes}
-                tr.add(name, t0, t1, bucket_cap=cap, **attrs)
-            self._deliver(cap, reqs, epoch, lens, res,
-                          getattr(fn, "last_stats", None))
-        with self._cv:
-            self._inflight -= len(reqs)
-            self._cv.notify_all()
+            t_replay = self._replay(flush, cap, reqs, t_flush, times, kc)
+        return len(reqs), self._end_flush(flush, cap, t_replay)

@@ -1,5 +1,7 @@
-"""repro.obs: spans, Perfetto export, Amdahl ledger, HTTP, roofline."""
+"""repro.obs: spans, Perfetto export, Amdahl ledger, HTTP, roofline,
+the profiler sink and the device-side names."""
 import json
+import re
 import threading
 import time
 import urllib.error
@@ -487,3 +489,148 @@ def test_roofline_endpoint_404_when_unattached():
         with pytest.raises(urllib.error.HTTPError) as ei:
             _get(srv.url + "/roofline")
         assert ei.value.code == 404
+
+
+# ------------------------------------------------------- profiler sink --
+def _host_events(profile_dir, names):
+    """``{name: [duration_s, ...]}`` of the capture's host-plane events."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{profile_dir}/**/*.xplane.pb", recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in names:
+                    out.setdefault(ev.name, []).append(ev.duration_ns * 1e-9)
+    return out
+
+
+def test_spans_and_stages_land_on_the_profiler_host_plane(tmp_path):
+    import jax
+
+    from repro.obs import StageTimer
+
+    tr, off, timer = Tracer(), Tracer(enabled=False), StageTimer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tr.span("obs_test_flush"):
+            with timer.stage("obs_test_stage"):
+                time.sleep(0.02)
+            with off.span("obs_test_disabled"):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    got = _host_events(tmp_path, {"obs_test_flush", "obs_test_stage",
+                                  "obs_test_disabled"})
+    assert "obs_test_disabled" not in got  # a disabled tracer stays silent
+    (flush,) = tr.log.spans()
+    ((_, s0, s1, _),) = timer.times
+    assert got["obs_test_flush"] == [pytest.approx(flush.duration_s,
+                                                   abs=1e-3)]
+    assert got["obs_test_stage"] == [pytest.approx(s1 - s0, abs=1e-3)]
+
+
+def test_trace_module_needs_no_jax():
+    """obs/trace.py imports, and an enabled tracer records, without JAX."""
+    import os
+    import subprocess
+    import sys
+
+    code = ("import sys; sys.modules['jax'] = None\n"
+            "from repro.obs.trace import Tracer, StageTimer\n"
+            "tr = Tracer()\n"
+            "with tr.span('flush'):\n"
+            "    with StageTimer().stage('align'):\n"
+            "        pass\n"
+            "assert [s.name for s in tr.log.spans()] == ['flush']\n"
+            "assert 'jax' not in [m for m in sys.modules if sys.modules[m]]\n")
+    env = dict(os.environ)
+    src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [src] + [p for p in [env.get("PYTHONPATH")] if p])
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr
+
+
+# ------------------------------------------------------- device names --
+@pytest.mark.parametrize("backend", ["lax", "pallas_dc"])
+def test_executor_stages_carry_named_scopes(backend):
+    """The compiled stages name seeding, the DC filter, the windowed DC
+    step and the traceback in their op metadata."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core import minimizer_index
+    from repro.core.mapper import LinearMapExecutor
+
+    rng = np.random.default_rng(3)
+    ref = rng.integers(0, 4, size=2000).astype(np.int8)
+    index = minimizer_index.build_reference_index(ref, w=8, k=12)
+    ex = LinearMapExecutor(p_cap=96, filter_bits=64, filter_k=8,
+                           minimizer_w=8, minimizer_k=12, backend=backend)
+    reads = jnp.asarray(np.stack([ref[100:196], ref[700:796]]))
+    lens = jnp.asarray(np.array([96, 96], np.int32))
+    sf_hlo = ex._sf.lower(index, reads, lens).compile().as_text()
+    sf = ex._sf(index, reads, lens)
+    al_hlo = ex._align.lower(sf, lens).compile().as_text()
+    # a scope is one component of an op's name path, ``vmap(...)``-wrapped
+    # where it sits under a vmap
+    def scoped(hlo, scope):
+        return re.search(rf'op_name="[^"]*[/(]{scope}[)/]', hlo)
+
+    for scope in ("seeding", "dc_filter"):
+        assert scoped(sf_hlo, scope), scope
+    for scope in ("dc_window", "traceback"):
+        assert scoped(al_hlo, scope), scope
+
+
+def _pallas_names(jaxpr) -> list[str]:
+    """``name`` of every ``pallas_call`` in a jaxpr, nested ones too."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn.params["name"])
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                out += _pallas_names(inner)
+    return out
+
+
+def test_every_pallas_kernel_has_its_own_name():
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels import bitalign, genasm_dc, genasm_dc_v2, myers
+
+    t = jnp.zeros((8, 64), jnp.int8)
+    bases = jnp.zeros((8, 16), jnp.int8)
+    succ = jnp.zeros((8, 16), jnp.uint32)
+    pats = jnp.zeros((8, 32), jnp.int8)
+    lens = jnp.full((8,), 20, jnp.int32)
+    calls = {
+        "genasm_dc": functools.partial(
+            genasm_dc.window_dc_batch, t, t, w=64, k=4, block_bt=8),
+        "genasm_dc_v2": functools.partial(
+            genasm_dc_v2.window_dc_batch_v2, t, t, w=64, k=4, block_bt=8),
+        "bitalign_dc": functools.partial(
+            bitalign.bitalign_dc_batch, bases, succ, pats, lens, m_bits=32,
+            k=4, block_bt=8),
+        "bitalign_filter": functools.partial(
+            bitalign.bitalign_filter_batch, bases, succ, pats, lens,
+            m_bits=32, k=4, span=8, block_bt=8),
+        "myers": functools.partial(
+            myers.myers_distance_batch, t, pats, lens, m_bits=32,
+            block_bt=8),
+    }
+    for name, fn in calls.items():
+        jaxpr = jax.make_jaxpr(functools.partial(fn, interpret=True))()
+        assert _pallas_names(jaxpr.jaxpr) == [name]

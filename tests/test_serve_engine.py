@@ -279,3 +279,70 @@ def test_map_stream_over_prefetcher(epi, reads):
     assert sorted(got) == [0, 1, 2]
     pos = np.concatenate([got[b] for b in sorted(got)])[:len(short.true_pos)]
     assert (np.abs(pos - short.true_pos) <= 16).mean() >= 0.7
+
+
+FLUSH_CHILDREN = ["dispatch", "encode", "seed_filter", "align", "fetch",
+                  "emit", "trace_replay"]
+WORKER_LEAVES = frozenset(FLUSH_CHILDREN) | {"worker_wait"}
+
+
+def test_traced_worker_is_covered_by_leaf_spans(epi, reads):
+    """Every flush has the same leaf children in pipeline order, the
+    waits between flushes are top-level spans that never overlap one,
+    and together the leaves cover the worker's time."""
+    from repro.obs import Tracer
+
+    short, _ = reads
+    tr = Tracer()
+    cfg = EngineConfig(buckets=(96,), max_batch=4, max_delay_s=0.005,
+                       filter_k=10, minimizer_w=8, minimizer_k=12,
+                       cache_capacity=0)  # every read reaches a flush
+    with ServeEngine(epi, cfg, tracer=tr) as eng:
+        eng.map_all(list(short.reads[:4]))  # compiles outside the trace
+        warm = [s for s in tr.log.spans() if s.name in ("seed_filter",
+                                                         "align")]
+        tr.log.clear()
+        futs = [eng.submit(r) for r in short.reads]  # full and deadline
+        for f in futs:
+            f.result(timeout=60)
+        time.sleep(0.02)  # the worker waits with nothing queued
+        for f in [eng.submit(r) for r in short.reads[:3]]:
+            f.result(timeout=60)
+    spans = tr.log.spans()
+    assert not [s for s in spans if s.kind == "instant"]  # no per-read event
+    # each executor stage says whether that call compiled it
+    assert sorted(s.name for s in warm if s.attrs["compile"]) == [
+        "align", "seed_filter"]
+    assert not any(s.attrs["compile"] for s in spans
+                   if s.name in ("seed_filter", "align"))
+    flushes = [s for s in spans if s.name == "flush"]
+    assert len(flushes) >= 4
+    for f in flushes:
+        kids = sorted((s for s in spans
+                       if s.parent_id == f.span_id and s.kind == "span"),
+                      key=lambda s: s.t_start)
+        assert [s.name for s in kids] == FLUSH_CHILDREN
+        for a, b in zip(kids, kids[1:]):
+            assert a.t_end <= b.t_start
+        assert f.t_start <= kids[0].t_start and kids[-1].t_end <= f.t_end
+        waits = [s for s in spans
+                 if s.parent_id == f.span_id and s.name == "enqueue_wait"]
+        assert len(waits) == f.attrs["batch"]
+    waits = [s for s in spans if s.name == "worker_wait"]
+    assert len(waits) >= len(flushes) - 1
+    for w in waits:
+        assert w.parent_id is None and w.kind == "span"
+        for f in flushes:
+            assert w.t_end <= f.t_start or w.t_start >= f.t_end
+    # leaf spans cover the worker from the first flush to the last; the
+    # margin leaves room for a loaded test host (a traced v5e run reads
+    # over 97%, PERF.md)
+    t0 = min(f.t_start for f in flushes)
+    t1 = max(f.t_end for f in flushes)
+    covered, reach = 0.0, t0
+    for a, b in sorted((s.t_start, s.t_end) for s in spans
+                       if s.name in WORKER_LEAVES and s.kind == "span"):
+        a, b = max(a, reach), min(b, t1)
+        if b > a:
+            covered, reach = covered + (b - a), b
+    assert covered >= 0.8 * (t1 - t0)

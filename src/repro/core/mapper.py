@@ -11,6 +11,7 @@ minimizer index replicated or sharded over ``"model"`` (DESIGN.md §5).
 """
 from __future__ import annotations
 
+import time
 from functools import partial
 from typing import NamedTuple
 
@@ -18,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.obs.trace import StageTimer
+from repro.obs.trace import StageTimer, profiler_annotation
 
 from .bitvector import SENTINEL, WILDCARD
 from .genasm import GenASMConfig
@@ -257,6 +258,14 @@ class LinearMapExecutor:
     `SeedFilterResult`, a per-flush cost measured at <1% of the stage
     itself on the smoke benchmark.
 
+    ``blocking=False`` dispatches the same two jits with no host sync
+    and returns the `MapResult` still on the device: the serve engine
+    calls it so, and fetches a flush's results only after it has queued
+    the next flush behind it.  Such a call records no ``last_times``;
+    ``last_stages`` holds ``(stage, t_dispatched, device output, attrs)``
+    of each stage, in dispatch order, for a caller that stamps when each
+    output is ready.
+
     ``trace_hook`` (if given) is called with ``("seed_filter",)`` /
     ``("align",)`` at trace time, mirroring `GraphMapExecutor`'s stage
     keys so retrace accounting is uniform across workloads.
@@ -271,6 +280,7 @@ class LinearMapExecutor:
                  minimizer_k: int = 15,
                  backend: str | None = None,
                  block_bt: int | None = None,
+                 blocking: bool = True,
                  trace_hook=None):
         from repro import align as align_dispatch
 
@@ -303,10 +313,14 @@ class LinearMapExecutor:
 
         self._sf = jax.jit(sf_fn)
         self._align = jax.jit(align_fn)
+        self.blocking = blocking
         self.last_times: list[tuple[str, float, float, dict]] = []
+        self.last_stages: tuple = ()
 
     def __call__(self, index: ReferenceIndex, reads, read_lens) -> MapResult:
         lens = jnp.asarray(read_lens)
+        if not self.blocking:
+            return self._dispatch(index, jnp.asarray(reads), lens)
         timer = StageTimer()
         # a stage not traced before traces (compiles) in this call
         with timer.stage("seed_filter",
@@ -317,4 +331,20 @@ class LinearMapExecutor:
             res = self._align(sf, lens)
             jax.block_until_ready(res)
         self.last_times = timer.times
+        return res
+
+    def _dispatch(self, index: ReferenceIndex, reads, lens) -> MapResult:
+        """seed_filter, then align, without waiting on either; each
+        dispatch is marked in an active ``jax.profiler`` capture."""
+        c_sf = ("seed_filter",) not in self._compiled
+        c_al = ("align",) not in self._compiled
+        with profiler_annotation("seed_filter"):
+            t_sf = time.monotonic()
+            sf = self._sf(index, reads, lens)
+        with profiler_annotation("align"):
+            t_al = time.monotonic()
+            res = self._align(sf, lens)
+        self.last_times = []
+        self.last_stages = (("seed_filter", t_sf, sf, {"compile": c_sf}),
+                            ("align", t_al, res, {"compile": c_al}))
         return res

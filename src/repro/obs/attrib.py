@@ -15,6 +15,7 @@ per canonical pipeline stage —
     merge_device   packed-key argmin-reduce of shard winners on device
     align          windowed GenASM/BitAlign alignment of the winners
     align_shard    the same align stage sharded over the shard mesh
+    device_wait    the worker waiting for a dispatched flush's results
     fetch          device→host copies of the flush's results
     emit           metrics, cache put, future resolution
     trace_replay   the tracer's own per-flush bookkeeping (tracing cost)
@@ -23,9 +24,12 @@ per canonical pipeline stage —
 The worker's ``worker_wait`` spans (flush end → next pick) lie outside
 flushes and stay out of the ledger.
 
-Stage spans parented by a ``flush`` span additionally feed the coverage
-accounting: ``coverage`` is attributed-stage time over total flush time,
-the "stage wall-times sum to ≥90% of end-to-end time" check.  Stage
+Stage spans parented by a ``flush`` span on the flush's own thread
+additionally feed the coverage accounting: ``coverage`` is
+attributed-stage time over total flush time, the "stage wall-times sum
+to ≥90% of end-to-end time" check.  (A pipelined flush's stage spans
+are device windows on a track of their own: they overlap the worker's
+host spans and the flush before it, so they stay out of coverage.)  Stage
 spans without a flush parent (direct executor use, failover drills)
 still land in the ledger.
 
@@ -48,8 +52,8 @@ from .trace import Span, TraceLog
 # canonical stage order (pipeline position, not size)
 STAGE_ORDER = ("enqueue_wait", "dispatch", "encode", "seed_filter",
                "prefilter", "dc_filter", "scatter", "merge", "merge_device",
-               "align", "align_shard", "fetch", "emit", "trace_replay",
-               "other")
+               "align", "align_shard", "device_wait", "fetch", "emit",
+               "trace_replay", "other")
 _STAGE_SET = frozenset(STAGE_ORDER)
 
 # stages whose current implementation already scales with shards; the
@@ -197,7 +201,8 @@ def build_ledger(spans: TraceLog | Iterable[Span]) -> StageLedger:
         led.add(s.name, s.duration_s,
                 word_ops=s.attrs.get("word_ops", 0.0) or 0.0,
                 hbm_bytes=s.attrs.get("hbm_bytes", 0.0) or 0.0)
-        if s.parent_id in flushes and s.name != "enqueue_wait":
+        f = flushes.get(s.parent_id)
+        if f is not None and s.tid == f.tid and s.name != "enqueue_wait":
             covered[s.parent_id] += s.duration_s
             led.attributed_s += s.duration_s
     for fid, f in flushes.items():

@@ -27,7 +27,8 @@ An enabled tracer also enters a ``jax.profiler.TraceAnnotation`` of the
 same name around each scoped span, so a ``jax.profiler`` capture shows the
 engine's stages on its host plane, on the device trace's clock;
 `StageTimer` does the same for executor stages, with or without a
-tracer.  JAX is imported on first use, so this module itself is
+tracer, and a non-blocking executor call marks each stage's dispatch
+(the engine's one-chip path).  JAX is imported on first use, so this module itself is
 stdlib-only, and without JAX the annotations are skipped.  A disabled
 tracer (`NULL_TRACER`) costs one attribute check per call site, which
 is what keeps tracing overhead on the serving hot path under the 3%
@@ -96,6 +97,7 @@ class _NullSpan:
     """Inert stand-in yielded by a disabled tracer's ``span()``."""
 
     __slots__ = ()
+    t_end = None  # a span that starts "where this one ended" starts now
 
     def set(self, **attrs) -> None:
         """Accept and discard attributes (mirrors `Span.set`)."""

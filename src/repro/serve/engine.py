@@ -35,6 +35,7 @@ byte-identical output.
 from __future__ import annotations
 
 import os
+import queue
 import threading
 import time
 from concurrent.futures import Future
@@ -42,6 +43,7 @@ from dataclasses import dataclass, field
 from functools import partial
 from typing import NamedTuple, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -90,13 +92,18 @@ class EngineConfig:
     see the `repro.shard.mapper` caveat before shrinking it on highly
     repetitive references.
 
-    ``align_sharded`` (sharded serving only) splits the winning-window
-    align stage over the same shard mesh as the scatter stage;
-    ``pipelined`` dispatches each flush through the executors'
-    non-blocking ``start``/``finish`` surface and overlaps batch *i*'s
-    align with batch *i+1*'s scatter (double buffering, one batch in
-    flight).  Both are bitwise-neutral on output and part of the
-    executor-cache key.
+    One chip's linear workload always keeps one flush in flight: the
+    worker dispatches flush *i+1*'s two stages through a non-blocking
+    executor call before it fetches and emits flush *i*
+    (double buffering), so the device never waits on the host between
+    flushes.  ``align_sharded`` (sharded serving only) splits the
+    winning-window align stage over the same shard mesh as the scatter
+    stage; ``pipelined`` is sharded serving's opt-in to the same
+    double-buffered worker, overlapping batch *i*'s align with batch
+    *i+1*'s scatter.  Both are bitwise-neutral on output and part of
+    the executor-cache key.  The graph workload on one chip flushes
+    one batch at a time: its executor syncs mid-flush to pick a tile
+    rung.
     """
 
     buckets: tuple[int, ...] = (160, 320, 640, 1280)
@@ -188,15 +195,70 @@ class _HostResult(NamedTuple):
 
 
 class _PendingFlush(NamedTuple):
-    """One dispatched-but-unmaterialized flush (pipelined mode)."""
+    """One dispatched-but-unmaterialized flush."""
 
     cap: int
     reqs: list
-    fn: object  # the sharded executor that dispatched it
-    pending: object  # its shard.PendingBatch
+    fn: object  # the executor that dispatched it
+    res: object  # its result tree, device leaves
+    pending: object  # a sharded executor's PendingBatch; None on one chip
     epoch: object
     lens: np.ndarray
     t_flush: float
+    windows: tuple | None  # _StageWatcher.watch's (times, ready)
+
+
+class _StageWatcher:
+    """Times each stage a flush dispatched on the device.
+
+    One thread blocks on the stage outputs in dispatch order, which is
+    the order the device runs them, and stamps each as it becomes ready.
+    A stage's window runs from its dispatch or the completion of the
+    stage before it, whichever is later, to its own completion: the
+    windows never overlap and add up to the device's time per stage,
+    with the gaps before each stage's first op and the thread's wake-up
+    lag in them.  The engine starts one at its first dispatch that is
+    traced or feeds the roofline counters: otherwise there is no thread
+    and no stamp.
+    """
+
+    def __init__(self) -> None:
+        self._q: queue.SimpleQueue = queue.SimpleQueue()
+        self._thread = threading.Thread(
+            target=self._run, name="serve-engine-stages", daemon=True)
+        self._thread.start()
+
+    def watch(self, stages) -> tuple[list, threading.Event]:
+        """Queue ``(stage, t_dispatched, output, attrs)`` tuples →
+        ``(times, ready)``: the event is set once ``times`` holds each
+        stage's ``(stage, t0, t1, attrs)`` window."""
+        times: list = []
+        ready = threading.Event()
+        self._q.put((stages, times, ready))
+        return times, ready
+
+    def close(self) -> None:
+        """Stop the thread once it has timed what was queued."""
+        self._q.put(None)
+        self._thread.join(timeout=10.0)
+
+    def _run(self) -> None:
+        t_free = float("-inf")  # when the last stage left the device
+        while (item := self._q.get()) is not None:
+            stages, times, ready = item
+            out = None
+            try:
+                for name, t_dispatch, out, attrs in stages:
+                    jax.block_until_ready(out)
+                    t_done = time.monotonic()
+                    times.append((name, max(t_dispatch, t_free), t_done,
+                                  attrs))
+                    t_free = t_done
+            except Exception:  # noqa: BLE001 — the worker's wait raises it
+                pass  # a failed stage leaves its flush without windows
+            finally:
+                ready.set()
+            del item, stages, out  # the worker frees the device buffers
 
 
 class ServeEngine:
@@ -272,7 +334,14 @@ class ServeEngine:
         self.trace_counts: dict[int, int] = {}
         self._cv = threading.Condition()
         self._inflight = 0
+        # one chip's linear executor dispatches both of its stages with
+        # no host sync, so the worker keeps one flush in flight behind
+        # the running one; sharded serving opts in; the graph executor
+        # syncs mid-flush and flushes one batch at a time
+        self._pipelined = config.pipelined or (
+            config.workload == "linear" and config.num_shards == 1)
         self._pending: _PendingFlush | None = None  # pipelined: one in flight
+        self._watcher: _StageWatcher | None = None  # one chip, timed only
         self._closed = False
         self._error: BaseException | None = None
         self._worker = threading.Thread(
@@ -428,6 +497,8 @@ class ServeEngine:
             self._closed = True
             self._cv.notify_all()
         self._worker.join(timeout=10.0)
+        if self._watcher is not None:
+            self._watcher.close()
 
     def __enter__(self):
         return self
@@ -523,14 +594,15 @@ class ServeEngine:
                     backend=backend, prefilter=c.graph_prefilter,
                     trace_hook=partial(self._count_trace, cap))
             else:
-                # host-orchestrated two-stage executor: same math as one
-                # fused map_batch jit, but the seed_filter/align boundary
-                # is observable (last_times) for per-stage attribution
+                # two-stage executor: same math as one fused map_batch
+                # jit, called without blocking by the double-buffered
+                # worker; the stage outputs it exposes (last_stages) time
+                # the seed_filter/align boundary on the device
                 fn = mapper.LinearMapExecutor(
                     cfg=c.genasm, p_cap=cap, filter_bits=fbits,
                     filter_k=c.filter_k, max_candidates=c.max_candidates,
                     minimizer_w=c.minimizer_w, minimizer_k=c.minimizer_k,
-                    backend=backend,
+                    backend=backend, blocking=False,
                     trace_hook=partial(self._count_trace, cap))
             self._executors[key] = fn
         return fn
@@ -620,11 +692,12 @@ class ServeEngine:
                     self._release(self._finish_pending()[0])
                     return
                 if action == "finish":
-                    done, t_idle = self._finish_pending()
+                    done, t_idle = self._finish_pending(t_pick)
                 else:
                     cap, reqs = picked  # compute outside the lock
-                    if self.config.pipelined:
-                        done, t_idle = self._execute_pipelined(cap, reqs)
+                    if self._pipelined:
+                        done, t_idle = self._execute_pipelined(cap, reqs,
+                                                               t_pick)
                     else:
                         done, t_idle = self._execute(cap, reqs, t_pick)
                     picked = None
@@ -653,60 +726,93 @@ class ServeEngine:
                 self._inflight -= n
                 self._cv.notify_all()
 
-    def _execute_pipelined(self, cap: int, reqs: list[_Request]
-                           ) -> tuple[int, float]:
+    def _execute_pipelined(self, cap: int, reqs: list[_Request],
+                           t_pick: float) -> tuple[int, float]:
         """Dispatch a flush without materializing it; finish the previous.
 
-        Double buffering, one batch deep: batch *i+1*'s encode + scatter
-        + device merge dispatch overlaps batch *i*'s still-running align
-        (the executors' ``start`` surface never syncs between stages).
-        Returns ``(reads delivered, end of the worker's last span)``: the
-        previous flush's reads.
+        Double buffering, one flush deep: flush *i+1*'s encode and
+        dispatch (seed_filter then align on one chip; scatter, device
+        merge and align sharded) queue it on the device behind flush
+        *i*, which is then waited for, fetched and emitted while *i+1*
+        runs.  Returns ``(reads delivered, end of the worker's last
+        span)``: the previous flush's reads.  Encode starts at
+        ``t_pick``, where ``worker_wait`` ended, and each span of the
+        worker starts where the one before it ended.
         """
+        c, tr = self.config, self.tracer
         prev, self._pending = self._pending, None
-        tr = self.tracer
         try:
             t_flush = self._clock()
-            # no flush span yet (it opens when the batch is finished), so
+            # no flush span yet (it opens when the flush is finished), so
             # encode and dispatch are top-level spans of the worker
-            arr = self._encode(cap, reqs)
-            with tr.span("dispatch", bucket_cap=cap, pipelined=True):
+            arr, enc = self._encode(cap, reqs, t_start=t_pick)
+            with tr.span("dispatch", t_start=enc.t_end, bucket_cap=cap,
+                         overlapped=prev is not None) as disp:
                 index, epoch = self.index.current()
-                fn = self._executor(cap, index.layout_key,
-                                    sharded_index=index)
                 lens = self._lengths(cap, reqs)
-                pending = fn.start(index.arrays, arr, lens, timed=False)
-            self._pending = _PendingFlush(cap, reqs, fn, pending, epoch,
-                                          lens, t_flush)
+                pending, windows = None, None
+                if c.num_shards > 1:
+                    fn = self._executor(cap, index.layout_key,
+                                        sharded_index=index)
+                    pending = fn.start(index.arrays, arr, lens, timed=False)
+                    res = pending.res
+                else:  # a non-blocking call: the results stay on device
+                    fn = self._executor(cap)
+                    res = fn(index, arr, lens)
+                    if tr.enabled or self._roofline_on():
+                        if self._watcher is None:
+                            self._watcher = _StageWatcher()
+                        windows = self._watcher.watch(fn.last_stages)
+            self._pending = _PendingFlush(cap, reqs, fn, res, pending, epoch,
+                                          lens, t_flush, windows)
         except BaseException:
             self._pending = prev  # the worker handler fails prev too
             raise
         if prev is None:
-            return 0, time.monotonic()
-        return self._finish_flush(prev)
+            return 0, disp.t_end or time.monotonic()
+        self.metrics.counter("flushes_overlapped").inc()
+        return self._finish_flush(prev, disp.t_end)
 
-    def _finish_pending(self) -> tuple[int, float]:
+    def _finish_pending(self, t_start: float | None = None
+                        ) -> tuple[int, float]:
         prev, self._pending = self._pending, None
         if prev is None:
             return 0, time.monotonic()
-        return self._finish_flush(prev)
+        return self._finish_flush(prev, t_start)
 
-    def _finish_flush(self, state: _PendingFlush) -> tuple[int, float]:
-        """Materialize a dispatched flush and deliver its results; returns
-        ``(reads delivered, end of the flush)``."""
+    def _finish_flush(self, state: _PendingFlush, t_start: float | None
+                      ) -> tuple[int, float]:
+        """Wait for a dispatched flush, then fetch and deliver its
+        results; returns ``(reads delivered, end of the flush)``.  The
+        flush and its ``device_wait`` start at ``t_start``, where the
+        worker's last span ended."""
         c, tr = self.config, self.tracer
         cap, reqs = state.cap, state.reqs
         try:
-            with tr.span("flush", bucket_cap=cap, batch=len(reqs),
-                         workload=c.workload, shards=c.num_shards,
-                         pipelined=True) as flush:
-                with tr.span("fetch", bucket_cap=cap):
-                    res, times = state.fn.finish(state.pending)
-                state.fn.last_times = list(times)
-                self._deliver(cap, reqs, state.epoch, state.lens, res,
-                              getattr(state.pending, "stats", None))
+            with tr.span("flush", t_start=t_start, bucket_cap=cap,
+                         batch=len(reqs), workload=c.workload,
+                         shards=c.num_shards, pipelined=True) as flush:
+                with tr.span("device_wait", t_start=t_start,
+                             bucket_cap=cap) as wait:
+                    jax.block_until_ready(state.res)
+                    if state.windows is not None:
+                        state.windows[1].wait()
+                tid, stats = None, None
+                if state.pending is None:  # one chip
+                    res = self._fetch(cap, state.res, t_start=wait.t_end)
+                    times = state.windows[0] if state.windows else ()
+                    # device windows, which overlap the worker's spans
+                    tid = f"{self._worker.name} device"
+                else:
+                    with tr.span("fetch", t_start=wait.t_end,
+                                 bucket_cap=cap):
+                        res, times = state.fn.finish(state.pending)
+                    state.fn.last_times = list(times)
+                    stats = state.pending.stats
+                kc = self._record_roofline(cap, times)
+                self._deliver(cap, reqs, state.epoch, state.lens, res, stats)
                 t_replay = self._replay(flush, cap, reqs, state.t_flush,
-                                        times, None)
+                                        times, kc, tid=tid)
         except BaseException as e:
             # this flush's futures die here; the worker handler that
             # re-raises cannot see them anymore (self._pending is clear)
@@ -716,6 +822,27 @@ class ServeEngine:
             raise
         return len(reqs), self._end_flush(flush, cap, t_replay)
 
+    def _roofline_on(self) -> bool:
+        return self.roofline is not None and self.roofline.enabled
+
+    def _record_roofline(self, cap: int, times):
+        """Per-kernel analytic counters of a linear flush's align stage:
+        the op/byte model is exact, sharded or not — the mesh split
+        changes the launch layout, not the per-read totals (graph
+        executors: not modeled yet).  None when nothing records."""
+        c, rf = self.config, self.roofline
+        if not self._roofline_on() or c.workload != "linear":
+            return None
+        from repro import align as align_dispatch
+
+        align_s = next((t1 - t0 for name, t0, t1, _ in times
+                        if name in ("align", "align_shard")), None)
+        return rf.record_flush(
+            self.align_backend, cap, c.genasm.k, c.max_batch,
+            align_s=align_s,
+            block_bt=align_dispatch.block_size_for(
+                self.align_backend, cap, c.genasm.k, c.max_batch))
+
     def _lengths(self, cap: int, reqs: list[_Request]) -> np.ndarray:
         """``[max_batch]`` read lengths as `encode.batch_reads` gives them:
         trimmed to the cap, 0 on padding rows."""
@@ -723,17 +850,22 @@ class ServeEngine:
         lens[:len(reqs)] = [min(r.length, cap) for r in reqs]
         return lens
 
-    def _encode(self, cap: int, reqs: list[_Request]) -> np.ndarray:
-        """The flush's reads as one ``[max_batch, cap]`` int8 batch."""
-        with self.tracer.span("encode", bucket_cap=cap):
-            return encode.batch_reads(
+    def _encode(self, cap: int, reqs: list[_Request],
+                t_start: float | None = None):
+        """The flush's reads as one ``[max_batch, cap]`` int8 batch, and
+        the ``encode`` span (its ``t_end`` is where the next one starts)."""
+        with self.tracer.span("encode", t_start=t_start,
+                              bucket_cap=cap) as span:
+            arr = encode.batch_reads(
                 [r.read for r in reqs]
                 + [np.zeros(0, np.int8)] * (self.config.max_batch - len(reqs)),
                 cap)[0]
+        return arr, span
 
-    def _fetch(self, cap: int, res) -> _HostResult:
+    def _fetch(self, cap: int, res, t_start: float | None = None
+               ) -> _HostResult:
         """Device→host copies of the result fields `_deliver` reads."""
-        with self.tracer.span("fetch", bucket_cap=cap):
+        with self.tracer.span("fetch", t_start=t_start, bucket_cap=cap):
             host = _HostResult(
                 np.asarray(res.position), np.asarray(res.distance),
                 np.asarray(res.ops), np.asarray(res.n_ops),
@@ -784,14 +916,16 @@ class ServeEngine:
                 r.future.set_result(out)
 
     def _replay(self, flush, cap: int, reqs: list[_Request], t_flush: float,
-                times, kc) -> float | None:
+                times, kc, tid: str | None = None) -> float | None:
         """The tracer's per-flush bookkeeping, the flush's last work: each
         request's queue wait (async: waits overlap the previous flush's
         compute) and the executor's per-stage monotonic windows
         (seed_filter/prefilter/dc_filter/scatter/merge_device/align/
         align_shard, with compile/dc_rows/shard attrs; the align span
         carries the analytic counters when modeled), all as children of
-        ``flush``.  Returns when it started (None with tracing off): the
+        ``flush``; ``tid`` puts the stage windows on a track of their own
+        (device windows, which overlap the worker's host spans).
+        Returns when it started (None with tracing off): the
         caller records ``trace_replay`` from there to the flush's end, so
         that one span holds what tracing costs the worker, closing the
         flush span included."""
@@ -807,7 +941,8 @@ class ServeEngine:
             if name in ("align", "align_shard") and kc is not None:
                 attrs = {**attrs, "word_ops": kc.word_ops,
                          "hbm_bytes": kc.hbm_bytes}
-            tr.add(name, t_a, t_b, parent=parent, bucket_cap=cap, **attrs)
+            tr.add(name, t_a, t_b, parent=parent, tid=tid, bucket_cap=cap,
+                   **attrs)
         return t0
 
     def _end_flush(self, flush, cap: int, t_replay: float | None) -> float:
@@ -835,42 +970,23 @@ class ServeEngine:
                      shards=c.num_shards) as flush:
             with tr.span("dispatch", t_start=t_pick, bucket_cap=cap):
                 index, epoch = self.index.current()
+                payload = index.arrays
                 if c.num_shards > 1:
-                    payload = index.arrays
                     fn = self._executor(cap, index.layout_key,
                                         sharded_index=index)
-                elif c.workload == "graph":
-                    payload = index.arrays
+                else:  # graph: syncs mid-flush to pick a tile rung
                     fn = self._executor(cap, index.tile_stride)
-                else:
-                    payload = index
-                    fn = self._executor(cap)
                 lens = self._lengths(cap, reqs)
                 # copied here, so the executor's own jnp.asarray of it is
                 # a no-op and no host work falls between encode and the
                 # first device stage
                 dev_lens = jnp.asarray(lens)
-            arr = self._encode(cap, reqs)
+            arr, _ = self._encode(cap, reqs)
             # unbound, so that fetch frees the device results
             host = self._fetch(cap, fn(payload, arr, dev_lens))
             self._deliver(cap, reqs, epoch, lens, host,
                           getattr(fn, "last_stats", None))
             times = getattr(fn, "last_times", ())
-            # per-kernel analytic counters: the linear workload's align
-            # stage has an exact op/byte model, sharded or not — the
-            # mesh split changes the launch layout, not the per-read
-            # op/byte totals (graph executors: not modeled yet)
-            kc = None
-            rf = self.roofline
-            if rf is not None and rf.enabled and c.workload == "linear":
-                from repro import align as align_dispatch
-
-                align_s = next((t1 - t0 for name, t0, t1, _ in times
-                                if name in ("align", "align_shard")), None)
-                kc = rf.record_flush(
-                    self.align_backend, cap, c.genasm.k, c.max_batch,
-                    align_s=align_s,
-                    block_bt=align_dispatch.block_size_for(
-                        self.align_backend, cap, c.genasm.k, c.max_batch))
+            kc = self._record_roofline(cap, times)
             t_replay = self._replay(flush, cap, reqs, t_flush, times, kc)
         return len(reqs), self._end_flush(flush, cap, t_replay)

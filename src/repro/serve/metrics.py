@@ -5,6 +5,8 @@ Thread-safe, dependency-free observability for the micro-batching engine
 bases (the waste length bucketing removes), result-cache hits, and
 end-to-end latency; `render()` emits a Prometheus-style text page and
 `snapshot()` a plain dict for JSON perf logs (benchmarks/serve_engine.py).
+``flushes_overlapped`` counts flushes dispatched while the one before
+them was still in flight: the double-buffered worker engaging.
 
 Graph-workload flushes additionally record the tile pre-filter's
 effectiveness, forwarded from the executor's ``last_stats``:

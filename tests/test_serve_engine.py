@@ -1,4 +1,5 @@
 """repro.serve: buckets, deadline flush, executor/result caches, pipeline."""
+import threading
 import time
 
 import numpy as np
@@ -281,15 +282,20 @@ def test_map_stream_over_prefetcher(epi, reads):
     assert (np.abs(pos - short.true_pos) <= 16).mean() >= 0.7
 
 
-FLUSH_CHILDREN = ["dispatch", "encode", "seed_filter", "align", "fetch",
-                  "emit", "trace_replay"]
-WORKER_LEAVES = frozenset(FLUSH_CHILDREN) | {"worker_wait"}
+# a flush's children on the worker's track, in order; its stage spans
+# are device windows on a track of their own
+FLUSH_CHILDREN = ["device_wait", "fetch", "emit", "trace_replay"]
+STAGES = ["seed_filter", "align"]
+WORKER_LEAVES = frozenset(FLUSH_CHILDREN) | {"encode", "dispatch",
+                                             "worker_wait"}
 
 
 def test_traced_worker_is_covered_by_leaf_spans(epi, reads):
-    """Every flush has the same leaf children in pipeline order, the
-    waits between flushes are top-level spans that never overlap one,
-    and together the leaves cover the worker's time."""
+    """Every flush has the same host children in pipeline order, behind
+    its own top-level encode and dispatch; its stage spans are device
+    windows that never overlap another flush's; the waits between picks
+    are top-level spans; and together the host leaves cover the
+    worker's time."""
     from repro.obs import Tracer
 
     short, _ = reads
@@ -299,8 +305,7 @@ def test_traced_worker_is_covered_by_leaf_spans(epi, reads):
                        cache_capacity=0)  # every read reaches a flush
     with ServeEngine(epi, cfg, tracer=tr) as eng:
         eng.map_all(list(short.reads[:4]))  # compiles outside the trace
-        warm = [s for s in tr.log.spans() if s.name in ("seed_filter",
-                                                         "align")]
+        warm = [s for s in tr.log.spans() if s.name in STAGES]
         tr.log.clear()
         futs = [eng.submit(r) for r in short.reads]  # full and deadline
         for f in futs:
@@ -313,36 +318,221 @@ def test_traced_worker_is_covered_by_leaf_spans(epi, reads):
     # each executor stage says whether that call compiled it
     assert sorted(s.name for s in warm if s.attrs["compile"]) == [
         "align", "seed_filter"]
-    assert not any(s.attrs["compile"] for s in spans
-                   if s.name in ("seed_filter", "align"))
+    assert not any(s.attrs["compile"] for s in spans if s.name in STAGES)
     flushes = [s for s in spans if s.name == "flush"]
     assert len(flushes) >= 4
+    worker = flushes[0].tid
     for f in flushes:
-        kids = sorted((s for s in spans
-                       if s.parent_id == f.span_id and s.kind == "span"),
+        kids = sorted((s for s in spans if s.parent_id == f.span_id
+                       and s.kind == "span" and s.tid == worker),
                       key=lambda s: s.t_start)
         assert [s.name for s in kids] == FLUSH_CHILDREN
         for a, b in zip(kids, kids[1:]):
             assert a.t_end <= b.t_start
         assert f.t_start <= kids[0].t_start and kids[-1].t_end <= f.t_end
+        stages = sorted((s for s in spans if s.parent_id == f.span_id
+                         and s.name in STAGES), key=lambda s: s.t_start)
+        assert [s.name for s in stages] == STAGES
+        assert all(s.tid != worker for s in stages)
+        # the results are ready when the worker's wait for them ends
+        assert stages[-1].t_end <= kids[0].t_end
         waits = [s for s in spans
                  if s.parent_id == f.span_id and s.name == "enqueue_wait"]
         assert len(waits) == f.attrs["batch"]
-    waits = [s for s in spans if s.name == "worker_wait"]
-    assert len(waits) >= len(flushes) - 1
-    for w in waits:
-        assert w.parent_id is None and w.kind == "span"
-        for f in flushes:
-            assert w.t_end <= f.t_start or w.t_start >= f.t_end
-    # leaf spans cover the worker from the first flush to the last; the
-    # margin leaves room for a loaded test host (a traced v5e run reads
-    # over 97%, PERF.md)
-    t0 = min(f.t_start for f in flushes)
+    # device windows of one engine never overlap, across flushes too
+    windows = sorted((s.t_start, s.t_end) for s in spans if s.name in STAGES)
+    assert len(windows) == 2 * len(flushes)
+    for a, b in zip(windows, windows[1:]):
+        assert a[0] <= a[1] <= b[0]
+    # the worker's top-level spans follow one another: each flush is
+    # dispatched by its own encode + dispatch and finished by a later
+    # pick or an idle queue
+    top = sorted((s for s in spans if s.parent_id is None
+                  and s.kind == "span" and s.tid == worker),
+                 key=lambda s: s.t_start)
+    names = [s.name for s in top]
+    assert names.count("dispatch") == names.count("encode") == len(flushes)
+    assert names.count("worker_wait") >= len(flushes) - 1
+    for a, b in zip(top, top[1:]):
+        assert a.t_end <= b.t_start
+    # host leaf spans cover the worker from its first pick to the last
+    # flush's end; the margin leaves room for a loaded test host (a
+    # traced v5e run reads over 97%, PERF.md)
+    t0 = min(s.t_start for s in top if s.name == "encode")
     t1 = max(f.t_end for f in flushes)
     covered, reach = 0.0, t0
     for a, b in sorted((s.t_start, s.t_end) for s in spans
-                       if s.name in WORKER_LEAVES and s.kind == "span"):
+                       if s.name in WORKER_LEAVES and s.kind == "span"
+                       and s.tid == worker):
         a, b = max(a, reach), min(b, t1)
         if b > a:
             covered, reach = covered + (b - a), b
     assert covered >= 0.8 * (t1 - t0)
+
+
+def _rung_reference(epi, eng, reads_by_cap):
+    """What `mapper.map_batch` gives for each rung's reads, per read."""
+    from repro.core import mapper
+    from repro.genomics import encode
+
+    c = eng.config
+    out = {}
+    for cap, rs in reads_by_cap.items():
+        arr, lens = encode.batch_reads(rs, cap)
+        res = mapper.map_batch(
+            epi.index, arr, lens, cfg=c.genasm, p_cap=cap,
+            filter_bits=min(c.filter_bits, cap), filter_k=c.filter_k,
+            max_candidates=c.max_candidates, minimizer_w=c.minimizer_w,
+            minimizer_k=c.minimizer_k, backend=eng.align_backend)
+        out[cap] = [tuple(np.asarray(f)[i] for f in
+                          (res.position, res.distance, res.ops, res.n_ops))
+                    for i in range(len(rs))]
+    return out
+
+
+def test_pipelined_results_equal_map_batch(epi, reads):
+    """The one-chip engine keeps a flush in flight and still answers
+    bit for bit what `map_batch` does, over two rungs, with full and
+    deadline flushes, and traces each rung's stages once."""
+    short, long = reads
+    cfg = EngineConfig(buckets=(96, 192), max_batch=4, max_delay_s=0.005,
+                       filter_k=10, minimizer_w=8, minimizer_k=12,
+                       cache_capacity=0)
+    with ServeEngine(epi, cfg) as eng:
+        with eng._cv:  # the whole backlog is queued before the first pick
+            futs = [eng.submit(r) for r in list(short.reads)
+                    + list(long.reads)]
+        got = [f.result(timeout=60) for f in futs]
+        snap = eng.metrics.snapshot()
+        counts = dict(eng.trace_counts)
+        # tracing off: no stage watcher thread, no stamps
+        assert eng._watcher is None
+        assert all(p.windows is None for p in [eng._pending] if p)
+        assert "serve-engine-stages" not in {
+            t.name for t in threading.enumerate()}
+        ref = _rung_reference(epi, eng, {96: list(short.reads),
+                                         192: list(long.reads)})
+    want = ref[96] + ref[192]
+    for g, (pos, dist, ops, n_ops) in zip(got, want):
+        assert (g.position, g.distance, g.n_ops) == (pos, dist, n_ops)
+        assert g.ops.dtype == ops.dtype and np.array_equal(g.ops, ops)
+    # 96: two full flushes and a deadline flush of 2; 192: one of 2
+    assert snap["batches_flushed_cap96"] == 3
+    assert snap["batches_flushed_cap192"] == 1
+    assert snap["batch_occupancy_mean"] < 1.0
+    assert snap["flushes_overlapped"] >= 1
+    assert counts == {(96, "seed_filter"): 1, (96, "align"): 1,
+                      (192, "seed_filter"): 1, (192, "align"): 1}
+
+
+class _Proxy:
+    """Stands in for an executor; ``on_call`` runs before each call."""
+
+    def __init__(self, fn, on_call):
+        self.fn, self.on_call = fn, on_call
+
+    def __getattr__(self, name):
+        return getattr(self.fn, name)
+
+    def __call__(self, *args):
+        self.on_call()
+        return self.fn(*args)
+
+
+def _instrument(eng):
+    """Count the flushes dispatched to the executor and not yet fetched."""
+    seen = {"open": 0, "open_at_start": []}
+    make, fetch = eng._executor, eng._fetch
+
+    def started():
+        seen["open_at_start"].append(seen["open"])
+        seen["open"] += 1
+
+    def fetched(*a, **k):
+        seen["open"] -= 1
+        return fetch(*a, **k)
+
+    eng._executor = lambda *a, **k: _Proxy(make(*a, **k), started)
+    eng._fetch = fetched
+    return seen
+
+
+def test_pipelined_keeps_one_flush_in_flight(epi, reads):
+    """Under a backlog each flush is dispatched while exactly one other
+    is in flight, never two."""
+    short, _ = reads
+    cfg = EngineConfig(buckets=(96,), max_batch=2, max_delay_s=0.005,
+                       filter_k=10, minimizer_w=8, minimizer_k=12,
+                       cache_capacity=0)
+    with ServeEngine(epi, cfg) as eng:
+        eng.map_all(list(short.reads[:2]))  # builds and compiles
+        seen = _instrument(eng)
+        with eng._cv:  # five full flushes queued before the first pick
+            futs = [eng.submit(r) for r in short.reads]
+        for f in futs:
+            f.result(timeout=60)
+        eng.drain(timeout=60)
+        snap = eng.metrics.snapshot()
+    assert seen["open_at_start"] == [0, 1, 1, 1, 1]
+    assert seen["open"] == 0
+    assert snap["flushes_overlapped"] == 4
+
+
+def test_lone_read_resolves_through_idle_finish(epi, reads):
+    """A read with nothing behind it is dispatched at its deadline and
+    finished because the queue is idle, not left in flight."""
+    short, _ = reads
+    clk = _FakeClock()
+    cfg = EngineConfig(buckets=(96,), max_batch=8, max_delay_s=0.03,
+                       filter_k=10, minimizer_w=8, minimizer_k=12)
+    with ServeEngine(epi, cfg, clock=clk) as eng:
+        fut = eng.submit(short.reads[0])
+        time.sleep(0.15)
+        assert not fut.done()  # frozen before its deadline
+        clk.advance(1.0)
+        res = fut.result(timeout=30)  # no later pick or close finishes it
+        assert eng._pending is None
+        snap = eng.metrics.snapshot()
+    assert res.bucket_cap == 96 and not res.cached
+    assert snap["batches_flushed"] == 1
+    assert "flushes_overlapped" not in snap
+
+
+@pytest.mark.parametrize("stage", ["start", "finish"])
+def test_pipelined_failure_fails_picked_and_inflight(epi, reads, stage):
+    """An executor failure while one flush is in flight and the next is
+    picked fails both flushes' futures and the queued ones, no hang."""
+    short, _ = reads
+    cfg = EngineConfig(buckets=(96,), max_batch=2, max_delay_s=0.005,
+                       filter_k=10, minimizer_w=8, minimizer_k=12,
+                       cache_capacity=0)
+    eng = ServeEngine(epi, cfg)
+    eng.map_all(list(short.reads[:2]))  # builds and compiles
+    (ex,) = eng.executors
+    # the backlog's second dispatch raises, or its first fetch, which
+    # comes once the second flush is dispatched
+    calls = {"n": 1 if stage == "finish" else 0}
+
+    def flaky():
+        calls["n"] += 1
+        if calls["n"] == 2:
+            raise RuntimeError(f"{stage} boom")
+
+    if stage == "start":
+        eng._executor = lambda *a, **k: _Proxy(ex, flaky)
+    else:
+        fetch = eng._fetch
+
+        def flaky_fetch(*a, **k):
+            flaky()
+            return fetch(*a, **k)
+        eng._fetch = flaky_fetch
+    with eng._cv:  # three full flushes queued before the first pick
+        futs = [eng.submit(r) for r in short.reads[:6]]
+    for f in futs:
+        with pytest.raises(RuntimeError, match="boom"):
+            f.result(timeout=30)
+    with pytest.raises(RuntimeError):
+        eng.submit(short.reads[7])
+    eng.close()
+    assert not eng._worker.is_alive()

@@ -29,7 +29,10 @@ from . import check, devtrace, drive, reference, traffic
 from .manifest import BENCH_DIR, Manifest
 
 MAX_SPANS = 1 << 21
-STREAMS = ("data", "warmup", "window", "arrival", "sample", "extra")
+# spawned in this order from the seed: a new stream goes last, so that
+# those before it draw what they drew
+STREAMS = ("data", "warmup", "window", "arrival", "sample", "extra",
+           "haplotype")
 
 
 class NoAccelerator(RuntimeError):
@@ -73,6 +76,24 @@ def make_data(config: dict, rng: np.random.Generator) -> Data:
             del_span=config["deletion_span"],
             site_pitch=config["variant_site_pitch"], rng=rng)
     return Data(ref, variants)
+
+
+def read_source(config: dict, data: Data,
+                rng: np.random.Generator) -> traffic.Source:
+    """The backbone, or with ``sample_alt_share`` a sample's haplotype that
+    takes each variant's alt allele with that probability."""
+    if "sample_alt_share" not in config:
+        return traffic.Source(data.reference)
+    hap = reference.haplotype(data.reference, data.variants,
+                              config["sample_alt_share"], rng)
+    return traffic.Source(hap.seq, hap.first_backbone)
+
+
+def streams(seed: int) -> dict:
+    """One generator per name in ``STREAMS``, spawned from ``seed``."""
+    return dict(zip(STREAMS, (np.random.default_rng(s) for s in
+                              np.random.SeedSequence(seed).spawn(
+                                  len(STREAMS)))))
 
 
 def compile_cache(root: Path) -> None:
@@ -135,6 +156,7 @@ class Service:
     devs: list
     engine: object  # ServeEngine
     tracer: object | None
+    source: traffic.Source
     plan: traffic.Plan
     warm_counts: dict  # the engine's trace counts after the warm-up
     streams: dict
@@ -150,9 +172,7 @@ def set_up(root: Path, workload: str, seed: int, seconds: float,
     config = man.config(cell["config"])
     mix = man.traffic(cell["traffic"])
     spec = man.limits(workload)
-    streams = dict(zip(STREAMS, (np.random.default_rng(s) for s in
-                                 np.random.SeedSequence(seed).spawn(
-                                     len(STREAMS)))))
+    rngs = streams(seed)
 
     compile_cache(root)
     devs = devices(platform, cell["chips"], root)
@@ -160,33 +180,33 @@ def set_up(root: Path, workload: str, seed: int, seconds: float,
     from repro.serve import ServeEngine
 
     system = importlib.import_module(f"bench.systems.{config['system']}")
-    data = make_data(config, streams["data"])
+    data = make_data(config, rngs["data"])
     overrides = dict(config.get("engine", {}))
     if control:
         overrides.update(spec["control"])
         log(f"control: the engine runs with {spec['control']}")
     index, ecfg = system.build(config, data, overrides)
-    plan = traffic.plan(mix, data.reference, seconds=seconds,
-                        max_batch=ecfg.max_batch,
-                        rng_warm=streams["warmup"],
-                        rng_window=streams["window"],
-                        rng_arrival=streams["arrival"])
+    source = read_source(config, data, rngs["haplotype"])
+    plan = traffic.plan(mix, source, seconds=seconds,
+                        max_batch=ecfg.max_batch, rng_warm=rngs["warmup"],
+                        rng_window=rngs["window"],
+                        rng_arrival=rngs["arrival"])
     tracer = Tracer(log=TraceLog(MAX_SPANS)) if trace else None
     engine = ServeEngine(index, ecfg, tracer=tracer)
 
     def make_read(cap: int) -> np.ndarray:
         lo = max([c for c in ecfg.buckets if c < cap], default=0)
         n = max(lo + 1, cap - 12)
-        return traffic.simulate_reads(data.reference, np.array([n]),
+        return traffic.simulate_reads(source.seq, np.array([n]),
                                       mix["error_profile"],
-                                      streams["extra"]).read(0)
+                                      rngs["extra"]).read(0)
 
     caps = {ecfg.bucket_for(int(n)) for n in
             np.unique(np.concatenate([plan.window.lengths,
                                       plan.warmup.lengths]))}
     before = drive.warm_up(engine, plan.warmup, caps, make_read)
     return Service(man, cell, mix, spec, system, data, devs, engine, tracer,
-                   plan, before, streams)
+                   source, plan, before, rngs)
 
 
 def run_cell(root: Path, workload: str, seed: int, seconds: float,

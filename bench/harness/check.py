@@ -66,39 +66,38 @@ def compare(win: Window, reads: Reads, data, answer, spec: dict,
 
 
 def _alignments(sample, ans, reads: Reads, data) -> tuple[int, int]:
+    """``(bad, excess_max)`` over the sampled answers.  On a graph, each
+    answer is checked against the nodes around it alone (``answer_graph``),
+    under the whole graph's node ids."""
     if not sample:
         return 0, 0
-    graph = (ref_mod.build_graph(data.reference, data.variants)
-             if data.variants is not None else None)
+    v = data.variants
+    c = ref_mod.coords(len(data.reference), v) if v is not None else None
     bad = 0
-    ok_rows, starts, lens = [], [], []
+    ok_rows, texts = [], []
     for i in sample:
         position, distance, ops, path = ans[i]
         read = reads.read(i)
         span = len(read) + max(distance, 0) + 8
-        if graph is None:
+        if v is None:
             err = ref_mod.cigar_error(
                 ops, read, data.reference[position:position + span],
                 distance)
-            start = position
+            bases, succ, start = data.reference, None, position
         else:
-            err = ref_mod.path_error(graph, ops, path, read, distance,
-                                     position)
-            consumed = path[path >= 0]
-            start = int(consumed[0]) if len(consumed) else 0
             span *= 2  # alt nodes interleave the backbone
+            g, start = ref_mod.answer_graph(data.reference, v, c, path, span)
+            err = ref_mod.path_error(g, ops, path, read, distance, position)
+            bases, succ, start = g.bases, g.succ, start - g.first
         if err is not None:
             bad += 1
             continue
         ok_rows.append(i)
-        starts.append(start)
-        lens.append(span)
+        texts.append(ref_mod.windows(bases, succ, np.array([start]),
+                                     np.array([span])))
     if not ok_rows:
         return bad, 0
-    bases = data.reference if graph is None else graph.bases
-    succ = None if graph is None else graph.succ
-    b, s = ref_mod.windows(bases, succ, np.array(starts, np.int64),
-                           np.array(lens, np.int64))
+    b, s = ref_mod.stack_windows(texts)
     opt = ref_mod.anchored_distance([reads.read(i) for i in ok_rows], b, s)
     dist = np.array([ans[i][1] for i in ok_rows])
     bad += int(np.sum(dist < opt))  # below the optimum: the reference is off

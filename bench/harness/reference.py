@@ -9,7 +9,10 @@ semantics:
 * the variation graph those variants spell, linearized with one base per
   node, alt nodes placed right after their backbone position, and edges
   stored as hop bits (bit ``h`` of ``succ[i]`` set when node ``i+h+1``
-  follows node ``i``);
+  follows node ``i``); a check builds only the nodes around an answer,
+  under the whole graph's node ids;
+* a sample's haplotype: the backbone with the alt allele taken at some
+  variant sites, spelled as one sequence that reads are drawn from;
 * the anchored semi-global edit distance of a read against a window of
   that graph (a linear reference is the chain graph): the alignment
   starts at window node 0, consumes the whole read, and may stop
@@ -42,45 +45,86 @@ class Variants(NamedTuple):
     span: np.ndarray  # [V] int32 deleted backbone bases (0 unless del)
 
 
+def length_table(spec) -> dict[int, float]:
+    """An indel length spec as ``{length: weight}``: an int is one length.
+    Lengths lie in 1..15, the longest edge a node's hop bits hold."""
+    table = ({int(spec): 1.0} if isinstance(spec, int)
+             else {int(k): float(w) for k, w in spec.items()})
+    if min(table) < 1 or max(table) >= HOP_LIMIT:
+        raise ValueError(f"indel lengths must lie in 1..{HOP_LIMIT - 1}")
+    return table
+
+
+def _lengths(spec, n: int, rng: np.random.Generator) -> np.ndarray:
+    """``n`` indel lengths: an int is every length, drawing nothing; a
+    table gives each length its share of ``n`` exactly (largest
+    remainders), in an order shuffled by ``rng``."""
+    if isinstance(spec, int):
+        return np.full(n, spec, np.int32)
+    table = length_table(spec)
+    lens = np.array(sorted(table), np.int32)
+    exact = n * np.array([table[k] for k in lens]) / sum(table.values())
+    counts = np.floor(exact).astype(np.int64)
+    counts[np.argsort(counts - exact, kind="stable")[:n - counts.sum()]] += 1
+    out = np.repeat(lens, counts)
+    rng.shuffle(out)
+    return out
+
+
 def random_variants(ref: np.ndarray, *, every_bp: int, mix: dict,
-                    ins_len: int, del_span: int, site_pitch: int,
+                    ins_len, del_span, site_pitch: int,
                     rng: np.random.Generator) -> Variants:
     """One variant per ``every_bp`` backbone bases on a ``site_pitch`` grid.
 
     ``mix`` gives the kinds' weights (e.g. snp 2, ins 1, del 1); a SNP
-    carries the next base ``(ref + 1) % 4``, an insertion ``ins_len``
-    random bases after its site, a deletion drops ``del_span`` bases
-    after its site.  Sites are distinct grid points, so variants never
-    overlap when ``site_pitch`` exceeds ``del_span + 1``.
+    carries the next base ``(ref + 1) % 4``, an insertion random bases
+    after its site, a deletion drops bases after its site.  ``ins_len``
+    and ``del_span`` are each an int, every indel's length, or a table
+    ``{length: weight}`` over 1..15 whose shares every seed meets
+    exactly.  Sites are distinct grid points at least the longest
+    deletion plus 2 apart, so variants never overlap and a deletion never
+    lands on another site; an int draws no numbers for the lengths.
     """
     n_ref = len(ref)
     n_var = n_ref // every_bp
     total = sum(mix.values())
     counts = {k: n_var * mix.get(k, 0) // total for k in KINDS}
-    sites = np.arange(4, n_ref - 8, site_pitch)
+    width = max(length_table(ins_len))
+    reach = max(length_table(del_span))
+    if site_pitch < reach + 2:
+        raise ValueError(f"site pitch {site_pitch} lets a {reach} bp "
+                         "deletion reach the next site")
+    sites = np.arange(4, n_ref - max(8, reach + 2), site_pitch)
     n = min(sum(counts.values()), len(sites))
     pos = np.sort(rng.choice(sites, size=n, replace=False)).astype(np.int64)
     kind = np.concatenate([np.full(counts[k], i, np.int8)
                            for i, k in enumerate(KINDS)])[:n]
     rng.shuffle(kind)
-    alt = np.zeros((n, max(ins_len, 1)), np.int8)
-    alt_len = np.zeros(n, np.int32)
-    span = np.zeros(n, np.int32)
     snp = kind == 0
     ins = kind == 1
+    dele = kind == 2
+    ins_lens = _lengths(ins_len, int(ins.sum()), rng)
+    del_spans = _lengths(del_span, int(dele.sum()), rng)
+    alt = np.zeros((n, width), np.int8)
+    alt_len = np.zeros(n, np.int32)
+    span = np.zeros(n, np.int32)
     alt[snp, 0] = (ref[pos[snp]] + 1) % 4
     alt_len[snp] = 1
-    alt[ins, :ins_len] = rng.integers(0, 4, size=(int(ins.sum()), ins_len),
-                                      dtype=np.int8)
-    alt_len[ins] = ins_len
-    span[kind == 2] = del_span
+    bases = rng.integers(0, 4, size=(int(ins.sum()), width), dtype=np.int8)
+    alt[ins] = np.where(np.arange(width) < ins_lens[:, None], bases, 0)
+    alt_len[ins] = ins_lens
+    span[dele] = del_spans
     return Variants(pos, kind, alt, alt_len, span)
 
 
 class Graph(NamedTuple):
+    """Nodes ``first .. first + len(bases) - 1`` of a graph of ``total``."""
+
     bases: np.ndarray  # [N] int8
     succ: np.ndarray  # [N] uint32 hop bits
     backbone: np.ndarray  # [N] int64 backbone coordinate (-1 on alt nodes)
+    first: int  # node id of bases[0]
+    total: int  # nodes in the whole graph
 
 
 def build_graph(ref: np.ndarray, v: Variants) -> Graph:
@@ -142,7 +186,177 @@ def build_graph(ref: np.ndarray, v: Variants) -> Graph:
     for h in np.unique(hop):
         m = hop == h
         succ[s[m]] |= np.uint32(1 << int(h))
-    return Graph(bases, succ, backbone)
+    return Graph(bases, succ, backbone, 0, n)
+
+
+class Coords(NamedTuple):
+    """Whole-graph node ids without the graph: backbone base ``p`` is node
+    ``p`` plus the alt nodes of the variants before it."""
+
+    pos: np.ndarray  # [V] variant sites
+    before: np.ndarray  # [V + 1] alt nodes of the variants before each
+    site: np.ndarray  # [V] node id of each site's backbone base
+    total: int  # nodes in the whole graph
+
+    def node(self, p):
+        """Node id of backbone base(s) ``p``."""
+        return p + self.before[np.searchsorted(self.pos, p, side="left")]
+
+    def base_at(self, node: int) -> int:
+        """The backbone base of ``node``, or the site of the variant whose
+        alt node it is."""
+        i = int(np.searchsorted(self.site, node, side="right")) - 1
+        if i < 0:
+            return int(node)
+        off = int(node) - int(self.site[i])
+        alt = int(self.before[i + 1] - self.before[i])
+        return int(self.pos[i]) + (off - alt if off > alt else 0)
+
+
+def coords(n_ref: int, v: Variants) -> Coords:
+    before = np.concatenate([[0], np.cumsum(v.alt_len, dtype=np.int64)])
+    return Coords(v.pos, before, v.pos + before[:-1], n_ref + int(before[-1]))
+
+
+def local_graph(ref: np.ndarray, v: Variants, c: Coords, first: int,
+                last: int) -> Graph:
+    """The whole graph's nodes ``first..last``, under its node ids.
+
+    Built from whole backbone bases with ``HOP_LIMIT`` more on each side;
+    every node and every edge between two of its nodes is the whole
+    graph's, and edges that leave it are cut (a deletion that lands past
+    its end is left out).
+    """
+    first = min(max(first, 0), c.total - 1)
+    last = min(max(last, first), c.total - 1)
+    lo = max(c.base_at(first) - HOP_LIMIT, 0)
+    hi = min(c.base_at(last) + 1 + HOP_LIMIT, len(ref))
+    i0, i1 = np.searchsorted(v.pos, [lo, hi])
+    part = Variants(*(a[i0:i1] for a in v))
+    keep = (part.kind != 2) | (part.pos + part.span + 1 < hi)
+    part = Variants(part.pos[keep] - lo, *(a[keep] for a in part[1:]))
+    g = build_graph(ref[lo:hi], part)
+    backbone = np.where(g.backbone >= 0, g.backbone + lo, -1)
+    return Graph(g.bases, g.succ, backbone, int(c.node(lo)), c.total)
+
+
+def answer_graph(ref: np.ndarray, v: Variants, c: Coords, path: np.ndarray,
+                 span: int) -> tuple[Graph, int]:
+    """The local graph that checking a graph answer reads, and the first
+    node of its anchored window: the path's nodes up to its first step
+    too long to be an edge, and ``span`` nodes from its first node."""
+    nodes = path[path >= 0].astype(np.int64)
+    if not len(nodes):
+        return local_graph(ref, v, c, 0, span - 1), 0
+    hop = np.diff(nodes) - 1
+    far = np.nonzero((hop < 0) | (hop >= HOP_LIMIT))[0]
+    chain = nodes[:int(far[0]) + 1 if len(far) else len(nodes)]
+    start = int(nodes[0])
+    return (local_graph(ref, v, c, int(min(chain.min(), start)),
+                        int(max(chain.max(), start + span - 1))), start)
+
+
+class Haplotype(NamedTuple):
+    """One sample's sequence: the backbone with the alt allele taken at the
+    variants ``taken``, whose sites lie at ``at`` on ``seq``."""
+
+    seq: np.ndarray  # int8 bases
+    taken: Variants
+    at: np.ndarray  # [K] haplotype position of each taken site's base
+
+    def _site(self, q: np.ndarray):
+        """For each ``q``: the last taken site at or before it (a SNP at
+        -1 where there is none) as ``(pos, kind, alt_len, span)``, and
+        ``q``'s offset from that site's base."""
+        i = np.searchsorted(self.at, q, side="right")
+        t = self.taken
+        pos, kind, alt_len, span, at = (
+            np.concatenate([[fill], a])[i] for a, fill in
+            ((t.pos, -1), (t.kind, 0), (t.alt_len, 0), (t.span, 0),
+             (self.at, -1)))
+        return pos, kind, alt_len, span, q - at
+
+    def _locate(self, q):
+        """``(on_alt, pos, off, bb)``: whether ``q`` is an alt base of the
+        site at ``pos``, its offset, and else its backbone coordinate."""
+        pos, kind, alt_len, span, off = self._site(np.asarray(q, np.int64))
+        on_alt = (((kind == 0) & (off == 0))
+                  | ((kind == 1) & (off >= 1) & (off <= alt_len)))
+        shift = np.where(kind == 1, -alt_len, np.where(kind == 2, span, 0))
+        bb = np.where(off == 0, pos, pos + off + shift)
+        return on_alt, pos, off, bb
+
+    def first_backbone(self, q: np.ndarray) -> np.ndarray:
+        """Backbone coordinate of the first base at or after each ``q``
+        that lies on the backbone."""
+        on_alt, pos, _, bb = self._locate(q)
+        return np.where(on_alt, pos + 1, bb)
+
+    def nodes(self, c: Coords, q: np.ndarray) -> np.ndarray:
+        """Whole-graph node id of each haplotype position ``q``."""
+        on_alt, pos, off, bb = self._locate(q)
+        return np.where(on_alt, c.node(pos) + np.maximum(off, 1),
+                        c.node(np.maximum(bb, 0)))
+
+
+def haplotype(ref: np.ndarray, v: Variants, alt_share: float,
+              rng: np.random.Generator) -> Haplotype:
+    """A haplotype that takes each variant's alt allele with probability
+    ``alt_share``: a SNP's base replaces its site's, an insertion's bases
+    follow its site, a deletion's span is gone."""
+    if np.any(v.pos[1:] <= v.pos[:-1] + v.span[:-1]):
+        raise ValueError("variants overlap")
+    take = rng.random(len(v.pos)) < alt_share
+    t = Variants(*(a[take] for a in v))
+    seq = ref.copy()
+    snp, ins, dele = (t.kind == k for k in range(3))
+    seq[t.pos[snp]] = t.alt[snp, 0]
+    spans = t.span[dele]
+    gone = (np.repeat(t.pos[dele] + 1 - np.cumsum(spans) + spans, spans)
+            + np.arange(int(spans.sum())))
+    keep = np.ones(len(ref), bool)
+    keep[gone] = False
+    seq = seq[keep]
+    lens = t.alt_len[ins]
+    dropped = np.concatenate([[0], np.cumsum(spans)])
+    before = dropped[np.searchsorted(t.pos[dele], t.pos[ins] + 1)]
+    alt = t.alt[ins]
+    seq = np.insert(seq, np.repeat(t.pos[ins] + 1 - before, lens),
+                    alt[np.arange(alt.shape[1]) < lens[:, None]])
+    shift = np.where(ins, t.alt_len, 0) - np.where(dele, t.span, 0)
+    at = t.pos + np.concatenate([[0], np.cumsum(shift)[:-1]])
+    return Haplotype(seq, t, at.astype(np.int64))
+
+
+def spelled_read(hap: Haplotype, c: Coords, start: int, length: int,
+                 profile: dict, rng: np.random.Generator):
+    """A read drawn from ``hap`` at ``start`` with Mason-style edits
+    (``profile`` as in a traffic mix), and the answer that spells it:
+    ``(read, (position, distance, ops, path))``."""
+    src = hap.seq[start:start + length]
+    nodes = hap.nodes(c, np.arange(start, start + length))
+    err = rng.random(length) < profile["rate"]
+    kind = rng.random(length)
+    cut = (profile["sub"], profile["sub"] + profile["ins"])
+    read, ops, path = [], [], []
+    for j in range(length):
+        if err[j] and cut[0] <= kind[j] < cut[1]:  # a base put before
+            read.append(int(rng.integers(4)))
+            ops.append(OP_I)
+            path.append(-1)
+        if err[j] and kind[j] >= cut[1]:
+            ops.append(OP_D)
+        elif err[j] and kind[j] < cut[0]:
+            read.append((int(src[j]) + int(rng.integers(1, 4))) % 4)
+            ops.append(OP_X)
+        else:
+            read.append(int(src[j]))
+            ops.append(OP_M)
+        path.append(int(nodes[j]))
+    ops = np.array(ops, np.int8)
+    return (np.array(read, np.int8),
+            (int(hap.first_backbone(start)), int(np.sum(ops != OP_M)), ops,
+             np.array(path, np.int64)))
 
 
 def windows(bases: np.ndarray, succ: np.ndarray | None, starts: np.ndarray,
@@ -166,6 +380,18 @@ def windows(bases: np.ndarray, succ: np.ndarray | None, starts: np.ndarray,
     return b, s
 
 
+def stack_windows(rows: list) -> tuple[np.ndarray, np.ndarray]:
+    """One ``[R, T]`` pair from one-row ``windows`` results, padded as
+    ``windows`` pads a shorter row."""
+    t = max(b.shape[1] for b, _ in rows)
+    bases = np.full((len(rows), t), SENTINEL, np.int8)
+    succ = np.zeros((len(rows), t), np.uint32)
+    for r, (b, s) in enumerate(rows):
+        bases[r, :b.shape[1]] = b[0]
+        succ[r, :s.shape[1]] = s[0]
+    return bases, succ
+
+
 def anchored_distance(reads: list[np.ndarray], bases: np.ndarray,
                       succ: np.ndarray) -> np.ndarray:
     """Anchored semi-global read-to-graph edit distance, one per row.
@@ -182,40 +408,40 @@ def anchored_distance(reads: list[np.ndarray], bases: np.ndarray,
     pat = np.full((r, max(mmax, 1)), -1, np.int16)
     for k, x in enumerate(reads):
         pat[k, :len(x)] = x
-    hops = [h for h in range(HOP_LIMIT) if np.any((succ >> h) & 1)]
-    pred = {}  # pred[h][:, j]: node j - h - 1 has an edge to node j
-    for h in hops:
-        p = np.zeros((r, t), bool)
-        p[:, h + 1:] = ((succ[:, :t - h - 1] >> np.uint32(h)) & 1) == 1
-        pred[h] = p
-    inf = np.int32(1 << 28)
-
-    def shifted(a, h):
-        out = np.full_like(a, inf)
-        out[:, h + 1:] = a[:, :t - h - 1]
-        return out
+    chain = np.zeros((r, t), bool)  # node j - 1 has an edge to node j
+    chain[:, 1:] = (succ[:, :-1] & 1) == 1
+    # longer edges, few: (row, from, to) for hops 1..15
+    rr, src, hop = np.nonzero(((succ[:, :, None] >> np.arange(
+        1, HOP_LIMIT, dtype=np.uint32)) & 1) == 1)
+    dst = src + hop + 2
+    inside = dst < t
+    rr, src, dst = rr[inside], src[inside], dst[inside]
+    inf = np.int64(1 << 28)
+    # a chain run's closure is a cumulative minimum of ``a[k] - k``; the
+    # runs are kept apart by an offset larger than any cost
+    run = np.cumsum(~chain, axis=1) * np.int64(1 << 32) + np.arange(t)
 
     def close(a):  # deletions: walk edges without consuming read bases
         while True:
-            b = a
-            for h in hops:
-                b = np.where(pred[h], np.minimum(b, shifted(b, h) + 1), b)
+            a = np.minimum.accumulate(a - run, axis=1) + run
+            b = a.copy()
+            np.minimum.at(b, (rr, dst), a[rr, src] + 1)
             if np.array_equal(a, b):
                 return a
             a = b
 
     out = m.astype(np.int64).copy()  # all insertions consume no node
-    row = np.full((r, t), inf, np.int32)
+    row = np.full((r, t), inf, np.int64)
     row[:, 0] = 1  # empty read prefix, start node deleted
     row = close(row)
     for i in range(1, mmax + 1):
-        cost = (pat[:, i - 1, None] != bases).astype(np.int32)
+        cost = (pat[:, i - 1, None] != bases).astype(np.int64)
         cur = row + 1  # insertion: read base i against node j again
         cur[:, 0] = np.minimum(cur[:, 0],
                                np.minimum(i + 1, i - 1 + cost[:, 0]))
-        for h in hops:
-            cur = np.where(pred[h],
-                           np.minimum(cur, shifted(row, h) + cost), cur)
+        cur[:, 1:] = np.where(chain[:, 1:], np.minimum(
+            cur[:, 1:], row[:, :-1] + cost[:, 1:]), cur[:, 1:])
+        np.minimum.at(cur, (rr, dst), row[rr, src] + cost[rr, dst])
         row = close(np.minimum(cur, inf))
         done = m == i
         if done.any():
@@ -257,24 +483,32 @@ def cigar_error(ops: np.ndarray, read: np.ndarray, text: np.ndarray,
 
 def path_error(g: Graph, ops: np.ndarray, path: np.ndarray, read: np.ndarray,
                distance: int, position: int) -> str | None:
-    """None when a graph answer walks edges and spells ``read``."""
+    """None when a graph answer walks edges and spells ``read``.
+
+    ``g`` may be a local graph (``answer_graph``): it has to hold the
+    path's nodes up to its first step too long to be an edge."""
     consumes = np.isin(ops, (OP_M, OP_X, OP_D))
     if not np.array_equal(path >= 0, consumes):
         return "path entries do not match the node-consuming ops"
     nodes = path[consumes].astype(np.int64)
     if len(nodes) == 0:
         return "the path consumes no node"
-    if nodes.min() < 0 or nodes.max() >= len(g.bases):
+    if nodes.min() < 0 or nodes.max() >= g.total:
         return "path node outside the graph"
     hop = nodes[1:] - nodes[:-1] - 1
     ok = (hop >= 0) & (hop < HOP_LIMIT)
-    ok[ok] = ((g.succ[nodes[:-1][ok]] >> hop[ok].astype(np.uint32)) & 1) == 1
+    chained = len(ok) if ok.all() else int(np.argmin(ok))
+    local = nodes[:chained + 1] - g.first  # steps up to the first too long
+    if local.min() < 0 or local.max() >= len(g.bases):
+        raise ValueError("the local graph does not hold the path")
+    ok[:chained] = ((g.succ[local[:-1]] >> hop[:chained].astype(np.uint32))
+                    & 1) == 1
     if not ok.all():
         k = int(np.argmin(ok))
         return f"path steps {nodes[k]}->{nodes[k + 1]} along no edge"
-    bb = g.backbone[nodes]
+    bb = g.backbone[local]
     first = bb[bb >= 0]
     want = int(first[0]) if len(first) else -1
     if want != position:
         return f"position {position}, first backbone node of the path {want}"
-    return cigar_error(ops, read, g.bases[nodes], distance)
+    return cigar_error(ops, read, g.bases[local], distance)

@@ -16,11 +16,13 @@ A mix file (``bench/traffic/<name>.json``) holds parameters only:
 * ``warmup_reads_per_length``: reads of each class kept apart for
   warm-up, so that no window read is ever a result-cache hit.
 
-Reads are drawn uniformly from the deployment's backbone sequence.
+Reads are drawn uniformly from a ``Source``: the deployment's backbone
+sequence, or a sample's haplotype with a map from a start on it to the
+backbone coordinate that ``true_pos`` records.
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import Callable, NamedTuple
 
 import numpy as np
 
@@ -45,6 +47,14 @@ class Reads(NamedTuple):
         return np.diff(self.offsets)
 
 
+class Source(NamedTuple):
+    """The sequence reads are drawn from; ``to_backbone`` maps a read's
+    start on it to its ``true_pos`` (``None``: it is the backbone)."""
+
+    seq: np.ndarray
+    to_backbone: Callable[[np.ndarray], np.ndarray] | None = None
+
+
 def _simulate(ref: np.ndarray, n: int, length: int, prof: dict,
               rng: np.random.Generator):
     pos = rng.integers(0, len(ref) - length, size=n)
@@ -65,11 +75,12 @@ def _simulate(ref: np.ndarray, n: int, length: int, prof: dict,
 
 
 def simulate_reads(ref: np.ndarray, lengths: np.ndarray, profile: dict,
-                   rng: np.random.Generator) -> Reads:
+                   rng: np.random.Generator, to_backbone=None) -> Reads:
     """One read per entry of ``lengths`` (source bases), in that order.
 
     Each chunk of reads is simulated one length class at a time, then
-    put back in ``lengths``' order.
+    put back in ``lengths``' order.  ``to_backbone`` maps each read's
+    start on ``ref`` to its ``true_pos``.
     """
     flats, lens, poss = [], [], []
     for s in range(0, len(lengths), CHUNK):
@@ -90,10 +101,12 @@ def simulate_reads(ref: np.ndarray, lengths: np.ndarray, profile: dict,
         lens.append(lens_p)
         poss.append(pos_p)
     lens_all = np.concatenate(lens) if lens else np.zeros(0, np.int64)
+    pos_all = np.concatenate(poss) if poss else np.zeros(0, np.int64)
     return Reads(
         flat=(np.concatenate(flats) if flats else np.zeros(0, np.int8)),
         offsets=np.concatenate([[0], np.cumsum(lens_all)]).astype(np.int64),
-        true_pos=(np.concatenate(poss) if poss else np.zeros(0, np.int64)))
+        true_pos=(pos_all if to_backbone is None
+                  else to_backbone(pos_all).astype(np.int64)))
 
 
 def class_lengths(mix: dict, n: int, rng: np.random.Generator) -> np.ndarray:
@@ -131,14 +144,15 @@ class Plan(NamedTuple):
     outstanding: int  # reads kept in flight (backlog), else 0
 
 
-def plan(mix: dict, backbone: np.ndarray, *, seconds: float, max_batch: int,
+def plan(mix: dict, source: Source, *, seconds: float, max_batch: int,
          rng_warm: np.random.Generator, rng_window: np.random.Generator,
          rng_arrival: np.random.Generator) -> Plan:
     """Everything a run of ``mix`` offers, drawn from three seeded streams."""
     prof = mix["error_profile"]
     warm_n = mix["warmup_reads_per_length"]
     warm_len = np.repeat([c["length"] for c in mix["reads"]], warm_n)
-    warmup = simulate_reads(backbone, warm_len, prof, rng_warm)
+    warmup = simulate_reads(source.seq, warm_len, prof, rng_warm,
+                            source.to_backbone)
     if mix["arrival"] == "backlog":
         n = int(np.ceil(mix["pool_reads_per_s"] * seconds))
         due, outstanding = None, mix["outstanding_batches"] * max_batch
@@ -147,6 +161,6 @@ def plan(mix: dict, backbone: np.ndarray, *, seconds: float, max_batch: int,
         n, outstanding = len(due), 0
     else:
         raise ValueError(f"unknown arrival {mix['arrival']!r}")
-    window = simulate_reads(backbone, class_lengths(mix, n, rng_window),
-                            prof, rng_window)
+    window = simulate_reads(source.seq, class_lengths(mix, n, rng_window),
+                            prof, rng_window, source.to_backbone)
     return Plan(warmup, window, due, outstanding)

@@ -114,7 +114,7 @@ def _sweep(svc, engine, windows, seed: int, t_process: float) -> None:
             mix["rate_reads_per_s"] = rate
         rngs = [np.random.default_rng(s) for s in
                 np.random.SeedSequence([seed, k]).spawn(3)]
-        plan = traffic.plan(mix, svc.data.reference, seconds=seconds,
+        plan = traffic.plan(mix, svc.source, seconds=seconds,
                             max_batch=svc.engine.config.max_batch,
                             rng_warm=rngs[0], rng_window=rngs[1],
                             rng_arrival=rngs[2])

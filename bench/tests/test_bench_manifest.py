@@ -1,4 +1,5 @@
-"""The manifest finds every file by name, and new cells need no harness edit."""
+"""The manifest finds every file by name, and new cells and configurations
+need no harness edit."""
 import importlib
 import json
 
@@ -7,7 +8,8 @@ import pytest
 from bench.harness import cell
 from bench.harness.manifest import NAME, UNIT, Manifest
 
-from .conftest import ROOT, copy_bench
+from .conftest import (DATA, ROOT, add_config, copy_bench, cut_to_tiny,
+                       tiny_bench)
 
 
 def test_every_named_file_loads():
@@ -82,3 +84,35 @@ def test_unknown_names_are_refused(tmp_path):
     (root / "BENCHMARK.json").write_text(json.dumps(man))
     with pytest.raises(ValueError):
         Manifest.load(root)
+
+
+@pytest.mark.parametrize("system", ["linear", "graph"])
+def test_a_configuration_is_added_by_files_alone(tmp_path, system):
+    src, limits = {
+        "linear": (ROOT / "bench/configs/linear-chr1.json",
+                   ROOT / "bench/checks/linear-sr-batch.json"),
+        "graph": (DATA / "graph-10m.json", DATA / "graph-sr-batch.json"),
+    }[system]
+    config = json.loads(src.read_text())
+    config["name"] = f"{system}-never-seen"
+    new = {"name": f"{system}-never-seen-sr-batch", "config": config["name"],
+           "traffic": "sr-batch", "chips": 1, "why": "test"}
+    root = tiny_bench(tmp_path)
+    add_config(root, config, new, json.loads(limits.read_text()))
+    cut_to_tiny(root)
+    r = cell.run_cell(root, new["name"], 2 ** 31 + 29, 0.5, False,
+                      t_process=0.0, platform="cpu", settle_s=10.0)
+    assert r["correct"], r["checks"]
+    assert r["metrics"]["bases_per_s"]["value"] > 0
+
+
+def test_a_configuration_of_an_unknown_system_is_named(tmp_path):
+    config = json.loads((DATA / "graph-10m.json").read_text())
+    config.update(name="kmer-count", system="kmer")
+    root = tiny_bench(tmp_path)
+    add_config(root, config, {"name": "kmer-sr-batch", "config": "kmer-count",
+                              "traffic": "sr-batch", "chips": 1,
+                              "why": "test"},
+               json.loads((DATA / "graph-sr-batch.json").read_text()))
+    with pytest.raises(ValueError, match="kmer-count.*'kmer'"):
+        cut_to_tiny(root)

@@ -33,19 +33,33 @@ def oracle_anchored(pattern, bases, succ):
     return int(min(a[m].min(), m))
 
 
-def small_graph(seed):
+LONG = {"1": 2, "7": 1, "15": 1}  # indel lengths up to the hop bits' 15
+
+
+def small_graph(seed, long=False):
     rng = np.random.default_rng(seed)
-    ref = R.random_reference(300, rng)
+    ref = R.random_reference(600 if long else 300, rng)
+    kw = (dict(ins_len=LONG, del_span=LONG, site_pitch=17) if long
+          else dict(ins_len=2, del_span=2, site_pitch=6))
     v = R.random_variants(ref, every_bp=12, mix={"snp": 2, "ins": 1, "del": 1},
-                          ins_len=2, del_span=2, site_pitch=6, rng=rng)
+                          rng=rng, **kw)
     return ref, v, R.build_graph(ref, v)
 
 
 @pytest.mark.parametrize("seed", range(4))
 def test_graph_matches_the_programs_linearization(seed):
+    linearizations_agree(seed, long=False)
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_graph_with_long_indels_matches_the_programs_linearization(seed):
+    linearizations_agree(seed, long=True)
+
+
+def linearizations_agree(seed, long):
     from repro.core.segram.graph import Variant, build_graph
 
-    ref, v, g = small_graph(seed)
+    ref, v, g = small_graph(seed, long)
     want = build_graph(ref, [
         Variant(int(p), R.KINDS[k], tuple(int(b) for b in a[:n]), int(s))
         for p, k, a, n, s in zip(v.pos, v.kind, v.alt, v.alt_len, v.span)])
@@ -56,8 +70,17 @@ def test_graph_matches_the_programs_linearization(seed):
 
 @pytest.mark.parametrize("seed", range(3))
 def test_anchored_distance_matches_the_loop_dp(seed):
+    distances_agree(seed, long=False)
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_anchored_distance_with_long_indels_matches_the_loop_dp(seed):
+    distances_agree(seed, long=True)
+
+
+def distances_agree(seed, long):
     rng = np.random.default_rng(seed)
-    ref, v, g = small_graph(seed)
+    ref, v, g = small_graph(seed, long)
     starts = rng.integers(0, len(g.bases) - 60, size=6)
     lens = np.full(6, 60)
     b, s = R.windows(g.bases, g.succ, starts, lens)

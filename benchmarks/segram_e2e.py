@@ -54,9 +54,9 @@ def run(kind: str = "short", batch: int = 16, *, ref_len: int = 8000,
     # so it is timed eagerly — its stages jit themselves internally
     def f(r, l):
         return gmapper.map_batch(
-            idx.arrays, r, l, tile_stride=idx.tile_stride, cfg=cfg,
-            p_cap=p_cap, filter_bits=128, filter_k=filter_k, minimizer_w=8,
-            minimizer_k=12, backend=be)
+            idx.arrays, r, l, geometry=idx.geometry, cfg=cfg, p_cap=p_cap,
+            filter_bits=128, filter_k=filter_k, minimizer_w=8, minimizer_k=12,
+            backend=be)
     us = timeit(f, jnp.asarray(reads), jnp.asarray(lens))
     out = f(jnp.asarray(reads), jnp.asarray(lens))
     mapped = int(np.sum(~np.asarray(out.failed)))

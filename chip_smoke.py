@@ -20,8 +20,8 @@ One chip:
   exact DP oracle (`repro.align.refdp`); positions must be correct.
 * **graph** — the same checks with GAF, ``graph_pallas`` against
   ``graph_lax``, over a variation graph whose backbone is cut to
-  ``--graph-len`` (the tiled graph index stores ~100 B per backbone
-  base at the 1280 rung) with one variant per 200 bp.
+  ``--graph-len`` (the graph index holds each node once, about 22 B
+  per backbone base on the device) with one variant per 200 bp.
 
 Four chips (``--chips 4``, this phase only): the linear service with
 ``num_shards=4`` on a 4-device mesh (``spmd`` asserted), with and
@@ -265,6 +265,7 @@ def run_graph(args) -> None:
     import numpy as np
     from repro.genomics import io, simulate
     from repro.graph.index import build_epoched_graph_index
+    from repro.graph.windowed import unpack_graph_text
     from repro.launch.serve_genomics import gaf_row
 
     cfg0 = engine_config(workload="graph")
@@ -273,16 +274,15 @@ def run_graph(args) -> None:
     variants = simulate.simulate_variants(
         ref, n_snp=n_var // 2, n_ins=n_var // 4, n_del=n_var // 4,
         seed=args.seed + 6)
-    log(f"graph: backbone cut to {args.graph_len} bp (the tile store "
-        f"costs ~100 B per backbone base at the 1280 rung), "
-        f"{len(variants)} variants")
+    log(f"graph: backbone cut to {args.graph_len} bp (the index holds "
+        f"~22 B per backbone base on the device), {len(variants)} variants")
     t0 = time.perf_counter()
     egi = build_epoched_graph_index(
         ref, variants, window=max(cfg0.buckets) + 2 * cfg0.genasm.w)
     garr = egi.index.arrays
     log(f"graph: index built in {time.perf_counter() - t0:.1f} s, "
         f"{nbytes(garr)} bytes on the device ({egi.index.n_nodes} nodes, "
-        f"{egi.index.n_tiles} tiles of {egi.index.tile_len})")
+        f"{egi.index.n_tiles} screen tiles of {egi.index.tile_len})")
     reads, true_pos = make_reads(ref, n_short=args.reads // 2,
                                  n_long=args.long_reads // 2,
                                  seed=args.seed + 7)
@@ -304,8 +304,8 @@ def run_graph(args) -> None:
     check(gk == gl, f"graph: {kern} GAF differs from graph_lax GAF")
     log(f"graph: {kern} GAF byte-identical to graph_lax ({len(gk)} bytes)")
     position_check("graph", rows, true_pos, len(reads))
-    graph_oracle_check(np.asarray(garr.bases), np.asarray(garr.succ_bits),
-                       res, reads, w=64)
+    bases, succ = unpack_graph_text(np.asarray(garr.gtext))
+    graph_oracle_check(np.asarray(bases), np.asarray(succ), res, reads, w=64)
 
 
 def run_sharded(args) -> None:

@@ -8,16 +8,16 @@ hop window are encoded as per-node **hopBits** (paper Figure 6-9): bit
 The linearization keeps variant branches adjacent to their backbone
 position so real variation graphs have small hop distances; edges beyond
 ``HOP_LIMIT`` would need graph re-chunking (the paper picks the hop limit
-so this does not occur; construction raises so the caller can re-chunk —
-`repro.graph.index` does exactly that for its tiled index).
+so this does not occur; construction raises so the caller can re-chunk).
 
-Construction is linear in nodes + edges: predecessor lists are tracked
-while the linearization is emitted (a SNP branch copies the predecessor
-list its backbone twin was just given), and hopBits are accumulated with
-one vectorized scatter at the end.
+Construction is a few numpy passes over positions and variants, not a
+loop: node ids come from a cumulative sum of each position's node count,
+and hopBits from one shifted entry mask per edge source (a position's
+entries are its backbone node and its SNP branch heads).
 """
 from __future__ import annotations
 
+import itertools
 from dataclasses import dataclass
 from typing import NamedTuple
 
@@ -54,96 +54,158 @@ class GenomeGraph:
         return int(self.bases.shape[0])
 
 
-def build_graph(ref: np.ndarray, variants: list[Variant] = ()) -> GenomeGraph:
+_KIND = {"snp": 0, "ins": 1, "del": 2}
+
+
+def _columns(variants):
+    """A ``Variant`` list as columns ``(pos, kind, alt_len, alt, span)``:
+    kind coded by ``_KIND`` (-1 unknown), every allele flattened into
+    ``alt`` in list order."""
+    if not len(variants):
+        z = np.zeros(0, np.int64)
+        return z, z, z, np.zeros(0, np.int8), z
+    pos, kind, alt, span = zip(*variants)
+    n = len(pos)
+    alt_len = np.fromiter(map(len, alt), np.int64, n)
+    flat = np.fromiter(itertools.chain.from_iterable(alt), np.int8,
+                       int(alt_len.sum()))
+    names = np.asarray(kind, dtype=object)
+    code = np.full(n, -1, np.int64)
+    for name, c in _KIND.items():
+        code[names == name] = c
+    return (np.fromiter(pos, np.int64, n), code, alt_len, flat,
+            np.fromiter(span, np.int64, n))
+
+
+def _first_error(variants, idx, pos, kind, alt_len, span, n_ref) -> None:
+    """Raise for the malformed variant a walk over backbone positions
+    meets first: the lowest position, the earlier in the list on a tie."""
+    tgt = pos + span + 1
+    bad = ((kind < 0) | ((kind == 0) & (alt_len == 0))
+           | ((kind == 2) & (tgt >= n_ref)))
+    if not bad.any():
+        return
+    j = np.flatnonzero(bad)[np.argmin(pos[bad])]  # argmin: first on a tie
+    v = variants[int(idx[j])]
+    if kind[j] < 0:
+        raise ValueError(v.kind)
+    if kind[j] == 0:
+        raise ValueError(f"snp at {v.pos} needs a non-empty alt")
+    raise ValueError(
+        f"del at {v.pos} (span {v.span}) lands at backbone {int(tgt[j])}, "
+        f"past the reference end {n_ref}; trim the variant or extend the "
+        f"reference")
+
+
+def _runs(starts: np.ndarray, lens: np.ndarray) -> np.ndarray:
+    """``concat([arange(s, s + n) for s, n in zip(starts, lens)])``."""
+    first = np.cumsum(lens) - lens
+    return np.repeat(starts - first, lens) + np.arange(int(lens.sum()))
+
+
+def build_graph(ref: np.ndarray, variants=()) -> GenomeGraph:
     """Build a variation graph from a linear reference + variant list.
 
+    Node order: each backbone position's base, then the alt nodes of the
+    variants at that position (``snp`` branches and ``ins`` chains, in
+    list order).  A position's *entries* are its backbone node and the
+    first node of each of its ``snp`` branches; each takes an edge from
+    the backbone node before it, from the last node of every branch or
+    insertion there, and from every deletion landing on the position
+    (``pos + span + 1``).  An insertion's first node follows its own
+    backbone node.  Variants off the reference are ignored, as is a
+    deletion landing at or before its own position.
+
     Raises ``ValueError`` for malformed variants: an empty ``snp`` alt, a
-    deletion whose landing position ``pos + span + 1`` falls past the
-    reference end (it would silently vanish otherwise), or any edge whose
-    hop distance exceeds ``HOP_LIMIT``.
+    deletion whose landing position falls past the reference end (it
+    would silently vanish otherwise), or any edge whose hop distance
+    exceeds ``HOP_LIMIT``.
     """
     L = len(ref)
-    # nodes assembled in backbone order; alt nodes inserted adjacent
-    bases: list[int] = []
-    backbone: list[int] = []
-    src: list[int] = []  # edge sources
-    dst: list[int] = []  # edge targets
-    node_of_backbone = np.full(L, -1, np.int64)
+    pos, kind, alt_len, flat, span = _columns(variants)
+    alt_at = np.cumsum(alt_len) - alt_len  # each allele's start in ``flat``
+    idx = np.flatnonzero((pos >= 0) & (pos < L))
+    _first_error(variants, idx, pos[idx], kind[idx], alt_len[idx],
+                 span[idx], L)
+    idx = idx[np.argsort(pos[idx], kind="stable")]  # list order on a tie
+    pos, kind, alt_len, alt_at, span = (
+        a[idx] for a in (pos, kind, alt_len, alt_at, span))
 
-    by_pos: dict[int, list[Variant]] = {}
-    for v in variants:
-        by_pos.setdefault(v.pos, []).append(v)
+    # node ids: a cumulative sum of each position's node count
+    n_alt = np.where(kind < 2, alt_len, 0)
+    counts = np.ones(L, np.uint32)
+    np.add.at(counts, pos, n_alt.astype(np.uint32))
+    nob = np.cumsum(counts, dtype=np.int64)
+    n = int(nob[-1]) if L else 0
+    if n >= 2 ** 31:
+        raise ValueError(f"{n} nodes overflow int32 node ids")
+    nob -= counts
+    nob = nob.astype(np.int32)  # node of each backbone position
+    before = np.cumsum(n_alt) - n_alt  # alt nodes of earlier variants
+    first = nob[pos] + 1 + before - before[np.searchsorted(pos, pos)]
+    bases = np.empty(n, np.int8)
+    bases[nob] = ref
+    backbone = np.full(n, -1, np.int32)
+    backbone[nob] = np.arange(L, dtype=np.int32)
+    bases[_runs(first, n_alt)] = flat[_runs(alt_at, n_alt)]
 
-    prev_tails: list[int] = []  # node ids whose successor is the next backbone node
-    pending_del: dict[int, list[int]] = {}  # backbone pos -> node ids jumping here
-    for p in range(L):
-        nid = len(bases)
-        bases.append(int(ref[p]))
-        backbone.append(p)
-        node_of_backbone[p] = nid
-        preds = prev_tails + pending_del.pop(p, [])
-        for t in preds:
-            src.append(t)
-            dst.append(nid)
-        prev_tails = [nid]
-        for v in by_pos.get(p, []):
-            if v.kind == "snp":
-                if not v.alt:
-                    raise ValueError(f"snp at {p} needs a non-empty alt")
-                # branch carrying the alt allele: the first alt node shares
-                # nid's predecessor list (tracked above — no edge rescans),
-                # further alt bases chain behind it
-                prev = -1
-                for j, ab in enumerate(v.alt):
-                    alt_id = len(bases)
-                    bases.append(int(ab))
-                    backbone.append(-1)
-                    for a in (preds if j == 0 else [prev]):
-                        src.append(a)
-                        dst.append(alt_id)
-                    prev = alt_id
-                prev_tails.append(prev)
-            elif v.kind == "ins":
-                prev = nid
-                for ab in v.alt:
-                    alt_id = len(bases)
-                    bases.append(int(ab))
-                    backbone.append(-1)
-                    src.append(prev)
-                    dst.append(alt_id)
-                    prev = alt_id
-                prev_tails.append(prev)
-            elif v.kind == "del":
-                tgt = p + v.span + 1
-                if tgt >= L:
-                    raise ValueError(
-                        f"del at {p} (span {v.span}) lands at backbone "
-                        f"{tgt}, past the reference end {L}; trim the "
-                        f"variant or extend the reference")
-                pending_del.setdefault(tgt, []).append(nid)
-            else:
-                raise ValueError(v.kind)
+    # a position's entries as bits above its backbone node: bit 0, and the
+    # offset of each snp head (``heads``, few: kept sparse in zeroed
+    # arrays); ``top`` is the furthest entry's offset
+    has = n_alt > 0
+    head = (kind == 0) & has
+    off = (first - nob[pos])[head]
+    extra = np.zeros(L, np.uint32)
+    near = off < HOP_LIMIT
+    np.bitwise_or.at(extra, pos[head][near],
+                     np.uint32(1) << off[near].astype(np.uint32))
+    top = np.zeros(L, np.int32)
+    np.maximum.at(top, pos[head], off.astype(np.int32))
+    heads = np.unique(pos[head])
+    heads = heads[heads > 0]
 
-    n = len(bases)
+    # sparse source groups: (sources, hop of the edge to the backbone
+    # entry, target position); the backbone chain, which holds nearly every
+    # edge, is handled whole below
+    tail = first + n_alt - 1
+    dels = (kind == 2) & (span >= 0)
+    groups = []
+    for s, p in ((tail[has], pos[has] + 1), (nob[pos[dels]],
+                                             (pos + span + 1)[dels])):
+        s, p = s[p < L], p[p < L]  # tails at the last position lead nowhere
+        groups.append((s, nob[p] - s - 1, p))
+    ins = (kind == 1) & has
+    ih = nob[pos[ins]]
+    groups.append((ih, first[ins] - ih - 1, None))
+    chain = counts[:-1] - np.uint32(1)  # hop from each backbone node
+
+    def reach(s, hop, p):  # (hop, target) of each source's furthest edge
+        far = hop.astype(np.int64) + (0 if p is None else top[p])
+        return far, s + far + 1
+
+    worst = max([int(chain.max(initial=0)),
+                 int((chain[heads - 1] + top[heads]).max(initial=0))]
+                + [int(reach(*g)[0].max()) for g in groups if len(g[0])])
+    if worst >= HOP_LIMIT:  # the loop named the first such edge it made
+        whole = (nob[:-1], chain, np.arange(1, L))
+        far = min(int(t[h == worst].min())
+                  for h, t in (reach(*g) for g in [whole] + groups)
+                  if (h == worst).any())
+        raise ValueError(
+            f"edge {far - worst - 1}->{far} hop {worst + 1} exceeds "
+            f"HOP_LIMIT={HOP_LIMIT}; re-chunk the graph")
+
     succ = np.zeros(n, np.uint32)
-    if src:
-        a = np.asarray(src, np.int64)
-        b = np.asarray(dst, np.int64)
-        hop = b - a - 1
-        if hop.min() < 0:
-            raise ValueError("graph not topologically ordered")
-        if hop.max() >= HOP_LIMIT:
-            w = int(hop.argmax())
-            raise ValueError(
-                f"edge {int(a[w])}->{int(b[w])} hop {int(hop[w]) + 1} "
-                f"exceeds HOP_LIMIT={HOP_LIMIT}; re-chunk the graph")
-        np.bitwise_or.at(succ, a, np.uint32(1) << hop.astype(np.uint32))
-    return GenomeGraph(
-        bases=np.array(bases, np.int8),
-        succ_bits=succ,
-        backbone=np.array(backbone, np.int32),
-        node_of_backbone=node_of_backbone.astype(np.int32),
-    )
+    bits = np.left_shift(np.uint32(1), chain)
+    bits[heads - 1] |= extra[heads] << chain[heads - 1]
+    succ[nob[:-1]] = bits  # distinct sources
+    del bits
+    for s, hop, p in groups:
+        entry = np.uint32(1) if p is None else extra[p] | np.uint32(1)
+        np.bitwise_or.at(succ, s, entry << hop.astype(np.uint32))
+    succ[_runs(first, np.maximum(n_alt - 1, 0))] |= np.uint32(1)  # alleles
+    return GenomeGraph(bases=bases, succ_bits=succ, backbone=backbone,
+                       node_of_backbone=nob)
 
 
 def linear_graph(ref: np.ndarray) -> GenomeGraph:

@@ -32,8 +32,8 @@ from repro.core.genasm import AlignResult, GenASMConfig, pad_pattern, \
 from repro.core.genasm_tb import OP_PAD
 
 from .windowed import (_graph_buf_cap, _scatter_windows, graph_align,
-                       pack_linear_text, pad_graph_text, unpack_graph_text,
-                       window_tb_graph)
+                       pack_linear_text, pad_graph_text, text_window,
+                       window_tb_graph, window_view)
 
 
 def as_graph_text(texts: jnp.ndarray) -> jnp.ndarray:
@@ -87,19 +87,20 @@ def batched_graph_align(
         lambda t, tl: pad_graph_text(t, tl, _graph_buf_cap(p_cap, cfg), cfg))(
         gtexts, t_lens)
 
+    tw = text_window(cfg)
     slice_w = jax.vmap(lambda buf, i: lax.dynamic_slice(buf, (i,), (w,)))
+    slice_t = jax.vmap(lambda buf, i: lax.dynamic_slice(buf, (i,), (tw,)))
     tb_fn = jax.vmap(
         partial(window_tb_graph, w=w, o=o, k=k, affine=cfg.affine))
-    full_w = jnp.full((b,), w, jnp.int32)  # no tail mask: full windows
 
     def window_step(carry, _):
         cur_p, cur_t = carry[0], carry[1]
         sub_p = slice_w(pats, cur_p)  # [B, w]
-        sub_g = slice_w(gbufs, cur_t)
-        bases, succ = unpack_graph_text(sub_g)
+        sub_g = slice_t(gbufs, cur_t)
+        bases, succ, p_win = window_view(sub_g, p_lens - cur_p, w)
         d_all, store = bitalign_dc_batch(
-            bases, succ, sub_p, full_w, m_bits=w, k=k, block_bt=block_bt,
-            interpret=interpret)  # store: [B, w, k+1, nw]
+            bases, succ, sub_p, p_win, m_bits=w, k=k, block_bt=block_bt,
+            interpret=interpret)  # store: [B, tw, k+1, nw]
         d_min = d_all[:, 0]  # anchored at window node 0
         cap_p = jnp.minimum(jnp.int32(cfg.commit), p_lens - cur_p)
         pm = jax.vmap(lambda p: pattern_bitmasks(p, w))(sub_p)

@@ -1,24 +1,26 @@
-"""Tiled graph-reference index: graphs far longer than one BitAlign window.
+"""Graph-reference index: one packed graph text, windows gathered from it.
 
-The whole linearized graph lives on device once, *plus* a tiled view:
-overlapping fixed-size tiles at ``tile_stride`` node pitch, each packed
-as graph text (`windowed.pack_graph_text`) with its hopBits cut at the
-tile boundary by the one shared masking rule
-(`core/segram/graph.hop_boundary_mask`).  A candidate backbone position
-maps to a tile via ``node // tile_stride`` — no per-read dynamic slicing
-of the full graph; the mapper's candidate windows are **one gather**
-``tile_gtext[tile_ids]`` per batch, which is what turns per-read
-per-candidate scans into a single ``[B, max_candidates]`` BitAlign-DC
+The linearized graph lives on the device once, as packed graph text
+(`windowed.pack_graph_text`: one uint32 per node, base and hopBits).  A
+candidate backbone position maps to a tile via ``node // tile_stride``;
+its window is gathered from the text at ``tile * tile_stride`` and cut at
+its own end by the one shared masking rule
+(`core/segram/graph.hop_boundary_mask`), so a gathered window is bitwise
+`extract_subgraph` at the same start and length (`gather_windows`).  The
+mapper gathers one ``[rows, length]`` block per batch, at the length the
+bucket rung needs, which keeps one ``[B, max_candidates]`` BitAlign-DC
 launch per step.
 
-Tile geometry: ``tile_len = tile_stride + margin + window``.  A
+Per tile the index keeps only the q-gram screen's payload, over the tile
+``tile_len = tile_stride + margin + window`` nodes long: a Bloom filter
+of its q-grams and the q-gram-lemma slack of its hop>1 edges.  A
 candidate's anchor is refined inside ``[0, tile_stride + margin)`` (the
 first ``tile_stride`` nodes own the tile, ``margin`` absorbs seed
 quantization + leading-variation drift), and ``window`` nodes of
 alignment text always remain past any refined anchor.  Edges whose hop
-would exceed ``HOP_LIMIT`` keep raising in `build_graph` — the tiling
-re-chunks *windows*, not edges, so the invariant the BitAlign PE design
-relies on (Figure 6-8's bounded hop queue) holds per tile by
+would exceed ``HOP_LIMIT`` keep raising in `build_graph` — windows are
+cut from the graph, not edges, so the invariant the BitAlign PE design
+relies on (Figure 6-8's bounded hop queue) holds per window by
 construction.
 
 ``EpochedGraphIndex`` mirrors `core/minimizer_index.EpochedIndex`: the
@@ -37,13 +39,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.bitvector import SENTINEL
-from repro.core.filter import QGRAM_Q, qgram_bloom
-from repro.core.segram.graph import (GenomeGraph, Variant, build_graph,
-                                     hop_boundary_mask)
+from repro.core.filter import BLOOM_WORDS, QGRAM_Q, _bloom_probes
+from repro.core.segram.graph import (HOP_LIMIT, GenomeGraph, Variant,
+                                     build_graph, hop_boundary_mask)
 from repro.core.segram.minimizer import build_index
 
-from .windowed import pack_graph_text
+from .windowed import SENT_NODE, pack_graph_text, unpack_graph_text
 
 DEFAULT_WINDOW = 256
 DEFAULT_STRIDE = 64
@@ -53,12 +54,9 @@ DEFAULT_MARGIN = 64
 class GraphArrays(NamedTuple):
     """Device half of the index (a jit-traceable pytree)."""
 
-    bases: jnp.ndarray  # [N] int8 linearized graph
-    succ_bits: jnp.ndarray  # [N] uint32 hopBits
+    gtext: jnp.ndarray  # [N] uint32 packed graph text, each node once
     backbone: jnp.ndarray  # [N] int32 backbone coord (-1 for alt nodes)
     node_of_backbone: jnp.ndarray  # [L] int32
-    tile_gtext: jnp.ndarray  # [C, tile_len] uint32 packed tiles
-    tile_valid: jnp.ndarray  # [C] int32 valid node count per tile
     idx_hashes: jnp.ndarray  # [M] uint32 sorted backbone minimizers
     idx_positions: jnp.ndarray  # [M] int32
     tile_bloom: jnp.ndarray  # [C, BLOOM_WORDS] uint32 per-tile q-gram Bloom
@@ -80,59 +78,173 @@ class GraphIndex:
 
     @property
     def n_nodes(self) -> int:
-        return int(self.arrays.bases.shape[0])
+        return int(self.arrays.gtext.shape[0])
 
     @property
     def n_tiles(self) -> int:
-        return int(self.arrays.tile_gtext.shape[0])
+        return int(self.arrays.tile_slack.shape[0])
 
     @property
     def ref_len(self) -> int:
         return int(len(self.ref))
 
+    @property
+    def geometry(self) -> tuple[int, int, int]:
+        """``(tile_stride, margin, window)``: what a mapper bakes in."""
+        return self.tile_stride, self.margin, self.window
 
-# tiles per device pass of the tile build: one tile row costs ~50 B per
-# node of q-gram/gather intermediates, so large graphs build in chunks
+
+def gather_windows(gtext: jnp.ndarray, starts: jnp.ndarray, length: int, *,
+                   n_nodes: int, node_base=0) -> jnp.ndarray:
+    """Packed windows ``[..., length]`` of the graph at global nodes
+    ``starts``, bitwise `extract_subgraph` at the same start and length.
+
+    ``gtext`` holds nodes ``node_base ..`` of an ``n_nodes``-node graph
+    (all of it in the whole-graph view); nodes past the graph end read as
+    sentinels, and hopBits leaving a window are cut at its end.
+    """
+    with jax.named_scope("graph_window_gather"):
+        starts = jnp.asarray(starts, jnp.int32)
+        idx = starts[..., None] + jnp.arange(length, dtype=jnp.int32)
+        local = jnp.clip(idx - node_base, 0, gtext.shape[-1] - 1)
+        win = jnp.where(idx < n_nodes, gtext[local], jnp.uint32(SENT_NODE))
+        valid = jnp.clip(n_nodes - starts, 0, length)
+        mask = hop_boundary_mask(length, valid[..., None])
+        return win & ((mask << 8) | jnp.uint32(0xFF))
+
+
+# tiles per device pass of the screen build: a pass reads one contiguous
+# slice of the text and sets the Bloom bits of every block in it at once
 TILE_CHUNK = 8192
 
 
-@partial(jax.jit, static_argnames=("n_rows", "tile_len", "tile_stride"))
-def _tile_rows(bases: jnp.ndarray, succ: jnp.ndarray, row0, *, n_rows: int,
-               tile_len: int, tile_stride: int):
-    """Tiles ``row0 .. row0 + n_rows`` (rows past the graph are empty)."""
-    n = bases.shape[0]
-    starts = (row0 + jnp.arange(n_rows)) * tile_stride
-    idx = starts[:, None] + jnp.arange(tile_len)[None, :]
-    inb = idx < n
-    idxc = jnp.clip(idx, 0, n - 1)
-    tb = jnp.where(inb, bases[idxc], SENTINEL).astype(jnp.int8)
-    ts = jnp.where(inb, succ[idxc], jnp.uint32(0))
-    valid = jnp.clip(n - starts, 0, tile_len).astype(jnp.int32)
-    mask = jax.vmap(lambda v: hop_boundary_mask(tile_len, v))(valid)
-    ts_m = ts & mask
-    # tile pre-filter payload: a Bloom filter over the tile's q-grams and
-    # the q-gram-lemma slack for alt paths — a matching path may spell up
-    # to q-1 q-grams across each hop>1 edge (bits 1.. of the masked
-    # hopBits) that are not substrings of the linearization
-    bloom = jax.vmap(qgram_bloom)(tb, valid)
-    in_valid = jnp.arange(tile_len)[None, :] < valid[:, None]
-    hop_edges = jnp.where(in_valid, jax.lax.population_count(ts_m >> 1), 0)
-    slack = ((QGRAM_Q - 1) *
-             jnp.sum(hop_edges, axis=-1)).astype(jnp.int32)
-    return pack_graph_text(tb, ts_m), valid, bloom, slack
+def _window_or(x: jnp.ndarray, n: int, rows: int) -> jnp.ndarray:
+    """``out[c] = x[c] | .. | x[c + n - 1]`` for ``c < rows``, by doubling."""
+    out, off, run = jnp.zeros_like(x[:rows]), 0, x
+    for bit in range(n.bit_length()):
+        step = 1 << bit
+        if n & step:
+            out = out | run[off:off + rows]
+            off += step
+        if step * 2 <= n:
+            run = run[:-step] | run[step:]
+    return out
 
 
-def _build_tiles(bases: jnp.ndarray, succ: jnp.ndarray, *, tile_len: int,
+def _block_blooms(x: jnp.ndarray, n_blocks: int, size: int, part: int):
+    """Bloom words ``[n_blocks, BLOOM_WORDS]`` of the q-grams starting at
+    offsets ``[0, part)`` (``head``) and ``[part, size)`` (``rest``) of each
+    ``size``-node block of the packed text ``x``.
+
+    A block's 2·``size`` probes set their bits through one one-hot matmul
+    (probe → word times probe → bit, counted), not a scatter per q-gram.
+    """
+    q, m = QGRAM_Q, n_blocks * size
+    base = (x & jnp.uint32(0xFF)).astype(jnp.int32)
+    ok = jnp.ones((m,), bool)
+    code = jnp.zeros((m,), jnp.uint32)
+    for j in range(q):  # q-gram codes by shifted slices, no gather
+        b = base[j:j + m]
+        ok = ok & (b < 4)
+        code = code | ((b & 3).astype(jnp.uint32) << (2 * (q - 1 - j)))
+    probes = jnp.stack(_bloom_probes(code), -1).reshape(n_blocks, 2 * size)
+    ok = jnp.repeat(ok.reshape(n_blocks, size), 2, axis=-1)
+    head = jnp.repeat(jnp.arange(size) < part, 2)
+    word = ((probes >> 5)[..., None]
+            == jnp.arange(BLOOM_WORDS, dtype=jnp.uint32))
+    bit = (probes & 31)[..., None] == jnp.arange(32, dtype=jnp.uint32)
+    bits = jnp.concatenate([bit & (ok & head)[..., None],
+                            bit & (ok & ~head)[..., None]], -1)
+    count = jnp.einsum("rpw,rpk->rwk", word.astype(jnp.bfloat16),
+                       bits.astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32)
+    weight = jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32)
+    packed = jnp.sum(jnp.where(count > 0, jnp.tile(weight, 2), 0).reshape(
+        n_blocks, BLOOM_WORDS, 2, 32), axis=-1, dtype=jnp.uint32)
+    return packed[..., 0], packed[..., 1]
+
+
+def _screen_rows(text: jnp.ndarray, row0, *, rows: int, tile_len: int,
                  tile_stride: int):
-    n = bases.shape[0]
-    c = max(1, -(-int(n) // tile_stride))
-    rows = min(c, TILE_CHUNK)
-    parts = [_tile_rows(bases, succ, r0, n_rows=rows, tile_len=tile_len,
+    """Bloom and slack of tiles ``row0 .. row0 + rows`` from one slice of
+    ``text`` (the graph text with sentinels past its end).
+
+    A tile's Bloom holds the q-grams starting in its first ``tile_len -
+    q + 1`` nodes: ``full`` whole blocks of ``tile_stride`` nodes and a
+    ``part``-node prefix of the next, so it is the OR of per-block Blooms
+    (each q-gram hashed once, not once per overlapping tile).
+    """
+    q, s, h = QGRAM_Q, tile_stride, HOP_LIMIT
+    full, part = divmod(tile_len - q + 1, s)
+    n_blocks = rows + full + 1
+    x = jax.lax.dynamic_slice(text, (row0 * s,), (n_blocks * s + q - 1,))
+    head, rest = _block_blooms(x, n_blocks, s, part)
+    bloom = _window_or(head | rest, full, rows) | head[full:full + rows]
+    # slack: the q-gram-lemma allowance for alt paths — a matching path may
+    # spell up to q-1 q-grams across each hop>1 edge (bits 1.. of the
+    # hopBits) that are not substrings of the linearization.  Edges of a
+    # tile's last ``h`` nodes are cut at its end; no edge leaves the graph
+    hops = lambda t: jax.lax.population_count(  # noqa: E731
+        (t >> 9) & jnp.uint32((1 << (h - 1)) - 1)).astype(jnp.int32)
+    cs = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                          jnp.cumsum(hops(x[:n_blocks * s]))])
+    body = cs[tile_len - h::s][:rows] - cs[::s][:rows]
+    last = x[tile_len - h:tile_len - h + rows * s].reshape(rows, s)[:, :h]
+    cut = (hop_boundary_mask(h, h) << 8) | jnp.uint32(0xFF)
+    slack = (q - 1) * (body + jnp.sum(hops(last & cut), axis=-1))
+    return bloom, slack.astype(jnp.int32)
+
+
+@partial(jax.jit, donate_argnums=(1, 2),
+         static_argnames=("rows", "tile_len", "tile_stride"))
+def _put_screen(text, bloom, slack, row0, *, rows: int, tile_len: int,
+                tile_stride: int):
+    b, s = _screen_rows(text, row0, rows=rows, tile_len=tile_len,
                         tile_stride=tile_stride)
-             for r0 in range(0, c, rows)]
-    if len(parts) == 1:
-        return parts[0]
-    return tuple(jnp.concatenate(p)[:c] for p in zip(*parts))
+    return (jax.lax.dynamic_update_slice(bloom, b, (row0, 0)),
+            jax.lax.dynamic_update_slice(slack, s, (row0,)))
+
+
+def _build_screen(gtext: jnp.ndarray, *, tile_len: int, tile_stride: int):
+    """``(tile_bloom [C, BLOOM_WORDS], tile_slack [C])`` of every tile,
+    ``TILE_CHUNK`` tiles a pass; the last pass ends at the last tile."""
+    if tile_stride < HOP_LIMIT or tile_len < HOP_LIMIT + QGRAM_Q:
+        raise ValueError(f"tile_stride {tile_stride} / tile_len {tile_len} "
+                         f"too short for the screen")
+    n = int(gtext.shape[0])
+    c = max(1, -(-n // tile_stride))
+    rows = min(c, TILE_CHUNK)
+    pad = (tile_len // tile_stride + 3) * tile_stride + QGRAM_Q
+    text = jnp.concatenate([gtext, jnp.full((pad,), SENT_NODE, jnp.uint32)])
+    bloom = jnp.zeros((c, BLOOM_WORDS), jnp.uint32)
+    slack = jnp.zeros((c,), jnp.int32)
+    for r0 in range(0, c, rows):
+        bloom, slack = _put_screen(text, bloom, slack, min(r0, c - rows),
+                                   rows=rows, tile_len=tile_len,
+                                   tile_stride=tile_stride)
+    return bloom, slack
+
+
+@jax.jit
+def _pack(bases: jnp.ndarray, succ: jnp.ndarray) -> jnp.ndarray:
+    return pack_graph_text(bases, succ)
+
+
+def _device_arrays(bases, succ, backbone, node_of_backbone, hashes,
+                   positions, *, tile_len: int, tile_stride: int
+                   ) -> GraphArrays:
+    gtext = _pack(jnp.asarray(bases), jnp.asarray(succ))
+    bloom, slack = _build_screen(gtext, tile_len=tile_len,
+                                 tile_stride=tile_stride)
+    return GraphArrays(
+        gtext=gtext,
+        backbone=jnp.asarray(backbone),
+        node_of_backbone=jnp.asarray(node_of_backbone),
+        idx_hashes=jnp.asarray(hashes),
+        idx_positions=jnp.asarray(positions),
+        tile_bloom=bloom,
+        tile_slack=slack,
+    )
 
 
 def build_graph_index(
@@ -147,30 +259,17 @@ def build_graph_index(
     margin: int = DEFAULT_MARGIN,
     graph: GenomeGraph | None = None,
 ) -> GraphIndex:
-    """Offline pre-processing (paper §6.5): graph + minimizers + tiles.
+    """Offline pre-processing (paper §6.5): graph + minimizers + screen.
 
     ``window`` must cover the largest alignment text cap the mapper will
     slice (``p_cap + 2·cfg.w``); `repro.graph.mapper.map_batch` checks.
     """
-    g = graph if graph is not None else build_graph(ref, list(variants))
+    g = graph if graph is not None else build_graph(ref, variants)
     idx = build_index(ref, w=w, k=k, freq_frac=freq_frac)
-    bases = jnp.asarray(g.bases)
-    succ = jnp.asarray(g.succ_bits)
     tile_len = tile_stride + margin + window
-    tiles, valid, bloom, slack = _build_tiles(bases, succ, tile_len=tile_len,
-                                              tile_stride=tile_stride)
-    arrays = GraphArrays(
-        bases=bases,
-        succ_bits=succ,
-        backbone=jnp.asarray(g.backbone),
-        node_of_backbone=jnp.asarray(g.node_of_backbone),
-        tile_gtext=tiles,
-        tile_valid=valid,
-        idx_hashes=jnp.asarray(idx.hashes),
-        idx_positions=jnp.asarray(idx.positions),
-        tile_bloom=bloom,
-        tile_slack=slack,
-    )
+    arrays = _device_arrays(g.bases, g.succ_bits, g.backbone,
+                            g.node_of_backbone, idx.hashes, idx.positions,
+                            tile_len=tile_len, tile_stride=tile_stride)
     return GraphIndex(arrays=arrays, ref=np.asarray(ref, np.int8),
                       tile_len=tile_len, tile_stride=tile_stride,
                       minimizer_w=w, minimizer_k=k, window=window,
@@ -183,7 +282,7 @@ class EpochedGraphIndex:
     ``refresh()`` rebuilds from a new reference and/or variant list and
     bumps ``epoch``; the serve engine's result cache keys on the epoch so
     every result mapped against the old graph is atomically invalidated,
-    and its compiled executors re-trace on the new tile shapes.
+    and its compiled executors re-trace on the new graph's shapes.
     """
 
     def __init__(self, index: GraphIndex, *, variants: Sequence[Variant] = (),
@@ -230,12 +329,13 @@ def build_epoched_graph_index(ref: np.ndarray,
 
 
 def save_graph_index(path: str | Path, gidx: GraphIndex) -> None:
-    """Persist to npz (tiles are re-derived on load, not stored)."""
+    """Persist to npz (the screen is re-derived on load, not stored)."""
     a = gidx.arrays
+    bases, succ = unpack_graph_text(np.asarray(a.gtext))
     np.savez_compressed(
         path,
-        bases=np.asarray(a.bases),
-        succ_bits=np.asarray(a.succ_bits),
+        bases=np.asarray(bases),
+        succ_bits=np.asarray(succ),
         backbone=np.asarray(a.backbone),
         node_of_backbone=np.asarray(a.node_of_backbone),
         idx_hashes=np.asarray(a.idx_hashes),
@@ -251,22 +351,10 @@ def load_graph_index(path: str | Path) -> GraphIndex:
     with np.load(path) as z:
         tile_len, tile_stride, w, k, window, margin = (
             int(x) for x in z["meta"])
-        bases = jnp.asarray(z["bases"])
-        succ = jnp.asarray(z["succ_bits"])
-        tiles, valid, bloom, slack = _build_tiles(
-            bases, succ, tile_len=tile_len, tile_stride=tile_stride)
-        arrays = GraphArrays(
-            bases=bases,
-            succ_bits=succ,
-            backbone=jnp.asarray(z["backbone"]),
-            node_of_backbone=jnp.asarray(z["node_of_backbone"]),
-            tile_gtext=tiles,
-            tile_valid=valid,
-            idx_hashes=jnp.asarray(z["idx_hashes"]),
-            idx_positions=jnp.asarray(z["idx_positions"]),
-            tile_bloom=bloom,
-            tile_slack=slack,
-        )
+        arrays = _device_arrays(
+            z["bases"], z["succ_bits"], z["backbone"], z["node_of_backbone"],
+            z["idx_hashes"], z["idx_positions"], tile_len=tile_len,
+            tile_stride=tile_stride)
         return GraphIndex(arrays=arrays, ref=z["ref"].astype(np.int8),
                           tile_len=tile_len, tile_stride=tile_stride,
                           minimizer_w=w, minimizer_k=k, window=window,

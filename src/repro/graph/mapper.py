@@ -1,6 +1,6 @@
 """End-to-end sequence-to-graph read mapper (paper Figure 6-1, batched).
 
-Seed-and-extend over a tiled graph index, as a three-stage pipeline:
+Seed-and-extend over a graph index, as a three-stage pipeline:
 
   * **Stage A — seed + tile pre-filter** (`tile_prefilter`): MinSeed
     minimizer seeding on the backbone, then a q-gram Bloom screen over
@@ -27,8 +27,8 @@ Seed-and-extend over a tiled graph index, as a three-stage pipeline:
     launch entirely (`unmapped_result`) without changing any output.
 
 The candidate stage is written against a :class:`GraphView` — local
-tile/backbone slices plus the global offsets of their first rows — so
-the whole-graph mapper and the sharded mapper
+graph text, screen and backbone slices plus the global offsets of their
+first rows — so the whole-graph mapper and the sharded mapper
 (`repro.shard.graph_mapper`) run the *same* seeding/screen/filter/
 selection code: per-candidate distances, refined anchors, and window
 bytes are bit-identical at 1 and N shards.
@@ -57,7 +57,7 @@ from repro.core.mapper import POS_SENTINEL
 from repro.core.segram.graph import HOP_LIMIT
 from repro.core.segram.minimizer import seed_candidates
 
-from .index import GraphArrays, GraphIndex
+from .index import GraphArrays, GraphIndex, gather_windows
 from .windowed import bitalign_search, unpack_graph_text
 
 # linear backend names map to their graph twins so ``backend="auto"`` (or
@@ -92,7 +92,7 @@ class GraphMapResult(NamedTuple):
 
 
 class GraphView(NamedTuple):
-    """One shard's (or the whole graph's) view of a tiled graph index.
+    """One shard's (or the whole graph's) view of a graph index.
 
     Local array slices plus the global coordinate of each slice's first
     row; the whole-graph view has all offsets 0.  ``idx_positions`` stay
@@ -100,13 +100,12 @@ class GraphView(NamedTuple):
     candidates then needs no translation step.
     """
 
-    tile_gtext: jnp.ndarray  # [Ct, tile_len] uint32 packed local tiles
-    tile_valid: jnp.ndarray  # [Ct] int32 valid node count per local tile
+    gtext: jnp.ndarray  # [Nb] uint32 local packed graph text slice
     tile_base: jnp.ndarray  # int32 global tile id of local tile row 0
     node_of_backbone: jnp.ndarray  # [Lb] int32 local backbone→node slice
     nb_offset: jnp.ndarray  # int32 global backbone coord of slice row 0
     backbone: jnp.ndarray  # [Nb] int32 local node→backbone slice
-    node_base: jnp.ndarray  # int32 global node id of backbone slice row 0
+    node_base: jnp.ndarray  # int32 global node id of gtext/backbone row 0
     idx_hashes: jnp.ndarray  # [M] uint32 sorted minimizer hashes
     idx_positions: jnp.ndarray  # [M] int32 GLOBAL backbone positions
     tile_bloom: jnp.ndarray  # [Ct, BLOOM_WORDS] uint32 per-tile q-gram Bloom
@@ -117,8 +116,8 @@ def whole_graph_view(garr: GraphArrays) -> GraphView:
     """The trivial single-shard view: full arrays, zero offsets."""
     zero = jnp.int32(0)
     return GraphView(
-        tile_gtext=garr.tile_gtext, tile_valid=garr.tile_valid,
-        tile_base=zero, node_of_backbone=garr.node_of_backbone,
+        gtext=garr.gtext, tile_base=zero,
+        node_of_backbone=garr.node_of_backbone,
         nb_offset=zero, backbone=garr.backbone, node_base=zero,
         idx_hashes=garr.idx_hashes, idx_positions=garr.idx_positions,
         tile_bloom=garr.tile_bloom, tile_slack=garr.tile_slack)
@@ -184,7 +183,7 @@ def _tiles_of_starts(view: GraphView, starts, *, tile_stride: int,
     node = view.node_of_backbone[
         jnp.clip(sb - view.nb_offset, 0, nb_len - 1)]  # [B, C] global ids
     tile_g = jnp.clip(node // tile_stride, 0, n_tiles - 1)
-    n_local_tiles = view.tile_gtext.shape[0]
+    n_local_tiles = view.tile_slack.shape[0]
     tile_local = jnp.clip(tile_g - view.tile_base, 0, n_local_tiles - 1)
     return tile_g, tile_local
 
@@ -286,6 +285,7 @@ def graph_candidate_stage(
     max_candidates: int,
     minimizer_w: int,
     minimizer_k: int,
+    margin: int,
     use_kernel: bool = False,
     block_bt: int | None = None,
     interpret: bool = True,
@@ -301,6 +301,11 @@ def graph_candidate_stage(
     lexicographically, so merging the winners of disjoint views
     reproduces the whole-graph winner exactly.
 
+    Each slot's window is gathered from the view's graph text at its
+    tile's first node, ``t_cap + tile_stride + margin`` nodes long: the
+    filter refines the anchor over the first ``tile_stride + margin``
+    nodes, and ``t_cap`` nodes of alignment text follow any anchor.
+
     With ``pf`` (a `tile_prefilter` result) the DC filter only scores
     surviving slots; with ``n_cap`` additionally set (a `tile_rung`
     bucket) survivors are compacted into an ``[n_cap]``-row buffer so
@@ -309,12 +314,16 @@ def graph_candidate_stage(
     mapped read: pruned slots take the exact ``(filter_k+1, off=0)``
     values the dense scan computes for them.
     """
-    del n_nodes  # global sizing is carried by the caller's geometry checks
     b = reads.shape[0]
     c = max_candidates
-    _, tile_len = view.tile_gtext.shape
-    search_span = tile_len - t_cap
+    search_span = tile_stride + margin
+    win_len = t_cap + search_span
     read_lens = read_lens.astype(jnp.int32)
+
+    def windows(tile_local):
+        start = (view.tile_base + tile_local) * tile_stride
+        return gather_windows(view.gtext, start, win_len, n_nodes=n_nodes,
+                              node_base=view.node_base)
 
     if pf is None:
         starts, votes = _seed(view, reads, max_candidates=c,
@@ -334,8 +343,8 @@ def graph_candidate_stage(
 
     if n_cap is None:
         # --- dense: one gather + one DC launch over every slot
-        wins = view.tile_gtext[tile_local]  # [B, C, tile_len]
-        d_c, off_c = dc(wins.reshape(b * c, tile_len),
+        wins = windows(tile_local)  # [B, C, win_len]
+        d_c, off_c = dc(wins.reshape(b * c, win_len),
                         jnp.repeat(fpat, c, axis=0), jnp.repeat(flens, c))
         d_c = jnp.where(keep, d_c.reshape(b, c), filter_k + 1)
         off_c = off_c.reshape(b, c)
@@ -354,7 +363,7 @@ def graph_candidate_stage(
         n_tot = jnp.sum(kf, dtype=jnp.int32)
         rowmask = jnp.arange(n_cap) < n_tot
         ridx = slots // c  # read of each compacted row
-        wins_r = view.tile_gtext[tile_local.reshape(bc)[slots]]
+        wins_r = windows(tile_local.reshape(bc)[slots])
         d_r, off_r = dc(wins_r, fpat[ridx], flens[ridx])  # [n_cap] each
         d_c = jnp.full((bc,), filter_k + 1, jnp.int32).at[slots].set(
             jnp.where(rowmask, d_r, filter_k + 1)).reshape(b, c)
@@ -379,12 +388,12 @@ def graph_candidate_stage(
     off = off_c[rows, ci]
     prefilter_ok = d_best <= filter_k
 
-    # --- slice the anchored alignment window out of the winning tile
-    wrow = view.tile_gtext[tile_local[rows, ci]]
+    # --- slice the anchored alignment window out of the winning window
+    win_start = (view.tile_base + tile_local[rows, ci]) * tile_stride
     gwin = jax.vmap(
         lambda wbuf, o: jax.lax.dynamic_slice(wbuf, (o,), (t_cap,)))(
-        wrow, off)
-    t_len = jnp.clip(view.tile_valid[tile_local[rows, ci]] - off, 0, t_cap)
+        windows(tile_local[rows, ci]), off)
+    t_len = jnp.clip(n_nodes - win_start - off, 0, t_cap)
 
     # backbone coordinate of every window node, shipped with the window
     # so the align stage needs no graph arrays (clip mirrors the
@@ -492,7 +501,7 @@ class GraphMapExecutor:
     (the serve engine forwards them into its metrics registry).
     """
 
-    def __init__(self, *, tile_stride: int,
+    def __init__(self, *, geometry: tuple[int, int, int],
                  cfg: GenASMConfig = GenASMConfig(),
                  p_cap: int = 256,
                  filter_bits: int = 128,
@@ -515,6 +524,13 @@ class GraphMapExecutor:
         self.cfg = cfg
         self.p_cap = p_cap
         self.t_cap = p_cap + 2 * cfg.w
+        tile_stride, margin, window = geometry
+        if window < self.t_cap:
+            raise ValueError(
+                f"index window {window} < the {self.t_cap}-node alignment "
+                f"text at p_cap {p_cap}: the tile screen would not cover "
+                f"the gathered windows; rebuild the index with window >= "
+                f"{self.t_cap}")
         self.tile_stride = tile_stride
         self.max_candidates = max_candidates
         self.prefilter = _env_prefilter(prefilter)
@@ -532,8 +548,9 @@ class GraphMapExecutor:
             max_candidates=max_candidates, minimizer_w=minimizer_w,
             minimizer_k=minimizer_k, prefilter=self.prefilter)
         self._stage_kw = dict(
-            tile_stride=tile_stride, t_cap=self.t_cap, filter_bits=fbits,
-            filter_k=filter_k, max_candidates=max_candidates,
+            tile_stride=tile_stride, margin=margin, t_cap=self.t_cap,
+            filter_bits=fbits, filter_k=filter_k,
+            max_candidates=max_candidates,
             minimizer_w=minimizer_w, minimizer_k=minimizer_k,
             use_kernel=use_kernel, block_bt=block_bt, interpret=interpret)
 
@@ -541,7 +558,7 @@ class GraphMapExecutor:
             self._hook(("prefilter",))
             return tile_prefilter(
                 whole_graph_view(garr), reads, lens,
-                n_tiles=garr.tile_gtext.shape[0],
+                n_tiles=garr.tile_slack.shape[0],
                 backbone_len=garr.node_of_backbone.shape[0], **self._pf_kw)
 
         self._pf = jax.jit(pf_fn)
@@ -565,26 +582,15 @@ class GraphMapExecutor:
                 self._hook((_n,))
                 return graph_candidate_stage(
                     whole_graph_view(garr), reads, lens,
-                    n_tiles=garr.tile_gtext.shape[0],
+                    n_tiles=garr.tile_slack.shape[0],
                     backbone_len=garr.node_of_backbone.shape[0],
-                    n_nodes=garr.bases.shape[0], pf=pf, n_cap=_n,
+                    n_nodes=garr.gtext.shape[0], pf=pf, n_cap=_n,
                     **self._stage_kw)
 
             fn = self._stages[n_cap] = jax.jit(stage_fn)
         return fn
 
-    def _check_geometry(self, garr: GraphArrays) -> None:
-        tile_len = int(garr.tile_gtext.shape[1])
-        span = tile_len - self.t_cap
-        if span < self.tile_stride:
-            raise ValueError(
-                f"tile_len {tile_len} leaves a {span}-node anchor search "
-                f"span < tile_stride {self.tile_stride} at p_cap "
-                f"{self.p_cap}; rebuild the index with window >= "
-                f"{self.t_cap}")
-
     def __call__(self, garr: GraphArrays, reads, read_lens) -> GraphMapResult:
-        self._check_geometry(garr)
         reads = jnp.asarray(reads)
         lens = jnp.asarray(read_lens, jnp.int32)
         b = reads.shape[0]
@@ -645,7 +651,7 @@ def map_batch(
     reads: jnp.ndarray,
     read_lens: jnp.ndarray,
     *,
-    tile_stride: int,
+    geometry: tuple[int, int, int],
     cfg: GenASMConfig = GenASMConfig(),
     p_cap: int = 256,
     filter_bits: int = 128,
@@ -657,10 +663,12 @@ def map_batch(
     block_bt: int | None = None,
     prefilter: bool | None = None,
 ) -> GraphMapResult:
-    """Map a read batch against the tiled graph index.
+    """Map a read batch against the graph index.
 
-    ``garr`` is the device half of a `GraphIndex` whose ``tile_stride``
-    the caller passes statically (it shapes the tile→node arithmetic).
+    ``garr`` is the device half of a `GraphIndex` whose ``geometry``
+    (tile stride, margin, window) the caller passes statically: it shapes
+    the tile→node arithmetic and the gathered windows, and the mapper
+    checks that the index's window covers what it gathers.
     ``backend`` resolves through `repro.align` with linear names mapped
     to their graph twins.  ``prefilter`` toggles the q-gram tile screen
     (None → the ``REPRO_GRAPH_PREFILTER`` env default, on); results are
@@ -671,11 +679,10 @@ def map_batch(
     sync): call it eagerly, do **not** wrap it in ``jax.jit``.
     """
     ex = get_map_executor(
-        tile_stride=tile_stride, cfg=cfg, p_cap=p_cap,
-        filter_bits=filter_bits, filter_k=filter_k,
-        max_candidates=max_candidates, minimizer_w=minimizer_w,
-        minimizer_k=minimizer_k, backend=backend, block_bt=block_bt,
-        prefilter=prefilter)
+        geometry=geometry, cfg=cfg, p_cap=p_cap, filter_bits=filter_bits,
+        filter_k=filter_k, max_candidates=max_candidates,
+        minimizer_w=minimizer_w, minimizer_k=minimizer_k, backend=backend,
+        block_bt=block_bt, prefilter=prefilter)
     return ex(garr, reads, read_lens)
 
 
@@ -684,5 +691,5 @@ def map_batch_index(gidx: GraphIndex, reads, read_lens, **kw
     """`map_batch` with the geometry pulled off a host `GraphIndex`."""
     kw.setdefault("minimizer_w", gidx.minimizer_w)
     kw.setdefault("minimizer_k", gidx.minimizer_k)
-    return map_batch(gidx.arrays, reads, read_lens,
-                     tile_stride=gidx.tile_stride, **kw)
+    return map_batch(gidx.arrays, reads, read_lens, geometry=gidx.geometry,
+                     **kw)

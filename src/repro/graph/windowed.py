@@ -7,9 +7,14 @@ the AND-combination of every successor's bitvectors within the hopBits
 window (Figure 6-9).  This module runs that generalized DC inside the
 *same* window loop as the linear aligner — `core/genasm.window_commit`
 is shared, the traceback mirrors `core/genasm_tb.window_tb_r` bit for
-bit — so on a degenerate (pure-backbone) graph the emitted distances,
-CIGARs and text advances are **bit-identical** to the `lax` backend.
-That equivalence is the graph conformance suite's anchor.
+bit.  A window holding a variant differs from a linear one in two ways,
+both for the alt nodes a path skips: it spans `text_window` nodes, ``w``
+plus room for skipped alleles, and the read's tail past its end is
+pre-matched.  A window whose first ``w`` nodes form a chain has nothing
+to skip and runs as a linear window (`window_view`), so on a degenerate
+(pure-backbone) graph the emitted distances, CIGARs and text advances
+are **bit-identical** to the `lax` backend — the graph conformance
+suite's anchor.
 
 Graph windows travel through the uniform dispatch signature as **packed
 graph text**: one uint32 per node, base id in the low 8 bits and the
@@ -26,7 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.bitvector import (SENTINEL, get_bit, msb, n_words, ones,
+from repro.core.bitvector import (SENTINEL, get_bit, msb, n_words,
                                   pattern_bitmasks, shl1)
 from repro.core.genasm import (AlignResult, GenASMConfig, pad_pattern,
                                window_commit)
@@ -59,10 +64,43 @@ def unpack_graph_text(gtext: jnp.ndarray):
     return base, succ
 
 
+def text_window(cfg: GenASMConfig) -> int:
+    """Nodes in one graph window: the ``w`` a linear window spans, plus
+    ``2 * HOP_LIMIT`` of room.  A path skips the alt nodes of variants it
+    does not take (an insertion allele of up to ``HOP_LIMIT - 1`` nodes, a
+    SNP's alt node) and the backbone nodes of deletions it does; without
+    the room, skipped nodes eat into the ``o`` characters of lookahead
+    past the window's commit point, and the window can take a branch into
+    an allele the read does not carry, paying for every node of it.  The
+    room holds 24 skipped allele nodes and 8 read deletions, the stated
+    distance bound's condition."""
+    return cfg.w + 2 * HOP_LIMIT
+
+
+def window_view(sub_g: jnp.ndarray, p_rest, w: int):
+    """Unpack ``[..., text_window]`` packed window nodes for the DC and
+    traceback: ``(bases, succ, p_len)``, where ``p_len`` is how much of
+    the ``w``-character sub-pattern the DC reads before the pre-matched
+    tail (``p_rest``, the read characters left, clipped to ``w``).
+
+    A window whose first ``w`` nodes form a chain (hop-0 edges only) has
+    nothing a path can skip, so it is run as a linear window, bit for bit:
+    node ``w - 1`` loses its edge, as a linear window ends there, and the
+    read's tail is read as the linear aligner reads it.
+    """
+    bases, succ = unpack_graph_text(sub_g)
+    chain = jnp.all(succ[..., :w] == 1, axis=-1)
+    succ = succ.at[..., w - 1].set(
+        jnp.where(chain, jnp.uint32(0), succ[..., w - 1]))
+    p_len = jnp.where(chain, w, jnp.clip(p_rest, 0, w)).astype(jnp.int32)
+    return bases, succ, p_len
+
+
 def pad_graph_text(gtext: jnp.ndarray, t_len, cap: int, cfg: GenASMConfig):
-    """Pad/trim a packed graph-text buffer to ``cap + w`` with sentinel
-    chain nodes after ``t_len`` (the graph twin of `genasm.pad_text`)."""
-    size = cap + cfg.w
+    """Pad/trim a packed graph-text buffer to ``cap`` plus one window
+    (`text_window`) with sentinel chain nodes after ``t_len`` (the graph
+    twin of `genasm.pad_text`)."""
+    size = cap + text_window(cfg)
     buf = jnp.full((size,), SENT_NODE, jnp.uint32)
     buf = lax.dynamic_update_slice(buf, jnp.asarray(gtext, jnp.uint32)[:size],
                                    (0,))
@@ -78,19 +116,28 @@ def _graph_buf_cap(p_cap: int, cfg: GenASMConfig) -> int:
 
 @partial(jax.jit, static_argnames=("w", "k"))
 def window_dc_graph(bases: jnp.ndarray, succ: jnp.ndarray,
-                    sub_pattern: jnp.ndarray, *, w: int, k: int):
-    """BitAlign DC over one ``w``-node subgraph window (R-only store).
+                    sub_pattern: jnp.ndarray, p_len, *, w: int, k: int):
+    """BitAlign DC over one subgraph window (R-only store).
 
-    ``bases``/``succ``: [w] window nodes (hops past the window end fall on
-    the all-ones boundary via the hop ring buffer, no masking needed).
-    Returns ``(d_min int32, store [w, k+1, nw] uint32)`` — ``d_min`` is
-    anchored at node 0, ``store[i]`` the status rows R of node ``i``.
-    On a hop-0 chain this equals `core/genasm_dc.window_dc_r` bitwise.
+    ``bases``/``succ``: the window's nodes, ``w`` or more (hops past the
+    window end fall on the boundary via the hop ring buffer, no masking
+    needed).  Only the first ``p_len`` characters of the ``w``-character
+    ``sub_pattern`` are read: its tail is pre-matched everywhere
+    (`bitalign._tail_mask`), so the read's end may meet the window's last
+    node, and alt nodes a path skips over never push the tail past the
+    window end.
+    Returns ``(d_min int32, store [nodes, k+1, nw] uint32)`` — ``d_min``
+    is anchored at node 0, ``store[i]`` the status rows R of node ``i``.
+    On a hop-0 chain of ``w`` nodes with ``p_len == w`` this equals
+    `core/genasm_dc.window_dc_r` bitwise.
     """
+    from repro.core.segram.bitalign import _tail_mask
+
     nw = n_words(w)
     pm = pattern_bitmasks(sub_pattern, w)
     H = HOP_LIMIT
-    boundary = ones((k + 1, nw))
+    tail = _tail_mask(p_len, w)
+    boundary = jnp.broadcast_to(tail, (k + 1, nw))
 
     def step(hist, inputs):
         # hist: [H, k+1, nw] — hist[h] = R of node i + 1 + h
@@ -99,16 +146,15 @@ def window_dc_graph(bases: jnp.ndarray, succ: jnp.ndarray,
         masked = jnp.where(hop_ok[:, None, None], hist, boundary[None])
         comb = masked[0]
         for h in range(1, H):
-            comb = comb & masked[h]  # [k+1, nw]; all-ones when no successor
+            comb = comb & masked[h]  # [k+1, nw]; the tail when no successor
         cur_pm = pm[base]
-        R0 = shl1(comb[0]) | cur_pm
-        rows = [R0]
+        rows = [(shl1(comb[0]) | cur_pm) & tail]
         for d in range(1, k + 1):
             D = comb[d - 1]
             S = shl1(comb[d - 1])
             I = shl1(rows[d - 1])
             M = shl1(comb[d]) | cur_pm
-            rows.append(D & S & I & M)
+            rows.append(D & S & I & M & tail)
         R = jnp.stack(rows)  # [k+1, nw]
         return jnp.concatenate([R[None], hist[:-1]], axis=0), R
 
@@ -185,10 +231,13 @@ def window_tb_graph(store: jnp.ndarray, succ: jnp.ndarray, bases: jnp.ndarray,
     how the walk advances through the linearization — that choice is the
     node path GAF reports.
 
+    ``store`` may span more nodes than the ``w``-character sub-pattern
+    (`text_window`); the commit stays within the first ``w - o`` nodes.
     Returns ``(pc, tc, err_used, ops [2*(w-o)] int8, n_ops,
     nodes [2*(w-o)] int32 window-local node per op (-1 for I), stuck)``.
     ``tc`` is the node advance for the next window (hops included).
     """
+    tw = store.shape[0]  # window nodes
     max_steps = 2 * (w - o)
     cap_t = jnp.int32(w - o)
     cap_p = jnp.asarray(cap_p, jnp.int32)
@@ -198,15 +247,15 @@ def window_tb_graph(store: jnp.ndarray, succ: jnp.ndarray, bases: jnp.ndarray,
 
     def succ_rows(ti, de):
         """[H, nw] successor R rows (all-ones past the window boundary)."""
-        pos = jnp.clip(ti + 1 + hop_rng, 0, w - 1)
+        pos = jnp.clip(ti + 1 + hop_rng, 0, tw - 1)
         rows = store[pos, de]
-        in_w = (ti + 1 + hop_rng) < w
+        in_w = (ti + 1 + hop_rng) < tw
         return jnp.where(in_w[:, None], rows, jnp.uint32(0xFFFFFFFF))
 
     def body(_, st):
         patternI, textI, curError, prev_op, pc, tc, n_ops, ops, nodes, stuck = st
         active = (pc < cap_p) & (tc < cap_t) & (patternI >= 0) & (~stuck)
-        ti = jnp.clip(textI, 0, w - 1)
+        ti = jnp.clip(textI, 0, tw - 1)
         de = jnp.clip(curError, 0, k)
         dem1 = jnp.clip(curError - 1, 0, k)
         pi = jnp.clip(patternI, 0, w - 1)
@@ -332,6 +381,7 @@ def graph_align(
     n_win = cfg.n_windows(p_cap)
     max_steps = 2 * cfg.commit
     w, o, k = cfg.w, cfg.o, cfg.k
+    tw = text_window(cfg)
 
     pat = pad_pattern(pattern, p_len, p_cap, cfg)
     gbuf = pad_graph_text(gtext, t_len, _graph_buf_cap(p_cap, cfg), cfg)
@@ -339,9 +389,9 @@ def graph_align(
     def window_step(carry, _):
         cur_p, cur_t = carry[0], carry[1]
         sub_p = lax.dynamic_slice(pat, (cur_p,), (w,))
-        sub_g = lax.dynamic_slice(gbuf, (cur_t,), (w,))
-        bases, succ = unpack_graph_text(sub_g)
-        d_min, store = window_dc_graph(bases, succ, sub_p, w=w, k=k)
+        sub_g = lax.dynamic_slice(gbuf, (cur_t,), (tw,))
+        bases, succ, p_win = window_view(sub_g, p_len - cur_p, w)
+        d_min, store = window_dc_graph(bases, succ, sub_p, p_win, w=w, k=k)
         cap_p = jnp.minimum(jnp.int32(cfg.commit), p_len - cur_p)
         pm = pattern_bitmasks(sub_p, w)
         pc, tc, err, ops, n_ops, nodes, stuck = window_tb_graph(

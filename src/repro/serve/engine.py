@@ -125,6 +125,11 @@ class EngineConfig:
     # graph workload: q-gram tile screen before the BitAlign-DC filter
     # (bitwise-neutral on output; off only for A/B measurement)
     graph_prefilter: bool = True
+    # graph workload: the most bytes the graph index may hold on the
+    # device, all shards together (what the graph_index_device_bytes
+    # gauge reads); the engine refuses a larger index at start.  None:
+    # no limit
+    graph_index_max_bytes: int | None = None
     # sharded serving: mesh-split align stage / double-buffered flushes
     align_sharded: bool = False
     pipelined: bool = False
@@ -146,6 +151,10 @@ class EngineConfig:
         if self.shard_candidates is not None and self.shard_candidates < 1:
             raise ValueError(f"shard_candidates must be >= 1, got "
                              f"{self.shard_candidates}")
+        if (self.graph_index_max_bytes is not None
+                and self.graph_index_max_bytes < 1):
+            raise ValueError(f"graph_index_max_bytes must be >= 1, got "
+                             f"{self.graph_index_max_bytes}")
         if (self.align_sharded or self.pipelined) and self.num_shards < 2:
             raise ValueError(
                 "align_sharded/pipelined serve through the repro.shard "
@@ -328,6 +337,8 @@ class ServeEngine:
             self.align_backend = align_dispatch.resolve_backend(
                 config.align_backend).name
         self.metrics = metrics or Metrics()
+        if config.workload == "graph":
+            self._graph_gauges(self.index.index)
         self.cache = ResultCache(config.cache_capacity)
         self._queues: dict[int, list[_Request]] = {c: [] for c in config.buckets}
         self._executors: dict[tuple, object] = {}
@@ -347,6 +358,19 @@ class ServeEngine:
         self._worker = threading.Thread(
             target=self._run, name="serve-engine", daemon=True)
         self._worker.start()
+
+    def _graph_gauges(self, gidx) -> None:
+        """The graph index's size: nodes, and the bytes it holds on the
+        device (a sharded index counts every shard's), held to
+        ``graph_index_max_bytes``."""
+        nbytes = sum(int(a.nbytes) for a in gidx.arrays)
+        limit = self.config.graph_index_max_bytes
+        if limit is not None and nbytes > limit:
+            raise ValueError(
+                f"the graph index holds {nbytes} bytes on the device, over "
+                f"graph_index_max_bytes={limit}")
+        self.metrics.gauge("graph_nodes").set(gidx.n_nodes)
+        self.metrics.gauge("graph_index_device_bytes").set(nbytes)
 
     # ------------------------------------------------------------ sharding --
     def _shard_halo(self) -> int:
@@ -530,7 +554,8 @@ class ServeEngine:
     def _executor(self, cap: int, geom=None, sharded_index=None):
         """One compiled ``map_batch`` per (bucket_cap, workload, backend,
         config) — built lazily.  ``geom`` is the index geometry *at
-        flush time* — the graph index's tile_stride, or a sharded
+        flush time* — the graph index's ``geometry`` (tile stride,
+        margin, window), or a sharded
         index's ``layout_key`` — baked into the compiled closure, so it
         rides in the key: a refresh() that re-tiles the graph (or
         re-partitions the shards) gets a fresh executor instead of
@@ -587,7 +612,7 @@ class ServeEngine:
                 # (one prefilter + align trace per cap, one candidate
                 # stage per tile-count rung — the graph bucket ladder)
                 fn = GraphMapExecutor(
-                    tile_stride=geom, cfg=c.genasm, p_cap=cap,
+                    geometry=geom, cfg=c.genasm, p_cap=cap,
                     filter_bits=fbits, filter_k=c.filter_k,
                     max_candidates=c.max_candidates,
                     minimizer_w=c.minimizer_w, minimizer_k=c.minimizer_k,
@@ -975,7 +1000,7 @@ class ServeEngine:
                     fn = self._executor(cap, index.layout_key,
                                         sharded_index=index)
                 else:  # graph: syncs mid-flush to pick a tile rung
-                    fn = self._executor(cap, index.tile_stride)
+                    fn = self._executor(cap, index.geometry)
                 lens = self._lengths(cap, reqs)
                 # copied here, so the executor's own jnp.asarray of it is
                 # a no-op and no host work falls between encode and the

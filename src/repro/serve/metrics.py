@@ -16,7 +16,9 @@ effectiveness, forwarded from the executor's ``last_stats``:
 ``graph_dc_rows_dense`` (BitAlign-DC rows actually launched at the
 chosen tile-count rung vs the dense [B·C] launch it replaced), and
 ``graph_reads_zero_survivor`` (reads short-circuited to the unmapped
-result without any DC/align work).
+result without any DC/align work).  Two gauges size the graph index when
+the engine takes it: ``graph_nodes`` (linearized graph nodes) and
+``graph_index_device_bytes`` (every array the index holds on the device).
 """
 from __future__ import annotations
 

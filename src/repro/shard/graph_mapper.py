@@ -44,16 +44,15 @@ from .mapper import PendingBatch
 
 def validate_graph_geometry(sharded: ShardedGraphIndex, *, p_cap: int,
                             filter_k: int, cfg: GenASMConfig) -> None:
-    """Raise if the tile/halo geometry cannot cover this mapping setup."""
+    """Raise if the window/halo geometry cannot cover this mapping setup."""
     from repro.core.segram.graph import HOP_LIMIT
 
     t_cap = p_cap + 2 * cfg.w
-    span = sharded.tile_len - t_cap
-    if span < sharded.tile_stride:
+    if sharded.window < t_cap:
         raise ValueError(
-            f"tile_len {sharded.tile_len} leaves a {span}-node anchor "
-            f"search span < tile_stride {sharded.tile_stride} at p_cap "
-            f"{p_cap}; rebuild the index with window >= {t_cap}")
+            f"index window {sharded.window} < the {t_cap}-node alignment "
+            f"text at p_cap {p_cap}: the tile screen would not cover the "
+            f"gathered windows; rebuild the index with window >= {t_cap}")
     need = p_cap + 32 + HOP_LIMIT + filter_k
     if sharded.layout.halo < need:
         raise ValueError(
@@ -62,11 +61,11 @@ def validate_graph_geometry(sharded: ShardedGraphIndex, *, p_cap: int,
             f"halo >= {need}")
 
 
-def _shard_view(tiles, tvalid, tbase, nob, nboff, bb, nbase, hashes, poss,
-                tbloom, tslack) -> GraphView:
+def _shard_view(gtext, tbase, nob, nboff, bb, nbase, hashes, poss, tbloom,
+                tslack) -> GraphView:
     """One shard's arrays (`GraphShardArrays` row order) as a GraphView."""
     return GraphView(
-        tile_gtext=tiles, tile_valid=tvalid, tile_base=tbase,
+        gtext=gtext, tile_base=tbase,
         node_of_backbone=nob, nb_offset=nboff, backbone=bb,
         node_base=nbase, idx_hashes=hashes, idx_positions=poss,
         tile_bloom=tbloom, tile_slack=tslack)
@@ -148,7 +147,8 @@ class ShardedGraphMapExecutor:
         static = dict(
             tile_stride=sharded.tile_stride, n_tiles=sharded.n_tiles,
             backbone_len=sharded.ref_len, n_nodes=sharded.n_nodes,
-            t_cap=t_cap, filter_bits=min(filter_bits, p_cap),
+            margin=sharded.margin, t_cap=t_cap,
+            filter_bits=min(filter_bits, p_cap),
             filter_k=filter_k, max_candidates=shard_candidates,
             minimizer_w=sharded.minimizer_w,
             minimizer_k=sharded.minimizer_k,

@@ -10,12 +10,13 @@ the already-built global `repro.graph.index.GraphIndex` arrays:
 * a haloed ``node_of_backbone`` slice (candidate backbone coordinate →
   node id);
 * the contiguous global **tile** range those nodes map to under
-  ``node // tile_stride`` — tiles are sliced from the global
-  ``tile_gtext``, so per-tile hop-boundary masks (and therefore window
-  bytes) are bit-identical to the whole-graph index;
-* the ``backbone`` (node → backbone coordinate) slice covering every
-  node of those tiles, shipped so the merged winner's GAF path
-  translates without touching any other shard.
+  ``node // tile_stride``, with the tiles' screen rows (Bloom, slack);
+* the node range those tiles cover, sliced out of the one packed graph
+  text and out of ``backbone`` (node → backbone coordinate): windows are
+  gathered from the slice under global node ids and cut at their own
+  ends by the shared rule, so their bytes are bit-identical to the
+  whole-graph index's, and the merged winner's GAF path translates
+  without touching any other shard.
 
 Candidates stay in global coordinates end-to-end (global backbone
 positions in the table, global tile ids, global origin node ids), so
@@ -32,6 +33,7 @@ import numpy as np
 
 from repro.core.segram.graph import Variant
 from repro.graph.index import EpochedGraphIndex, GraphIndex, build_graph_index
+from repro.graph.windowed import SENT_NODE
 
 from .partition import (DEFAULT_HALO, ShardLayout, _PAD_HASH, _PAD_POS,
                         plan_layout)
@@ -46,13 +48,12 @@ class GraphShardArrays(NamedTuple):
     sits at that global coordinate).
     """
 
-    tile_gtext: jnp.ndarray  # [S, Ct, tile_len] uint32 packed local tiles
-    tile_valid: jnp.ndarray  # [S, Ct] int32 valid node count per tile
+    gtext: jnp.ndarray  # [S, Nb] uint32 packed graph text slice
     tile_base: jnp.ndarray  # [S] int32 global tile id of local row 0
     node_of_backbone: jnp.ndarray  # [S, Lb] int32 backbone→node slice
     nb_offset: jnp.ndarray  # [S] int32 global backbone coord of slice row 0
     backbone: jnp.ndarray  # [S, Nb] int32 node→backbone slice
-    node_base: jnp.ndarray  # [S] int32 global node id of slice row 0
+    node_base: jnp.ndarray  # [S] int32 global node id of gtext/backbone row 0
     hashes: jnp.ndarray  # [S, Mm] uint32 sorted minimizer hashes
     positions: jnp.ndarray  # [S, Mm] int32 GLOBAL backbone positions
     tile_bloom: jnp.ndarray  # [S, Ct, BLOOM_WORDS] uint32 q-gram Blooms
@@ -90,30 +91,30 @@ class ShardedGraphIndex:
         """Hashable geometry key (partition + tile pitch + padded dims)."""
         a = self.arrays
         return (self.layout.bounds, self.layout.halo, self.tile_len,
-                self.tile_stride, int(a.tile_gtext.shape[1]),
-                int(a.node_of_backbone.shape[1]), int(a.backbone.shape[1]),
-                int(a.hashes.shape[1]))
+                self.tile_stride, self.margin, self.n_nodes,
+                int(a.tile_slack.shape[1]), int(a.node_of_backbone.shape[1]),
+                int(a.backbone.shape[1]), int(a.hashes.shape[1]))
 
 
 def shard_graph_index(gidx: GraphIndex, num_shards: int, *,
                       halo: int = DEFAULT_HALO) -> ShardedGraphIndex:
     """Slice a built ``GraphIndex`` into per-device shards.
 
-    Pure slicing of the global arrays — tiles, hop masks, and minimizer
-    entries are exactly the whole-graph ones, which is what keeps the
-    sharded mapper's windows byte-identical to the single-device path.
+    Pure slicing of the global arrays — graph text, screen rows, and
+    minimizer entries are exactly the whole-graph ones, which is what
+    keeps the sharded mapper's windows byte-identical to the single-device
+    path.
     """
     a = gidx.arrays
     L = int(a.node_of_backbone.shape[0])
-    n_tiles = int(a.tile_gtext.shape[0])
-    n_nodes = int(a.bases.shape[0])
+    n_tiles = gidx.n_tiles
+    n_nodes = gidx.n_nodes
     layout = plan_layout(L, num_shards, halo)
     nob = np.asarray(a.node_of_backbone)
     g_hash = np.asarray(a.idx_hashes)
     g_pos = np.asarray(a.idx_positions)
     backbone = np.asarray(a.backbone)
-    tiles = np.asarray(a.tile_gtext)
-    tvalid = np.asarray(a.tile_valid)
+    text = np.asarray(a.gtext)
     tbloom = np.asarray(a.tile_bloom)
     tslack = np.asarray(a.tile_slack)
 
@@ -127,20 +128,18 @@ def shard_graph_index(gidx: GraphIndex, num_shards: int, *,
         node_hi = min(n_nodes, (thi - 1) * gidx.tile_stride + gidx.tile_len)
         m = (g_pos >= lo) & (g_pos < hi)
         rows.append(dict(
-            tiles=tiles[tlo:thi], tvalid=tvalid[tlo:thi], tile_base=tlo,
-            nob=nob[blo:bhi], nb_offset=blo,
-            backbone=backbone[node_lo:node_hi], node_base=node_lo,
+            tile_base=tlo, nob=nob[blo:bhi], nb_offset=blo,
+            text=text[node_lo:node_hi], backbone=backbone[node_lo:node_hi],
+            node_base=node_lo,
             hashes=g_hash[m], positions=g_pos[m],
             tbloom=tbloom[tlo:thi], tslack=tslack[tlo:thi]))
 
     s = num_shards
-    ct = max(len(r["tiles"]) for r in rows)
+    ct = max(len(r["tslack"]) for r in rows)
     lb = max(len(r["nob"]) for r in rows)
     nb = max(len(r["backbone"]) for r in rows)
     mm = max(1, max(len(r["hashes"]) for r in rows))
-    tile_len = gidx.tile_len
-    st_tiles = np.zeros((s, ct, tile_len), np.uint32)
-    st_tvalid = np.zeros((s, ct), np.int32)
+    st_text = np.full((s, nb), SENT_NODE, np.uint32)
     st_nob = np.zeros((s, lb), np.int32)
     st_bb = np.full((s, nb), -1, np.int32)
     st_hash = np.full((s, mm), _PAD_HASH, np.uint32)
@@ -151,8 +150,7 @@ def shard_graph_index(gidx: GraphIndex, num_shards: int, *,
     nb_offset = np.zeros(s, np.int32)
     node_base = np.zeros(s, np.int32)
     for i, r in enumerate(rows):
-        st_tiles[i, : len(r["tiles"])] = r["tiles"]
-        st_tvalid[i, : len(r["tvalid"])] = r["tvalid"]
+        st_text[i, : len(r["text"])] = r["text"]
         st_nob[i, : len(r["nob"])] = r["nob"]
         st_bb[i, : len(r["backbone"])] = r["backbone"]
         st_hash[i, : len(r["hashes"])] = r["hashes"]
@@ -163,15 +161,16 @@ def shard_graph_index(gidx: GraphIndex, num_shards: int, *,
         nb_offset[i] = r["nb_offset"]
         node_base[i] = r["node_base"]
     arrays = GraphShardArrays(
-        tile_gtext=jnp.asarray(st_tiles), tile_valid=jnp.asarray(st_tvalid),
-        tile_base=jnp.asarray(tile_base), node_of_backbone=jnp.asarray(st_nob),
+        gtext=jnp.asarray(st_text), tile_base=jnp.asarray(tile_base),
+        node_of_backbone=jnp.asarray(st_nob),
         nb_offset=jnp.asarray(nb_offset), backbone=jnp.asarray(st_bb),
         node_base=jnp.asarray(node_base), hashes=jnp.asarray(st_hash),
         positions=jnp.asarray(st_pos), tile_bloom=jnp.asarray(st_bloom),
         tile_slack=jnp.asarray(st_slack))
     return ShardedGraphIndex(
         arrays=arrays, layout=layout, ref=np.asarray(gidx.ref, np.int8),
-        tile_len=tile_len, tile_stride=gidx.tile_stride, n_tiles=n_tiles,
+        tile_len=gidx.tile_len, tile_stride=gidx.tile_stride,
+        n_tiles=n_tiles,
         n_nodes=n_nodes, minimizer_w=gidx.minimizer_w,
         minimizer_k=gidx.minimizer_k, window=gidx.window, margin=gidx.margin)
 

@@ -14,13 +14,22 @@ Three anchors, mirroring the linear suite's tiers:
     `graph_edit_distance_anchored` DP oracle: exact for substitution-only
     injections on spelled graph paths, oracle ≤ reported ≤ oracle + 3
     for mixed edits; every emitted path walks real graph edges and every
-    M op matches its node base.
+    M op matches its node base;
+  * **the stated bound** — the service's graph path at the benchmark's
+    population density (1000 Genomes phase 3: a variant per 35 bp, 1-15
+    bp indels, reads from a sample's haplotype), and at sites clustered
+    6 bp apart, keeps every answer within the distance bound its
+    configuration states of the anchored oracle wherever the bound's
+    condition holds, and every CIGAR spells its read along its GAF path.
 
 ``REPRO_ALIGN_BACKEND`` (the CI matrix knob) narrows the graph backend
 list; pinning a linear backend skips this suite (the linear suite
 already runs it through the graph backends' chain packing).
 """
+import json
 import os
+import sys
+from pathlib import Path
 
 import numpy as np
 import jax.numpy as jnp
@@ -247,3 +256,125 @@ def test_emit_cigar_false_distances_match(backend):
                                   np.asarray(full.distance))
     np.testing.assert_array_equal(np.asarray(slim.n_ops),
                                   np.asarray(full.n_ops))
+
+
+# ---------------------------------------------------- the stated bound ---
+ROOT = Path(__file__).resolve().parents[1]
+POPULATION = ROOT / "bench" / "tests" / "data" / "graph-1kg.json"
+STATED = json.loads((ROOT / "bench" / "checks" / "pangenome-sr-batch.json")
+                    .read_text())["limits"]["excess_max"]
+# the stated bound's condition: the alleles within any 96 bp of backbone
+# total at most 24 nodes (a SNP or insertion its allele, a deletion its
+# span), and a read has at most 8 deletions
+SPAN, ALLELE_NODES, READ_DELETIONS = 96, 24, 8
+
+
+def _service_answers(config: dict, seed: int, n_reads: int):
+    """Map ``n_reads`` 150 bp reads from a sample's haplotype through the
+    serve engine over ``config``'s deployment, built as the benchmark
+    builds it; return the reads, the answers, the graph's packed text
+    unpacked, its node→backbone map, and the variants."""
+    if str(ROOT) not in sys.path:
+        sys.path.insert(0, str(ROOT))
+    from bench.harness import cell, traffic
+    from bench.systems import graph as system
+    from repro.serve import ServeEngine
+
+    mix = json.loads((ROOT / "bench" / "traffic" / "sr-batch.json")
+                     .read_text())
+    rngs = cell.streams(seed)
+    data = cell.make_data(config, rngs["data"])
+    source = cell.read_source(config, data, rngs["haplotype"])
+    reads = traffic.simulate_reads(
+        source.seq, np.full(n_reads, 150), mix["error_profile"],
+        rngs["window"], source.to_backbone)
+    index, ecfg = system.build(config, data, {})
+    with ServeEngine(index, ecfg) as engine:
+        res = engine.map_all([reads.read(i) for i in range(len(reads))])
+    bases, succ = (np.asarray(a) for a in windowed.unpack_graph_text(
+        index.index.arrays.gtext))
+    backbone = np.asarray(index.index.arrays.backbone)
+    return ([reads.read(i) for i in range(len(reads))], res, bases, succ,
+            backbone, data.variants)
+
+
+def _allele_load(variants, n_ref: int) -> np.ndarray:
+    """``load[b]``: allele nodes within the ``SPAN`` bp of backbone from
+    ``b`` — the most nodes a path over them can skip."""
+    nodes = np.zeros(n_ref + SPAN, np.int64)
+    np.add.at(nodes, variants.pos,
+              np.where(variants.kind == 2, variants.span, variants.alt_len))
+    cs = np.concatenate([[0], np.cumsum(nodes)])
+    return cs[SPAN:] - cs[:-SPAN]
+
+
+def _check_answers(reads, res, bases, succ, backbone, load):
+    """Every mapped answer's path walks graph edges, its first backbone
+    node is the reported position, and its CIGAR spells the read along
+    that path with exactly the reported edits; where the bound's condition
+    holds, its distance is within the stated bound of the exact anchored optimum at the path's
+    first node.  Returns the load of each answer held to the bound."""
+    graph = cgraph.GenomeGraph(bases, succ, backbone, None)
+    bounded = []
+    for i, (read, r) in enumerate(zip(reads, res)):
+        if r.position < 0:
+            continue
+        path = [int(x) for x in r.path[:r.n_ops] if x >= 0]
+        on_backbone = backbone[path][backbone[path] >= 0]
+        assert on_backbone[0] == r.position, f"read {i}: position"
+        for a, b in zip(path, path[1:]):
+            hop = b - a - 1
+            assert 0 <= hop < cgraph.HOP_LIMIT and (int(succ[a]) >> hop) & 1, \
+                f"read {i}: path steps {a}->{b} off the graph"
+        err = oracle.check_cigar(r.ops, r.n_ops, read, bases[path], r.distance)
+        assert err is None, f"read {i}: {err}"
+        worst = int(load[r.position:r.position + len(read)].max())
+        deletions = int(np.sum(np.asarray(r.ops[:r.n_ops]) == 3))
+        if worst > ALLELE_NODES or deletions > READ_DELETIONS:
+            continue
+        o, t = path[0], 2 * (len(read) + 8)
+        win_b, win_s = cgraph.extract_subgraph(graph, o, t)
+        preds = [[] for _ in range(t)]
+        for j in range(t):
+            for h in range(cgraph.HOP_LIMIT):
+                if (int(win_s[j]) >> h) & 1:
+                    preds[j + h + 1].append(j)
+        want = oracle.graph_edit_distance_anchored(read, win_b, preds)
+        assert want <= r.distance <= want + STATED, \
+            f"read {i}: distance {r.distance}, optimum {want}"
+        bounded.append(worst)
+    return bounded
+
+
+@pytest.mark.parametrize("seed", [2147483677, 17, 5, 2 ** 31 + 101,
+                                  4_000_000_007])
+def test_service_keeps_stated_bound_at_population_density(seed):
+    """Reads from a sample's haplotype, mapped by the serve engine over
+    the graph-1kg deployment cut to 200 kbp (as the benchmark builds it):
+    every answer is a valid alignment along a graph path, and every one
+    of at least 56 of 64 is within the stated bound of the optimum."""
+    config = json.loads(POPULATION.read_text())
+    reads, res, bases, succ, backbone, variants = _service_answers(
+        config, seed, 64)
+    load = _allele_load(variants, config["reference_length"])
+    bounded = _check_answers(reads, res, bases, succ, backbone, load)
+    assert len(bounded) >= 56
+
+
+@pytest.mark.parametrize("seed", [2147483677, 17, 3_000_000_019])
+def test_service_keeps_stated_bound_at_clustered_sites(seed):
+    """The same at sites 6 bp apart, a variant per 12 bp, a quarter of them
+    1-15 bp insertions and a quarter 1-4 bp deletions: the bound holds
+    wherever its condition does, including reads past several alleles
+    close together, and every answer is a valid alignment."""
+    config = json.loads(POPULATION.read_text())
+    config.update(reference_length=100_000, variant_every_bp=12,
+                  variant_site_pitch=6,
+                  variant_mix={"snp": 2, "ins": 1, "del": 1},
+                  deletion_span={"1": 46, "2": 15, "3": 7, "4": 8})
+    reads, res, bases, succ, backbone, variants = _service_answers(
+        config, seed, 64)
+    load = _allele_load(variants, config["reference_length"])
+    bounded = _check_answers(reads, res, bases, succ, backbone, load)
+    assert len(bounded) >= 32
+    assert sum(n >= 16 for n in bounded) >= 8

@@ -3,6 +3,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
+from repro.core import filter as qfilter
 from repro.core import oracle
 from repro.core.segram import graph as cgraph
 from repro.core.segram import segram
@@ -54,6 +55,159 @@ def test_build_graph_rejects_bad_variants():
         cgraph.build_graph(ref, [cgraph.Variant(5, "del", span=20)])
 
 
+def _loop_build_graph(ref, variants):
+    """The position-by-position build `build_graph` replaced: its oracle."""
+    L = len(ref)
+    bases, backbone, src, dst = [], [], [], []
+    node_of_backbone = np.full(L, -1, np.int64)
+    by_pos = {}
+    for v in variants:
+        by_pos.setdefault(v.pos, []).append(v)
+    prev_tails, pending_del = [], {}
+    for p in range(L):
+        nid = len(bases)
+        bases.append(int(ref[p]))
+        backbone.append(p)
+        node_of_backbone[p] = nid
+        preds = prev_tails + pending_del.pop(p, [])
+        for t in preds:
+            src.append(t)
+            dst.append(nid)
+        prev_tails = [nid]
+        for v in by_pos.get(p, []):
+            if v.kind == "snp":
+                if not v.alt:
+                    raise ValueError(f"snp at {p} needs a non-empty alt")
+                prev = -1
+                for j, ab in enumerate(v.alt):
+                    alt_id = len(bases)
+                    bases.append(int(ab))
+                    backbone.append(-1)
+                    for a in (preds if j == 0 else [prev]):
+                        src.append(a)
+                        dst.append(alt_id)
+                    prev = alt_id
+                prev_tails.append(prev)
+            elif v.kind == "ins":
+                prev = nid
+                for ab in v.alt:
+                    alt_id = len(bases)
+                    bases.append(int(ab))
+                    backbone.append(-1)
+                    src.append(prev)
+                    dst.append(alt_id)
+                    prev = alt_id
+                prev_tails.append(prev)
+            elif v.kind == "del":
+                tgt = p + v.span + 1
+                if tgt >= L:
+                    raise ValueError(
+                        f"del at {p} (span {v.span}) lands at backbone "
+                        f"{tgt}, past the reference end {L}; trim the "
+                        f"variant or extend the reference")
+                pending_del.setdefault(tgt, []).append(nid)
+            else:
+                raise ValueError(v.kind)
+    succ = np.zeros(len(bases), np.uint32)
+    if src:
+        a, b = np.asarray(src, np.int64), np.asarray(dst, np.int64)
+        hop = b - a - 1
+        if hop.max() >= cgraph.HOP_LIMIT:
+            w = int(hop.argmax())
+            raise ValueError(
+                f"edge {int(a[w])}->{int(b[w])} hop {int(hop[w]) + 1} "
+                f"exceeds HOP_LIMIT={cgraph.HOP_LIMIT}; re-chunk the graph")
+        np.bitwise_or.at(succ, a, np.uint32(1) << hop.astype(np.uint32))
+    return cgraph.GenomeGraph(np.array(bases, np.int8), succ,
+                              np.array(backbone, np.int32),
+                              node_of_backbone.astype(np.int32))
+
+
+INDEL_LENGTHS = np.arange(1, 16)  # the 1000 Genomes table's 1-15 bp
+INDEL_WEIGHTS = np.array([46, 15, 7, 8, 4, 3, 2, 3, 2, 2, 1, 2, 1, 2, 2],
+                         float)
+
+
+def _variant_mix(seed, L, *, every=35, snp_share=46 / 48, pitch=17):
+    """Variants at population density: sites about ``every`` bp apart on
+    a ``pitch`` grid (so that no 15 bp deletion reaches the next site),
+    mostly SNPs with 1-3 base alts, indels drawn from a 1-15 bp table;
+    beside some SNPs a second SNP at the same position or the next, and a
+    deletion at the last legal position."""
+    rng = np.random.default_rng(seed)
+    sites = np.unique(rng.integers(1, (L - 20) // pitch,
+                                   size=L // every)) * pitch
+    w = INDEL_WEIGHTS / INDEL_WEIGHTS.sum()
+    out = []
+    for p in (int(x) for x in sites):
+        r = rng.random()
+        n = int(rng.choice(INDEL_LENGTHS, p=w))
+        if r < snp_share:
+            size = 1 + int(rng.random() < 0.1) * int(rng.integers(1, 3))
+            out.append(cgraph.Variant(p, "snp", tuple(
+                int(x) for x in rng.integers(0, 4, size=size))))
+            if rng.random() < 0.2:  # a second allele here, or next door
+                out.append(cgraph.Variant(p + int(rng.integers(0, 2)), "snp",
+                                          (int(rng.integers(0, 4)),)))
+        elif r < (1 + snp_share) / 2:
+            out.append(cgraph.Variant(p, "ins", tuple(
+                int(x) for x in rng.integers(0, 4, size=n))))
+        else:
+            out.append(cgraph.Variant(p, "del", span=n))
+    out.append(cgraph.Variant(L - 3, "del", span=1))  # lands at L - 1
+    order = rng.permutation(len(out))  # list order is not position order
+    return [out[i] for i in order]
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 2 ** 31 + 5])
+def test_build_graph_matches_loop(seed):
+    """The array build gives the position-by-position loop's graph: node
+    order, hopBits, backbone and node_of_backbone, at population density
+    with 1-15 bp indels, multi-base SNP alts and adjacent sites."""
+    ref = simulate.random_reference(20_000, seed=seed % 1000)
+    variants = _variant_mix(seed, len(ref))
+    want = _loop_build_graph(ref, variants)
+    got = cgraph.build_graph(ref, variants)
+    for f in ("bases", "succ_bits", "backbone", "node_of_backbone"):
+        a, b = getattr(got, f), getattr(want, f)
+        assert a.dtype == b.dtype, f
+        np.testing.assert_array_equal(a, b, err_msg=f)
+    # and dense edge cases: every kind at one position, a graph end
+    ref = np.tile(np.arange(4, dtype=np.int8), 8)
+    dense = [cgraph.Variant(3, "ins", (1, 2)), cgraph.Variant(3, "snp", (0,)),
+             cgraph.Variant(3, "del", span=2), cgraph.Variant(4, "snp", (2,)),
+             cgraph.Variant(6, "snp", (1, 1, 1)), cgraph.Variant(31, "ins",
+                                                                 (3,)),
+             cgraph.Variant(0, "snp", (3,)), cgraph.Variant(2, "ins", ()),
+             cgraph.Variant(40, "snp", (1,))]
+    want, got = _loop_build_graph(ref, dense), cgraph.build_graph(ref, dense)
+    for f in ("bases", "succ_bits", "backbone", "node_of_backbone"):
+        np.testing.assert_array_equal(getattr(got, f), getattr(want, f),
+                                      err_msg=f)
+
+
+@pytest.mark.parametrize("bad", [
+    [cgraph.Variant(27, "del", span=2)],
+    [cgraph.Variant(5, "snp", ())],
+    [cgraph.Variant(5, "del", span=20)],
+    [cgraph.Variant(5, "inv", span=2)],
+    [cgraph.Variant(4, "ins", tuple([1] * 14)), cgraph.Variant(4, "snp", (2,)),
+     cgraph.Variant(3, "snp", (1,))],
+    [cgraph.Variant(9, "snp", ()), cgraph.Variant(7, "del", span=40),
+     cgraph.Variant(7, "snp", ())],
+    [cgraph.Variant(2, "ins", tuple([0] * 15)), cgraph.Variant(12, "del",
+                                                               span=16)],
+])
+def test_build_graph_raises_as_loop(bad):
+    """The same ValueError, message and all, as the loop raised first."""
+    ref = np.zeros(30, np.int8)
+    with pytest.raises(ValueError) as want:
+        _loop_build_graph(ref, bad)
+    with pytest.raises(ValueError) as got:
+        cgraph.build_graph(ref, bad)
+    assert str(got.value) == str(want.value)
+
+
 def test_window_extractors_share_boundary_rule(rng):
     """Host extract_subgraph and device segram._window agree bitwise."""
     ref = simulate.random_reference(600, seed=9)
@@ -69,38 +223,71 @@ def test_window_extractors_share_boundary_rule(rng):
         np.testing.assert_array_equal(hs, np.asarray(ds))
 
 
-# ----------------------------------------------------------- tiled index ---
+# ------------------------------------------------------------ one store ---
+def _gather(idx, starts, length):
+    return np.asarray(gindex.gather_windows(
+        idx.arrays.gtext, jnp.asarray(starts), length, n_nodes=idx.n_nodes))
+
+
+def _packed_extract(g, start, length):
+    bases, succ = cgraph.extract_subgraph(g, start, length)
+    return np.asarray(windowed.pack_graph_text(jnp.asarray(bases),
+                                               jnp.asarray(succ)))
+
+
 def test_tiles_match_extract_subgraph():
-    """Every tile is extract_subgraph at its start — one masking rule."""
+    """Every window gathered from the one stored text is extract_subgraph
+    at its start and length — one masking rule — at each tile start, for
+    the screen's tile length and each bucket rung's gather length."""
     ref = simulate.random_reference(900, seed=3)
     variants = simulate.simulate_variants(ref, n_snp=6, n_ins=3, n_del=3,
                                           seed=4)
     g = cgraph.build_graph(ref, variants)
-    idx = gindex.build_graph_index(ref, variants, w=8, k=12, window=128,
-                                   tile_stride=64)
-    tiles = np.asarray(idx.arrays.tile_gtext)
-    for c in (0, 1, idx.n_tiles // 2, idx.n_tiles - 1):
-        bases, succ = cgraph.extract_subgraph(g, c * idx.tile_stride,
-                                              idx.tile_len)
-        want = np.asarray(windowed.pack_graph_text(jnp.asarray(bases),
-                                                   jnp.asarray(succ)))
-        np.testing.assert_array_equal(tiles[c], want, err_msg=f"tile {c}")
-        assert int(idx.arrays.tile_valid[c]) == \
-            min(idx.tile_len, g.n_nodes - c * idx.tile_stride)
+    cfg = EngineConfig(workload="graph")
+    t_caps = [cap + 2 * cfg.genasm.w for cap in cfg.buckets]
+    idx = gindex.build_graph_index(ref, variants, w=8, k=12,
+                                   window=max(t_caps), tile_stride=64)
+    assert idx.arrays.gtext.shape == (g.n_nodes,)  # each node once
+    starts = np.array([0, 1, idx.n_tiles // 2, idx.n_tiles - 1]) \
+        * idx.tile_stride
+    rungs = [t_cap + idx.tile_stride + idx.margin for t_cap in t_caps]
+    assert rungs[-1] == idx.tile_len
+    for length in rungs:
+        got = _gather(idx, starts, length)
+        for s, row in zip(starts, got):
+            np.testing.assert_array_equal(
+                row, _packed_extract(g, int(s), length),
+                err_msg=f"window at {s}, length {length}")
 
 
 def test_chunked_tile_build_matches_one_pass(monkeypatch):
-    """Tiles built a chunk of rows at a time equal the one-pass build."""
+    """The tile screen built a chunk of tiles at a time, from per-block
+    Blooms, equals the one-pass build and each tile's own Bloom and slack
+    over its extract_subgraph window."""
     ref = simulate.random_reference(12_000, seed=4)
     vs = simulate.simulate_variants(ref, n_snp=30, n_ins=15, n_del=15,
                                     seed=5)
     g = cgraph.build_graph(ref, vs)
-    args = (jnp.asarray(g.bases), jnp.asarray(g.succ_bits))
-    one = gindex._build_tiles(*args, tile_len=512, tile_stride=64)
-    monkeypatch.setattr(gindex, "TILE_CHUNK", 37)
-    chunked = gindex._build_tiles(*args, tile_len=512, tile_stride=64)
-    for a, b in zip(one, chunked):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    gtext = windowed.pack_graph_text(jnp.asarray(g.bases),
+                                     jnp.asarray(g.succ_bits))
+    for tile_len in (512, 480, 455):  # q-grams ending mid-block or not
+        one = gindex._build_screen(gtext, tile_len=tile_len, tile_stride=64)
+        monkeypatch.setattr(gindex, "TILE_CHUNK", 37)
+        chunked = gindex._build_screen(gtext, tile_len=tile_len,
+                                       tile_stride=64)
+        monkeypatch.undo()
+        for a, b in zip(one, chunked):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        bloom, slack = (np.asarray(a) for a in one)
+        assert len(bloom) == -(-g.n_nodes // 64)
+        for c in (0, 1, len(bloom) // 2, len(bloom) - 3, len(bloom) - 1):
+            bases, succ = cgraph.extract_subgraph(g, c * 64, tile_len)
+            valid = min(tile_len, g.n_nodes - c * 64)
+            want = qfilter.qgram_bloom(jnp.asarray(bases), valid)
+            np.testing.assert_array_equal(bloom[c], np.asarray(want),
+                                          err_msg=f"tile {c} Bloom")
+            hops = sum(bin(int(x) >> 1).count("1") for x in succ[:valid])
+            assert slack[c] == (qfilter.QGRAM_Q - 1) * hops, f"tile {c}"
 
 
 def test_npz_roundtrip(tmp_path):
@@ -152,7 +339,8 @@ def test_mapper_chunked_long_reference():
     ok = (~failed) & (np.abs(pos - rs.true_pos) <= 40)
     assert ok.sum() >= 10
     # paths walk real edges
-    succ = np.asarray(idx.arrays.succ_bits)
+    _, succ = windowed.unpack_graph_text(idx.arrays.gtext)
+    succ = np.asarray(succ)
     for i in np.nonzero(~failed)[0]:
         p = [int(x) for x in np.asarray(out.path[i]) if x >= 0]
         for a, b in zip(p, p[1:]):
@@ -189,6 +377,26 @@ def test_engine_graph_workload_end_to_end():
         assert again.cached and again.path is not None
         key_workloads = {k[1] for k in eng._executors}
     assert key_workloads == {"graph"}
+
+
+def test_engine_holds_graph_index_to_its_budget():
+    ref = simulate.random_reference(3000, seed=11)
+    variants = simulate.simulate_variants(ref, n_snp=8, n_ins=4, n_del=4,
+                                          seed=12)
+    egi = gindex.build_epoched_graph_index(
+        ref, variants, w=8, k=12, window=96 + 2 * 64)
+    nbytes = sum(int(a.nbytes) for a in egi.index.arrays)
+    kw = dict(buckets=(96,), workload="graph", minimizer_w=8, minimizer_k=12)
+    with ServeEngine(egi, EngineConfig(graph_index_max_bytes=nbytes,
+                                       **kw)) as eng:
+        gauges = eng.metrics.snapshot()
+    assert gauges["graph_index_device_bytes"] == nbytes
+    assert gauges["graph_nodes"] == egi.index.n_nodes
+    with pytest.raises(ValueError, match=f"{nbytes} bytes.*max_bytes"):
+        ServeEngine(egi, EngineConfig(graph_index_max_bytes=nbytes - 1,
+                                      **kw))
+    with pytest.raises(ValueError, match="graph_index_max_bytes"):
+        EngineConfig(graph_index_max_bytes=0, **kw)
 
 
 def test_engine_graph_workload_rejects_linear_index():

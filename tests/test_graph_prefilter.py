@@ -80,17 +80,18 @@ def _dense_slot_dists(gidx, arr, lens, pf, filter_k):
     """Every slot's dense in-span DC distance (the exact filter verdict)."""
     view = gmapper.whole_graph_view(gidx.arrays)
     b, c = pf.votes.shape
-    _, tile_len = view.tile_gtext.shape
+    span = gidx.tile_stride + gidx.margin  # the mapper's anchor span
     tile_g, tile_local = gmapper._tiles_of_starts(
         view, pf.starts, tile_stride=gidx.tile_stride, n_tiles=gidx.n_tiles,
         backbone_len=gidx.arrays.node_of_backbone.shape[0])
     fpat, flens = gmapper._filter_pattern(jnp.asarray(arr),
                                           jnp.asarray(lens, jnp.int32),
                                           P_CAP)
-    wins = view.tile_gtext[tile_local]
+    wins = gindex.gather_windows(view.gtext, tile_local * gidx.tile_stride,
+                                 T_CAP + span, n_nodes=gidx.n_nodes)
     d, _ = gmapper._filter_best(
-        wins.reshape(b * c, tile_len), jnp.repeat(fpat, c, axis=0),
-        jnp.repeat(flens, c), span=tile_len - T_CAP, m_bits=P_CAP,
+        wins.reshape(b * c, T_CAP + span), jnp.repeat(fpat, c, axis=0),
+        jnp.repeat(flens, c), span=span, m_bits=P_CAP,
         k=filter_k, use_kernel=False, block_bt=None, interpret=True)
     return np.asarray(d).reshape(b, c), np.asarray(tile_g)
 
@@ -243,7 +244,8 @@ def test_compacted_stage_matches_dense_at_any_rung(graph_setup):
     ref, _, gidx = graph_setup
     arr, lens = _mixed_reads(ref, seed=80)
     view = gmapper.whole_graph_view(gidx.arrays)
-    skw = dict(tile_stride=gidx.tile_stride, n_tiles=gidx.n_tiles,
+    skw = dict(tile_stride=gidx.tile_stride, margin=gidx.margin,
+               n_tiles=gidx.n_tiles,
                backbone_len=gidx.arrays.node_of_backbone.shape[0],
                n_nodes=gidx.n_nodes, t_cap=T_CAP, filter_bits=P_CAP,
                filter_k=FILTER_K, max_candidates=MAX_CAND, **SEED_KW)
@@ -291,7 +293,7 @@ def test_zero_survivor_batch_short_circuits(graph_setup):
     rng = np.random.default_rng(90)
     reads = [rng.integers(0, 4, 100).astype(np.int8) for _ in range(6)]
     arr, lens = encode.batch_reads(reads, P_CAP)
-    kw = dict(tile_stride=gidx.tile_stride, cfg=CFG, p_cap=P_CAP,
+    kw = dict(geometry=gidx.geometry, cfg=CFG, p_cap=P_CAP,
               filter_bits=P_CAP, filter_k=FILTER_K,
               max_candidates=MAX_CAND, backend="graph_lax", **SEED_KW)
     ex_on = gmapper.GraphMapExecutor(prefilter=True, **kw)
@@ -317,7 +319,7 @@ def test_mixed_batch_zero_survivor_reads_stat(graph_setup):
     reads = [np.array(ref[500:600], np.int8),
              rng.integers(0, 4, 100).astype(np.int8)]
     arr, lens = encode.batch_reads(reads, P_CAP)
-    kw = dict(tile_stride=gidx.tile_stride, cfg=CFG, p_cap=P_CAP,
+    kw = dict(geometry=gidx.geometry, cfg=CFG, p_cap=P_CAP,
               filter_bits=P_CAP, filter_k=FILTER_K,
               max_candidates=MAX_CAND, backend="graph_lax", **SEED_KW)
     ex = gmapper.GraphMapExecutor(prefilter=True, **kw)

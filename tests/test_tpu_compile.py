@@ -2,9 +2,10 @@
 
 Ahead-of-time compiles against a *described* v5e topology — no chip
 attached — at the shapes the serve engine issues: GenASM-DC v1/v2 window
-batches, the graph filter's BitAlign kernel at the top bucket's tile
-length, the graph align's BitAlign DC, and the jitted batched align
-step at the smallest and largest bucket caps.  Interpret-mode tests
+batches, the graph filter's BitAlign kernel over the windows the mapper
+gathers at the smallest and largest bucket caps, the graph align's
+BitAlign DC over a graph window, and the jitted batched align step at
+the smallest and largest bucket caps.  Interpret-mode tests
 cannot see what only the TPU compiler refuses (block shapes off the
 (8, 128) tiling, reversals, integer reductions, VMEM overflow).
 
@@ -20,6 +21,7 @@ import pytest
 
 from repro.align.batched import batched_kernel_align
 from repro.core.genasm import GenASMConfig
+from repro.graph.windowed import text_window
 from repro.kernels.bitalign import bitalign_dc_batch, bitalign_filter_batch
 from repro.kernels.genasm_dc import window_dc_batch
 from repro.kernels.genasm_dc_v2 import window_dc_batch_v2
@@ -27,7 +29,7 @@ from repro.kernels.genasm_dc_v2 import window_dc_batch_v2
 CFG = GenASMConfig()  # w=64, k=24: the engine's alignment geometry
 BATCH = 32  # EngineConfig.max_batch
 FILTER_BITS, FILTER_K = 128, 11  # graph filter of the serving launcher
-TILE_LEN = 1536  # graph tile at stride 64 + margin 64 + window 1408
+SPAN = 128  # graph anchor span: tile stride 64 + margin 64
 
 
 @pytest.fixture(scope="module")
@@ -67,24 +69,27 @@ def test_window_dc_kernels_compile(one_chip, kernel):
         lambda t, p: kernel(t, p, w=CFG.w, k=CFG.k, interpret=False), s, s)
 
 
-def test_graph_filter_kernel_compiles(one_chip):
+@pytest.mark.parametrize("cap", [160, 1280])
+def test_graph_filter_kernel_compiles(one_chip, cap):
     rows = BATCH * 4  # max_candidates slots: the top tile rung
+    win = cap + 2 * CFG.w + SPAN  # the window gathered per slot
     _compiled_kernel(
         lambda b, s, p, n: bitalign_filter_batch(
-            b, s, p, n, m_bits=FILTER_BITS, k=FILTER_K,
-            span=TILE_LEN - (160 + 2 * CFG.w), interpret=False),
-        _spec(one_chip, (rows, TILE_LEN), jnp.int8),
-        _spec(one_chip, (rows, TILE_LEN), jnp.uint32),
+            b, s, p, n, m_bits=FILTER_BITS, k=FILTER_K, span=SPAN,
+            interpret=False),
+        _spec(one_chip, (rows, win), jnp.int8),
+        _spec(one_chip, (rows, win), jnp.uint32),
         _spec(one_chip, (rows, FILTER_BITS), jnp.int8),
         _spec(one_chip, (rows,), jnp.int32))
 
 
 def test_graph_align_dc_kernel_compiles(one_chip):
+    nodes = text_window(CFG)
     _compiled_kernel(
         lambda b, s, p, n: bitalign_dc_batch(
             b, s, p, n, m_bits=CFG.w, k=CFG.k, interpret=False),
-        _spec(one_chip, (BATCH, CFG.w), jnp.int8),
-        _spec(one_chip, (BATCH, CFG.w), jnp.uint32),
+        _spec(one_chip, (BATCH, nodes), jnp.int8),
+        _spec(one_chip, (BATCH, nodes), jnp.uint32),
         _spec(one_chip, (BATCH, CFG.w), jnp.int8),
         _spec(one_chip, (BATCH,), jnp.int32))
 
